@@ -161,8 +161,8 @@ def bench_executor_e2e():
     from repro.core import canonical, plan_skew_join, reference_join, two_way
     from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
     from repro.data import skewed_join_dataset
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((8,), ("cells",))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((8,), ("cells",), axis_types=(AxisType.Auto,))
     q = two_way()
     data = skewed_join_dataset(q, 3_000, 3_000, skew={"B": 1.0}, seed=3)
     plan = plan_skew_join(q, data, 8)
@@ -285,8 +285,8 @@ def bench_shuffle_scaling():
                                 two_way)
         from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
         from repro.data import skewed_join_dataset
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((8,), ("cells",))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((8,), ("cells",), axis_types=(AxisType.Auto,))
         q = two_way()
         data = skewed_join_dataset(q, 3_000, 3_000, skew={"B": 1.0}, seed=3)
         plan = plan_skew_join(q, data, 8)
@@ -339,10 +339,10 @@ def bench_fold_scaling():
                             plan_skew_join, reference_join, two_way)
     from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
     from repro.data import skewed_join_dataset
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
 
     n_dev = 8
-    mesh = make_mesh_compat((n_dev,), ("cells",))
+    mesh = jax.make_mesh((n_dev,), ("cells",), axis_types=(AxisType.Auto,))
     q = two_way()
     data = skewed_join_dataset(q, 4_000, 2_000, skew={"B": 1.3}, seed=11)
     expect = reference_join(q, data)
@@ -494,10 +494,11 @@ def bench_map_scaling():
     if len(jax.devices()) >= 8:
         from repro.core import canonical, reference_join
         from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
-        from repro.launch.mesh import make_mesh_compat
+        from jax.sharding import AxisType
         small = skewed_join_dataset(q, 3_000, 3_000, skew={"B": 1.0}, seed=3)
         plan = plan_skew_join(q, small, 64)
-        ex = ShardedJoinExecutor(plan, make_mesh_compat((8,), ("cells",)),
+        mesh = jax.make_mesh((8,), ("cells",), axis_types=(AxisType.Auto,))
+        ex = ShardedJoinExecutor(plan, mesh,
                                  config=ExecutorConfig(out_capacity=131072))
         t0 = time.perf_counter()
         session = ex.session().prepare(small)
@@ -663,11 +664,11 @@ def bench_recover_scaling():
                                      ShardedJoinExecutor)
     from repro.data import skewed_join_dataset
     from repro.ft import ChaosInjector
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
     from repro.serve import SelfHealingSession
 
     n_dev = 8
-    mesh = make_mesh_compat((n_dev,), ("cells",))
+    mesh = jax.make_mesh((n_dev,), ("cells",), axis_types=(AxisType.Auto,))
     q = two_way()
     data = skewed_join_dataset(q, 3_000, 1_500, skew={"B": 1.2}, seed=41)
     expect = reference_join(q, data)
@@ -821,11 +822,11 @@ def bench_adapt_scaling():
     from repro.core.adapt import AdaptPolicy
     from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
     from repro.data import drifting_join_batch
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
     from repro.serve import SelfHealingSession
 
     n_dev, n, hh_rows, dom, nhot, bonus, k = 8, 1024, 128, 128, 6, 24, 32
-    mesh = make_mesh_compat((n_dev,), ("cells",))
+    mesh = jax.make_mesh((n_dev,), ("cells",), axis_types=(AxisType.Auto,))
     q = two_way()
 
     # Group tail values by which cell slice the plan routes them to, so a
@@ -954,10 +955,10 @@ def bench_shuffle_overlap():
     from repro.core import canonical, plan_skew_join, reference_join, two_way
     from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
     from repro.data import skewed_join_dataset
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
 
     n_dev = 8
-    mesh = make_mesh_compat((n_dev,), ("cells",))
+    mesh = jax.make_mesh((n_dev,), ("cells",), axis_types=(AxisType.Auto,))
     q = two_way()
     chunk_counts = (1, 2, 4)
     report = {"n_devices": n_dev, "cores": os.cpu_count(),
@@ -1051,11 +1052,11 @@ def bench_serve_scaling():
         return
     from repro.core import canonical, reference_join
     from repro.data import mixed_workload
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
     from repro.serve import JoinServingEngine
 
     n_dev, warm_n, steady_n = 8, 12, 24
-    mesh = make_mesh_compat((n_dev,), ("cells",))
+    mesh = jax.make_mesh((n_dev,), ("cells",), axis_types=(AxisType.Auto,))
     eng = JoinServingEngine(mesh, k=n_dev)
 
     def _run_phase(n_requests, seed):

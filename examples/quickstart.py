@@ -3,12 +3,13 @@
 Reproduces Examples 1.1/1.2: a heavy hitter makes naive partitioning cost
 r + ks while the Shares grid costs 2√(krs), and the full SkewShares planner
 (HH detection -> residual joins -> per-residual Shares) balances reducer load.
-The finale executes a k=256 plan on an 8-device mesh: the executor folds the
+The finale executes a k=256 plan on the device mesh: the executor folds the
 256 logical cells onto the devices with LPT placement (core/placement.py) and
 the result is validated bit-exactly against the numpy oracle.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
-(8 virtual CPU devices are requested below; on TPU the mesh is real.)
+The mesh spans every device JAX finds: the chips on a TPU host, or 8
+virtual devices on the CPU (requested below).
 """
 import os
 
@@ -66,15 +67,18 @@ def main():
         print(f"  k={kk:5d}: naive r+ks = {naive_hh_cost(r, s, kk):.3e}   "
               f"Shares 2√(krs) = {shares_hh_cost(r, s, kk):.3e}")
 
-    # Now EXECUTE a k=256 plan on 8 devices: 256 logical cells fold onto the
+    # Now EXECUTE a k=256 plan on the mesh: 256 logical cells fold onto the
     # mesh via LPT placement; output is bit-exact vs the numpy oracle.
     import jax
     from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     run_data = skewed_join_dataset(query, n_per_relation=3_000, domain=1_500,
                                    skew={"B": 1.4}, seed=1)
     run_plan = plan_skew_join(query, run_data, k)
-    mesh = make_mesh_compat((len(jax.devices()),), ("cells",))
+    mesh = jax.make_mesh((len(jax.devices()),), ("cells",),
+                         axis_types=(AxisType.Auto,))
     ex = ShardedJoinExecutor(run_plan, mesh,
                              config=ExecutorConfig(out_capacity=1 << 18))
     session = ex.session().prepare(run_data)

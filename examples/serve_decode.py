@@ -18,12 +18,13 @@ from repro.configs import ARCHS
 from repro.models import api
 from repro.models.common import init_params
 from repro.serve import build_decode_step
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 
 
 def main():
     cfg = ARCHS["qwen2-0.5b"].reduced()
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     B, MAX_SEQ, PROMPT, GEN = 8, 128, 16, 32
 
     fns = build_decode_step(cfg, mesh, batch=B, max_seq=MAX_SEQ)

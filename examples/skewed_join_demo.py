@@ -9,7 +9,8 @@ onto the mesh with skew-aware LPT placement (core/placement.py), exactly how
 a data-sized plan runs on fixed hardware.
 
 Run:  PYTHONPATH=src python examples/skewed_join_demo.py
-(8 virtual CPU devices are requested below; on TPU the mesh is real.)
+The mesh spans every device JAX finds: the chips on a TPU host, or 8
+virtual devices on the CPU (requested below).
 """
 import os
 
@@ -26,11 +27,14 @@ from repro.core import (canonical, plan_skew_join, reference_join,
                         running_example)
 from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
 from repro.data import skewed_join_dataset
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.cache import enable_compile_cache
+from jax.sharding import AxisType
 
 
 def main():
-    mesh = make_mesh_compat((8,), ("cells",))
+    enable_compile_cache()
+    mesh = jax.make_mesh((len(jax.devices()),), ("cells",),
+                         axis_types=(AxisType.Auto,))
     # The paper's running 3-way example: R(A,B) ⋈ S(B,E,C) ⋈ T(C,D),
     # with heavy hitters on both B and C.
     query = running_example()
@@ -39,7 +43,7 @@ def main():
     print(f"query: {query}")
     print(f"mesh: {dict(mesh.shape)} ({len(jax.devices())} devices)\n")
 
-    # k = 64 logical cells on 8 devices: an 8x fold.
+    # k = 64 logical cells folded onto the devices (8x on 8 devices).
     plan = plan_skew_join(query, data, k=64, max_hh_per_attr=3)
     print(f"HHs: B={plan.hhs.values('B')} C={plan.hhs.values('C')}")
     print(f"{len(plan.residuals)} residual joins, k={plan.k} logical cells, "

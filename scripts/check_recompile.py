@@ -38,12 +38,15 @@ def main() -> int:
     from repro.core import plan_skew_join, two_way
     from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
     from repro.data import skewed_join_dataset
-    from repro.launch.mesh import make_mesh_compat
+    import jax
+    from jax.sharding import AxisType
 
+    mesh = jax.make_mesh((len(jax.devices()),), ("cells",),
+                         axis_types=(AxisType.Auto,))
     q = two_way()
     data = skewed_join_dataset(q, 600, 60, skew={"B": 1.5}, seed=31)
     plan = plan_skew_join(q, data, 8)
-    ex = ShardedJoinExecutor(plan, make_mesh_compat((8,), ("cells",)),
+    ex = ShardedJoinExecutor(plan, mesh,
                              config=ExecutorConfig(out_capacity=16384))
 
     session = ex.session().prepare(data)
@@ -109,7 +112,7 @@ def main() -> int:
 
     adata = drifting_join_batch(q, 512, 64, 64, [3, 7], 16, seed=5)
     aplan = plan_skew_join(q, adata, 16)
-    aex = ShardedJoinExecutor(aplan, make_mesh_compat((8,), ("cells",)),
+    aex = ShardedJoinExecutor(aplan, mesh,
                               config=ExecutorConfig(out_capacity=32768))
     eng = SelfHealingSession(aex, adapt=AdaptPolicy()).prepare(adata)
     eng.run_batch()

@@ -2,6 +2,8 @@
 # Tier-1 verify + benchmark smoke check + example smoke runs (see ROADMAP.md).
 set -euo pipefail
 cd "$(dirname "$0")/.."
+# CI runs on the CPU (8 virtual devices); the chip has chip_smoke.py.
+export JAX_PLATFORMS=cpu
 
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q
 # Chaos smoke: every injected-fault scenario (overflow retry, device loss,

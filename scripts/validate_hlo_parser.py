@@ -16,9 +16,10 @@ from jax.sharding import PartitionSpec as P, NamedSharding
 import sys
 sys.path.insert(0, "src")
 from repro.launch import hlo_analysis
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 
-mesh = make_mesh_compat((2, 16, 16), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 16, 16), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 
 D, FF, H, KV, L, V, B, S = 5120, 17408, 40, 8, 40, 151936, 32, 4096
 HD = D // H

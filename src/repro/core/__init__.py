@@ -24,7 +24,7 @@ from .hypercube import Hypercube, hash_seed, multiply_shift
 from .placement import (CellPlacement, lpt_placement, modulo_placement,
                         place_cells, placement_gain)
 from .plan import JoinQuery, Relation, running_example, triangle, two_way
-from .reference import canonical, reference_join
+from .reference import canonical, cascade_sizes, reference_join
 from .residual import (ORDINARY, ResidualJoin, TypeCombination, decompose,
                        enumerate_combinations, residual_sizes, tuple_mask)
 from .shares import (SharesSolution, brute_force_shares, optimize_shares,
@@ -40,7 +40,8 @@ __all__ = [
     "Hypercube", "hash_seed", "multiply_shift", "CellPlacement",
     "lpt_placement", "modulo_placement", "place_cells", "placement_gain",
     "JoinQuery", "Relation",
-    "running_example", "triangle", "two_way", "canonical", "reference_join",
+    "running_example", "triangle", "two_way", "canonical", "cascade_sizes",
+    "reference_join",
     "ORDINARY", "ResidualJoin", "TypeCombination", "decompose",
     "enumerate_combinations", "residual_sizes", "tuple_mask", "SharesSolution",
     "brute_force_shares", "optimize_shares", "optimize_shares_expr",

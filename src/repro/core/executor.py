@@ -67,7 +67,6 @@ from ..kernels.map_pack import count_scatter
 from ..kernels.ref import (bucket_pack_ref, build_table_ref, expand_rows_ref,
                            fold_cells_ref, join_hash_ref, run_lengths_ref,
                            segment_scan_ref)
-from ..launch.mesh import shard_map_compat
 from .hypercube import hash_seed
 from .placement import (CellPlacement, check_fold, modulo_placement,
                         place_cells)
@@ -707,25 +706,29 @@ class ShardedJoinExecutor:
         This is the ONLY routing of the data prepare() performs: the staged
         `_route_relation` histogram it replaces materialized the full
         (n·F, w+1) tagged expansion just to throw it away (kept below as the
-        fuse_map=False oracle)."""
+        fuse_map=False oracle).  Each device counts its own shard, which is
+        one source row of the matrix: a Pallas kernel cannot be partitioned
+        by XLA, so the pass runs under `shard_map` like the step."""
         if self._count_fn is None:
             k, cfg, query = self.plan.k, self.config, self.plan.query
-            n_dev, routes = self.n_devices, self.routes
-            specs = self.route_specs
+            routes, specs = self.routes, self.route_specs
 
             def count_matrices(*arrs):
                 outs = []
                 for rel, a in zip(query.relations, arrs):
                     if cfg.use_kernels and cfg.fuse_map:
-                        outs.append(kops.map_count(a, specs[rel.name], k,
-                                                   n_dev))
+                        outs.append(kops.map_count(a, specs[rel.name], k, 1))
                         continue
                     dest, _ = _route_relation(a, routes[rel.name],
                                               cfg.use_kernels)
-                    outs.append(_count_matrix(dest, a.shape[0], k, n_dev))
+                    outs.append(_count_matrix(dest, a.shape[0], k, 1))
                 return tuple(outs)
 
-            self._count_fn = jax.jit(count_matrices)
+            n_rel = len(query.relations)
+            self._count_fn = jax.jit(jax.shard_map(
+                count_matrices, mesh=self.mesh,
+                in_specs=(P(self.axis),) * n_rel,
+                out_specs=(P(self.axis),) * n_rel, check_vma=False))
         return self._count_fn
 
     def _compiled_step(self, shapes: tuple, caps: Mapping[str, int],
@@ -823,8 +826,8 @@ class ShardedJoinExecutor:
 
         specs_in = (P(),) + tuple(P(self.axis) for _ in query.relations)
         specs_out = (P(self.axis),) * 5
-        f = jax.jit(shard_map_compat(step, mesh=self.mesh, in_specs=specs_in,
-                                     out_specs=specs_out))
+        f = jax.jit(jax.shard_map(step, mesh=self.mesh, in_specs=specs_in,
+                                  out_specs=specs_out, check_vma=False))
         # Bounded: one-shot run()s over ever-changing data derive fresh caps
         # each time, and each retained executable pins real memory — evict
         # least-recently-used so a long-lived executor can't grow without

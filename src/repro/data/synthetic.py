@@ -96,6 +96,50 @@ def drifting_join_batch(
     return out
 
 
+def pinned_skew_batch(
+    query: JoinQuery,
+    n: int,
+    heavy: Mapping[str, str],
+    hh_rows: int,
+    light_rows: int,
+    tail_domain: int,
+    seed: int = 0,
+) -> dict[str, np.ndarray]:
+    """A batch whose heavy-hitter rows are pinned, at any size.
+
+    For every join attribute `a`, relation `heavy[a]` holds `hh_rows` rows of
+    value 0 and every other relation holding `a` holds `light_rows` of them:
+    0 is a heavy hitter of `a` for any k ≥ n / hh_rows, yet its join stays
+    hh_rows × light_rows, the one-sided skew of a hot key in a fact table.
+    A relation's zero blocks sit on disjoint rows, so no row holds 0 on two
+    attributes.  Every other row of a join column takes a tail value in
+    1..tail_domain, each value as often as any other, through a seeded
+    bijection of the tail; non-join columns cycle.  `seed` also permutes the
+    rows.  `core.reference.cascade_sizes` gives the exact join sizes.
+    """
+    join_attrs = set(query.join_attributes())
+    rng = np.random.default_rng(seed)
+    out = {}
+    for rel in query.relations:
+        n_zero = {a: (hh_rows if heavy.get(a) == rel.name else light_rows)
+                  for a in rel.attrs if a in join_attrs}
+        if sum(n_zero.values()) > n:
+            raise ValueError(f"{rel.name}: zero blocks exceed n={n}")
+        cols, start = [], 0
+        for a in rel.attrs:
+            if a not in join_attrs:
+                cols.append(np.arange(n, dtype=np.int64) % max(tail_domain, 1))
+                continue
+            tail = 1 + rng.permutation(tail_domain)[
+                np.arange(n - n_zero[a]) % tail_domain]
+            col = np.roll(np.concatenate(
+                [np.zeros(n_zero[a], np.int64), tail]), start)
+            cols.append(col)
+            start += n_zero[a]
+        out[rel.name] = np.stack(cols, axis=1)[rng.permutation(n)]
+    return out
+
+
 def skewed_join_dataset(
     query: JoinQuery,
     n_per_relation: int | Mapping[str, int],

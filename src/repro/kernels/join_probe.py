@@ -57,12 +57,15 @@ from jax.experimental import pallas as pl
 
 from .ref import MULT
 
-DEFAULT_BLOCK = 256       # rows per tile; auto-shrunk so (block, P+1) fits VMEM
+# Rows per tile.  Every per-row operand is a 1-D int32 array, which the TPU
+# keeps in tiles of 1024, so tiles are that long.
+BLOCK = 1024
 MAX_BITS = 16             # default table-size cap (2^16 buckets)
-# Largest n_bits the single-pass (block, P+1) one-hot handles at a healthy
-# tile size; beyond it `build_table` recurses on the high hash bits (the
-# factored two-level histogram of `_build_table_multi_kernel`), keeping
-# O(block · 2^(bits/2)) VMEM at full tiles instead of shrinking the tile.
+HOST_BITS = 30            # hash bits of `join_probe_host`'s sorted partitions
+# Largest n_bits the single-pass (P+1, block) one-hot handles; beyond it
+# `build_table` recurses on the high hash bits (the factored two-level
+# histogram of `_build_table_multi_kernel`), keeping O(block · 2^(bits/2))
+# VMEM per tile.
 SINGLE_PASS_BITS = 10
 INVALID = -1
 
@@ -83,118 +86,139 @@ def default_bits(n_r: int) -> int:
     return max(1, min(MAX_BITS, (max(n_r, 2) - 1).bit_length() + 1))
 
 
-def _hash_block(keys: jnp.ndarray, n_bits: int) -> jnp.ndarray:
-    """(n,) int32 bucket in [0, 2^n_bits): fused multiply-shift over columns.
+def _hash_cols(cols, n_bits: int) -> jnp.ndarray:
+    """int32 bucket in [0, 2^n_bits): fused multiply-shift over key columns.
 
-    Shared by the kernel bodies and the host twins; the per-column seeds
-    unroll statically (w is tiny).
+    `cols[c]` is key column c, all of one shape; the per-column seeds unroll
+    statically (w is tiny).  Shared by the kernel bodies and the host twins.
     """
-    h = jnp.zeros((keys.shape[0],), jnp.uint32)
-    for c, seed in enumerate(col_seeds(keys.shape[1])):
-        h = h + keys[:, c].astype(jnp.uint32) * jnp.uint32(seed)
+    h = jnp.zeros(cols[0].shape, jnp.uint32)
+    for col, seed in zip(cols, col_seeds(len(cols))):
+        h = h + col.astype(jnp.uint32) * jnp.uint32(seed)
     h = h * jnp.uint32(MULT)
     return (h >> jnp.uint32(32 - n_bits)).astype(jnp.int32)
 
 
-def _auto_block(block: int, n_bits: int) -> int:
-    """Shrink the tile so the (block, P+1) one-hot stays within ~4 MiB."""
-    return max(8, min(block, (1 << 20) // ((1 << n_bits) + 1)))
+def _hash_block(keys: jnp.ndarray, n_bits: int) -> jnp.ndarray:
+    """(n,) bucket of each row of keys (n, w) — `_hash_cols` over columns."""
+    return _hash_cols([keys[:, c] for c in range(keys.shape[1])], n_bits)
 
 
-def _auto_block_multi(block: int, n_bits: int, lo_bits: int) -> int:
-    """Tile budget of the factored build: two one-hots of 2^(bits/2) width."""
-    nh = (1 << (n_bits - lo_bits)) + 1
-    nl = 1 << lo_bits
-    return max(8, min(block, (1 << 20) // (nh + nl)))
+def _pad_cols(keys: jnp.ndarray, valid: jnp.ndarray
+              ) -> list[jnp.ndarray]:
+    """Key columns and the int32 valid flag, each padded to whole tiles.
+
+    Each enters a kernel as its own 1-D operand: a (rows, w) tile with
+    w < 128 would be padded to 128 lanes in HBM and VMEM.  Pad rows are
+    invalid, so they land in the sentinel bucket.
+    """
+    pad = -keys.shape[0] % BLOCK
+    cols = [jnp.pad(keys[:, c], (0, pad)) for c in range(keys.shape[1])]
+    return cols + [jnp.pad(valid.astype(jnp.int32), (0, pad))]
+
+
+def _local_rank(d: jnp.ndarray, block: int) -> jnp.ndarray:
+    """(block,) count of earlier rows of the tile with the same bucket: a
+    strict-lower-triangular equality count, summed over sublanes so the
+    result comes out lane-major like `d`."""
+    eq = d[:, None] == d[None, :]                       # [i, j]: d_i == d_j
+    row = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    return jnp.sum((eq & (row < col)).astype(jnp.int32), axis=0)
 
 
 # ---------------------------------------------------------------------------
 # Pallas kernels
 # ---------------------------------------------------------------------------
 
-def _join_hash_kernel(keys_ref, valid_ref, out_ref, *, n_bits: int):
-    keys = keys_ref[...]                                    # (block, w)
-    v = valid_ref[...]                                      # (block,) int32
-    b = _hash_block(keys, n_bits)
-    out_ref[...] = jnp.where(v > 0, b, jnp.int32(1 << n_bits))
+def _join_hash_kernel(*refs, n_bits: int):
+    *key_refs, valid_ref, out_ref = refs
+    b = _hash_cols([r[...] for r in key_refs], n_bits)
+    out_ref[...] = jnp.where(valid_ref[...] > 0, b, jnp.int32(1 << n_bits))
 
 
-def _build_table_kernel(keys_ref, valid_ref, bkt_ref, rank_ref, hist_ref, *,
-                        n_bits: int, block: int):
+def _build_table_kernel(*refs, n_bits: int, block: int):
+    *key_refs, valid_ref, bkt_ref, rank_ref, hist_ref = refs
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    keys = keys_ref[...]                                    # (block, w)
-    v = valid_ref[...]                                      # (block,) int32
-    p1 = (1 << n_bits) + 1
-    d = jnp.where(v > 0, _hash_block(keys, n_bits), jnp.int32(1 << n_bits))
+    d = jnp.where(valid_ref[...] > 0,
+                  _hash_cols([r[...] for r in key_refs], n_bits),
+                  jnp.int32(1 << n_bits))                   # (block,)
     # Carried-histogram stable rank (the bucket_pack idiom): base from the
-    # running histogram, local from a strict-lower-triangular equality count.
-    carry = hist_ref[...]                                   # (P + 1,)
-    bins = jax.lax.broadcasted_iota(jnp.int32, (block, p1), 1)
-    oh = (d[:, None] == bins).astype(jnp.int32)
-    base = (oh * carry[None, :]).sum(axis=1)                # carry[d]
-    eq = d[:, None] == d[None, :]
-    row = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-    local = (eq & (col < row)).astype(jnp.int32).sum(axis=1)
+    # running histogram, held as a (P1, 1) column so the one-hot is
+    # (P1, block) and every sum runs over sublanes, local from the
+    # strict-lower-triangular equality count.
+    carry = hist_ref[...]                                   # (P1, 1)
+    bins = jax.lax.broadcasted_iota(jnp.int32, (carry.shape[0], block), 0)
+    oh = d[None, :] == bins                                 # (P1, block)
+    base = jnp.sum(jnp.where(oh, carry, 0), axis=0)         # carry[d]
     bkt_ref[...] = d
-    rank_ref[...] = base + local
-    hist_ref[...] = carry + oh.sum(axis=0)
+    rank_ref[...] = base + _local_rank(d, block)
+    hist_ref[...] = carry + jnp.sum(oh.astype(jnp.int32), axis=1,
+                                    keepdims=True)
 
 
-def _build_table_multi_kernel(keys_ref, valid_ref, bkt_ref, rank_ref,
-                              hist_ref, *, n_bits: int, lo_bits: int,
-                              block: int):
+# Byte limbs of the factored histogram's one-hot lookups (see below).
+_LIMBS = 4
+
+
+def _build_table_multi_kernel(*refs, n_bits: int, lo_bits: int, block: int):
     """The multi-pass (recursion-on-high-bits) build: bucket d splits into
-    hi = d >> lo_bits and lo = d & (2^lo_bits - 1), and the carried histogram
-    becomes the FACTORED (2^hi_bits + 1, 2^lo_bits) table C — carry lookup is
-    a (block, nh+1) @ C dot masked by the lo one-hot, accumulation is the
-    rank-1 update oh_hiᵀ @ oh_lo, both MXU dots.  VMEM per tile drops from
-    O(block · 2^bits) to O(block · 2^(bits/2)), lifting the ~2^14-bucket
-    single-pass cap.  The sentinel bucket P = 2^bits maps to the unique cell
-    (hi = 2^hi_bits, lo = 0) no valid row can reach, so ranks and histogram
-    stay bit-identical to the single-pass kernel."""
+    hi = d >> lo_bits and lo = d & (2^lo_bits - 1), and the carried
+    histogram becomes the FACTORED table Cᵀ (2^lo_bits, NH) with NH ≥
+    2^hi_bits + 1 — carry lookup is the dot Cᵀ @ oh_hi masked by the lo
+    one-hot, accumulation is the rank-1 update oh_lo @ oh_hiᵀ, both MXU dots.
+    VMEM per tile drops from O(block · 2^bits) to O(block · 2^(bits/2)).
+    The sentinel bucket P = 2^bits maps to the unique cell (hi = 2^hi_bits,
+    lo = 0) no valid row can reach, so ranks and histogram stay
+    bit-identical to the single-pass kernel.
+
+    The dots run in bf16 with f32 accumulation and are exact: one-hot
+    entries are 0 or 1; the lookup splits each count into `_LIMBS` bytes
+    (every byte ≤ 255 is exact in bf16) and each output sums exactly one
+    nonzero product per byte; the update sums at most `block` ones, exact
+    in f32 below 2^24.  Counts are int32, so 4 bytes cover every count."""
+    *key_refs, valid_ref, bkt_ref, rank_ref, hist_ref = refs
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    keys = keys_ref[...]                                    # (block, w)
-    v = valid_ref[...]                                      # (block,) int32
-    nh = 1 << (n_bits - lo_bits)
     nl = 1 << lo_bits
-    d = jnp.where(v > 0, _hash_block(keys, n_bits), jnp.int32(1 << n_bits))
+    d = jnp.where(valid_ref[...] > 0,
+                  _hash_cols([r[...] for r in key_refs], n_bits),
+                  jnp.int32(1 << n_bits))                   # (block,)
     hi = d >> lo_bits                                       # sentinel -> nh
     lo = d & (nl - 1)                                       # sentinel -> 0
-    C = hist_ref[...]                                       # (nh + 1, nl)
-    oh_hi = (hi[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block, nh + 1), 1)).astype(jnp.int32)
-    oh_lo = (lo[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block, nl), 1)).astype(jnp.int32)
-    tmp = jax.lax.dot_general(
-        oh_hi, C, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)                   # C[hi, :]
-    base = (tmp * oh_lo).sum(axis=1)                        # C[hi, lo]
-    eq = d[:, None] == d[None, :]
-    row = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-    local = (eq & (col < row)).astype(jnp.int32).sum(axis=1)
+    ct = hist_ref[...]                                      # (nl, NH)
+    oh_hi = (hi[None, :] == jax.lax.broadcasted_iota(
+        jnp.int32, (ct.shape[1], block), 0)).astype(jnp.bfloat16)
+    oh_lo = lo[None, :] == jax.lax.broadcasted_iota(
+        jnp.int32, (nl, block), 0)                          # (nl, block)
+    rows = jnp.zeros((nl, block), jnp.int32)                # C[hi_j, :]ᵀ
+    for b in range(_LIMBS):
+        limb = ((ct >> (8 * b)) & 0xFF).astype(jnp.bfloat16)
+        part = jax.lax.dot_general(
+            limb, oh_hi, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        rows = rows + (part.astype(jnp.int32) << (8 * b))
+    base = jnp.sum(jnp.where(oh_lo, rows, 0), axis=0)       # C[hi, lo]
     bkt_ref[...] = d
-    rank_ref[...] = base + local
-    hist_ref[...] = C + jax.lax.dot_general(
-        oh_hi, oh_lo, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    rank_ref[...] = base + _local_rank(d, block)
+    hist_ref[...] = ct + jax.lax.dot_general(
+        oh_lo.astype(jnp.bfloat16), oh_hi,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("n_bits", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_bits", "interpret"))
 def join_hash(keys: jnp.ndarray, valid: jnp.ndarray, *, n_bits: int,
-              block: int = DEFAULT_BLOCK, interpret: bool = False
-              ) -> jnp.ndarray:
+              interpret: bool = False) -> jnp.ndarray:
     """(n,) int32 bucket ids; invalid rows land in the sentinel bucket P.
 
     keys (n, w) int32; valid (n,) int32/bool — False rows get bucket
@@ -203,19 +227,17 @@ def join_hash(keys: jnp.ndarray, valid: jnp.ndarray, *, n_bits: int,
     n, w = keys.shape
     if n == 0:
         return jnp.zeros((0,), jnp.int32)
-    block = _auto_block(block, n_bits)
-    kp = jnp.pad(keys, ((0, -n % block), (0, 0)))
-    vp = jnp.pad(valid.astype(jnp.int32), (0, -n % block))
-    grid = (kp.shape[0] // block,)
+    ops = _pad_cols(keys, valid)
+    n_p = ops[0].shape[0]
+    tile = pl.BlockSpec((BLOCK,), lambda i: (i,))
     out = pl.pallas_call(
         functools.partial(_join_hash_kernel, n_bits=n_bits),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block, w), lambda i: (i, 0)),
-                  pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((kp.shape[0],), jnp.int32),
+        grid=(n_p // BLOCK,),
+        in_specs=[tile] * (w + 1),
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((n_p,), jnp.int32),
         interpret=interpret,
-    )(kp, vp)
+    )(*ops)
     return out[:n]
 
 
@@ -229,11 +251,10 @@ def join_hash_host(keys: jnp.ndarray, valid: jnp.ndarray, *, n_bits: int
                      jnp.int32(1 << n_bits))
 
 
-@functools.partial(jax.jit, static_argnames=("n_bits", "block", "multi_pass",
+@functools.partial(jax.jit, static_argnames=("n_bits", "multi_pass",
                                              "interpret"))
 def build_table(keys: jnp.ndarray, valid: jnp.ndarray, *, n_bits: int,
-                block: int = DEFAULT_BLOCK, multi_pass: bool | None = None,
-                interpret: bool = False
+                multi_pass: bool | None = None, interpret: bool = False
                 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """(bucket (n,), rank (n,), hist (P,)) — hash + stable rank in ONE pass.
 
@@ -245,7 +266,7 @@ def build_table(keys: jnp.ndarray, valid: jnp.ndarray, *, n_bits: int,
     `multi_pass` selects the factored two-level histogram (recursion on the
     high hash bits) of `_build_table_multi_kernel`; the default (None) picks
     it automatically once `n_bits` exceeds `SINGLE_PASS_BITS` — where the
-    single-pass one-hot would force tiny tiles.  Outputs are bit-identical
+    single-pass one-hot would outgrow VMEM.  Outputs are bit-identical
     either way.
     """
     n, w = keys.shape
@@ -259,42 +280,35 @@ def build_table(keys: jnp.ndarray, valid: jnp.ndarray, *, n_bits: int,
     if multi_pass:
         lo_bits = n_bits // 2
         nh, nl = 1 << (n_bits - lo_bits), 1 << lo_bits
-        block = _auto_block_multi(block, n_bits, lo_bits)
+        # Cᵀ: (nl, NH) with the sentinel hi row nh inside, NH lane-aligned.
+        hist_shape = (nl, nh + 1 + (-(nh + 1) % 128))
         kernel = functools.partial(_build_table_multi_kernel, n_bits=n_bits,
-                                   lo_bits=lo_bits, block=block)
-        hist_spec = pl.BlockSpec((nh + 1, nl), lambda i: (0, 0))
-        hist_shape = jax.ShapeDtypeStruct((nh + 1, nl), jnp.int32)
+                                   lo_bits=lo_bits, block=BLOCK)
     else:
-        block = _auto_block(block, n_bits)
+        hist_shape = (p + 1 + (-(p + 1) % 8), 1)            # (P1, 1) column
         kernel = functools.partial(_build_table_kernel, n_bits=n_bits,
-                                   block=block)
-        hist_spec = pl.BlockSpec((p + 1,), lambda i: (0,))
-        hist_shape = jax.ShapeDtypeStruct((p + 1,), jnp.int32)
-    kp = jnp.pad(keys, ((0, -n % block), (0, 0)))
-    vp = jnp.pad(valid.astype(jnp.int32), (0, -n % block))  # pads -> sentinel
-    grid = (kp.shape[0] // block,)
+                                   block=BLOCK)
+    ops = _pad_cols(keys, valid)                            # pads -> sentinel
+    n_p = ops[0].shape[0]
+    tile = pl.BlockSpec((BLOCK,), lambda i: (i,))
     bkt, rank, hist = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block, w), lambda i: (i, 0)),
-                  pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=(
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            hist_spec,                                      # revisited carry
-        ),
+        grid=(n_p // BLOCK,),
+        in_specs=[tile] * (w + 1),
+        out_specs=(tile, tile,
+                   pl.BlockSpec(hist_shape, lambda i: (0, 0))),  # carry
         out_shape=(
-            jax.ShapeDtypeStruct((kp.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((kp.shape[0],), jnp.int32),
-            hist_shape,
+            jax.ShapeDtypeStruct((n_p,), jnp.int32),
+            jax.ShapeDtypeStruct((n_p,), jnp.int32),
+            jax.ShapeDtypeStruct(hist_shape, jnp.int32),
         ),
         interpret=interpret,
-    )(kp, vp)
+    )(*ops)
     if multi_pass:
-        # Drop the sentinel row (hi = nh); valid buckets are hi·nl + lo, so
-        # the row-major reshape IS the flat (P,) histogram.
-        return bkt[:n], rank[:n], hist[:nh].reshape(p)
-    return bkt[:n], rank[:n], hist[:p]
+        # Valid buckets are hi·nl + lo: the first nh columns of Cᵀ,
+        # transposed and flattened row-major, ARE the flat (P,) histogram.
+        return bkt[:n], rank[:n], hist[:, :nh].T.reshape(p)
+    return bkt[:n], rank[:n], hist[:p, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("n_bits",))
@@ -347,8 +361,9 @@ def _chain_probe(lk: jnp.ndarray, rk: jnp.ndarray, perm1: jnp.ndarray,
     are 0 and never gathered).
 
     One `lax.while_loop` round follows every partition's collision chain one
-    link: the partition's first unresolved row (found scatter-free with a
-    cumulative-count + searchsorted trick) is the round's representative;
+    link: the partition's first unresolved row (found scatter-free as the
+    nearest unresolved row at or after the partition start, one reverse
+    running minimum) is the round's representative;
     right rows with keys exactly equal to it resolve into one contiguous
     group of final slots read straight off the round's prefix sum
     (partitions are contiguous in packed order, so prefix-sum order IS
@@ -373,6 +388,8 @@ def _chain_probe(lk: jnp.ndarray, rk: jnp.ndarray, perm1: jnp.ndarray,
     # keys from the neighbouring partition s_l points into never equal it.
     cnt0 = jnp.zeros((n_l,), jnp.int32)
     lo0 = jnp.zeros((n_l,), jnp.int32)
+    idx = jnp.arange(n_r, dtype=jnp.int32)
+    last_l = jnp.clip(rend[s_l] - 1, 0, n_r - 1)            # loop-invariant
 
     def cond(state):
         fpos, _cnt, _lo, _total = state
@@ -381,12 +398,10 @@ def _chain_probe(lk: jnp.ndarray, rk: jnp.ndarray, perm1: jnp.ndarray,
     def body(state):
         fpos, cnt, lo, total = state
         unres = fpos < 0
-        # Per packed row, its partition's first unresolved row: the
-        # (count-before-partition + 1)-th unresolved row globally.
-        cu = jnp.cumsum(unres.astype(jnp.int32))            # inclusive
-        base_u = jnp.where(rstart > 0, cu[jnp.clip(rstart - 1, 0, n_r - 1)],
-                           0)
-        pos = jnp.searchsorted(cu, base_u + 1, side="left")
+        # Per packed row, its partition's first unresolved row: the nearest
+        # unresolved row at or after the partition start.
+        nxt = jax.lax.cummin(jnp.where(unres, idx, n_r), reverse=True)
+        pos = nxt[rstart]
         rep = jnp.where(pos < rend, pos, n_r)               # (n_r,) per row
         mask = unres & (pk == pk[jnp.clip(rep, 0, n_r - 1)]).all(axis=1)
         rep_l = rep[s_l]                                    # left partitions
@@ -397,7 +412,7 @@ def _chain_probe(lk: jnp.ndarray, rk: jnp.ndarray, perm1: jnp.ndarray,
         # already grouped (≤ 1 resolving group per partition per round).
         pcm = jnp.cumsum(mask.astype(jnp.int32))            # inclusive
         base_l = jnp.where(s_l > 0, pcm[jnp.clip(s_l - 1, 0, n_r - 1)], 0)
-        reach_l = pcm[jnp.clip(rend[s_l] - 1, 0, n_r - 1)]
+        reach_l = pcm[last_l]
         fpos = jnp.where(mask, total + pcm - 1, fpos)
         cnt = jnp.where(hit, reach_l - base_l, cnt)
         lo = jnp.where(hit, total + base_l, lo)
@@ -453,11 +468,10 @@ def probe_tables(lk: jnp.ndarray, l_bkt: jnp.ndarray, rk: jnp.ndarray,
                         fpos0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_bits", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_bits", "interpret"))
 def join_probe(lk: jnp.ndarray, l_valid: jnp.ndarray, rk: jnp.ndarray,
                r_valid: jnp.ndarray, *, n_bits: int | None = None,
-               block: int = DEFAULT_BLOCK, interpret: bool = False
+               interpret: bool = False
                ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Radix hash join via the Pallas kernels: (counts, lo, perm).
 
@@ -466,8 +480,8 @@ def join_probe(lk: jnp.ndarray, l_valid: jnp.ndarray, rk: jnp.ndarray,
     resolution stays exact, only the chains deepen).
     """
     bits = n_bits or default_bits(rk.shape[0])
-    bl = join_hash(lk, l_valid, n_bits=bits, block=block, interpret=interpret)
-    br, rank, hist = build_table(rk, r_valid, n_bits=bits, block=block,
+    bl = join_hash(lk, l_valid, n_bits=bits, interpret=interpret)
+    br, rank, hist = build_table(rk, r_valid, n_bits=bits,
                                  interpret=interpret)
     return probe_tables(lk, bl, rk, br, rank, hist, bits)
 
@@ -478,38 +492,25 @@ def join_probe_host(lk: jnp.ndarray, l_valid: jnp.ndarray, rk: jnp.ndarray,
                     ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """`join_probe` on the vectorized-XLA twins (non-TPU hot path).
 
-    The packed table comes from ONE plain unstable sort of the fused
-    (hash, arrival) word — distinct words make it order-stable for free, the
-    sorted word IS (partition, original row), partition bounds fall out of
+    The packed table comes from ONE sort of (hash, arrival) pairs: the
+    sorted pairs ARE (partition, original row), partition bounds fall out of
     two run scans, and left rows locate their partition with a single-column
-    searchsorted: no union buffer, no multi-column lexsort, no stable
-    argsort, no scatter.  The hash takes every bit the word can spare
-    (30 - ceil(log2 n_r); invalid rows ride above bit 30, sorting last), so
-    partitions are far finer than the kernel's histogram table and the chain
-    loop converges in O(1) rounds; an explicit tiny `n_bits` still forces
-    deep chains for the collision tests.  Degenerate giant inputs
-    (n_r ≥ 2^29) fall back to the `build_table_host` twin.
+    searchsorted: no union buffer, no multi-column lexsort, no scatter.  The
+    hash keeps `HOST_BITS` bits (invalid rows take the sentinel above them,
+    sorting last), so partitions are far finer than the kernel's histogram
+    table and the chain loop converges in O(1) rounds at any n_r; an
+    explicit tiny `n_bits` still forces deep chains for the collision tests.
     """
     n_l, n_r = lk.shape[0], rk.shape[0]
     if n_r == 0:
         return (jnp.zeros((n_l,), jnp.int32), jnp.zeros((n_l,), jnp.int32),
                 jnp.zeros((0,), jnp.int32))
-    idx_bits = max(n_r - 1, 1).bit_length()
-    if 30 - idx_bits < 1:
-        bits = n_bits or default_bits(n_r)
-        bl = join_hash_host(lk, l_valid, n_bits=bits)
-        br, rank, hist = build_table_host(rk, r_valid, n_bits=bits)
-        return probe_tables(lk, bl, rk, br, rank, hist, bits)
-    bits = min(n_bits, 30 - idx_bits) if n_bits else 30 - idx_bits
+    bits = min(n_bits or HOST_BITS, HOST_BITS)
     qidx = jnp.arange(n_r, dtype=jnp.int32)
     hw_l = _hash_block(lk, bits)
     hw_r = _hash_block(rk, bits)
-    word = jnp.where(r_valid.astype(bool),
-                     (hw_r << idx_bits)
-                     | qidx, jnp.int32(1 << 30) | qidx)
-    sword = jnp.sort(word)
-    perm1 = sword & ((1 << idx_bits) - 1)                   # packed -> orig
-    rid = sword >> idx_bits                  # partitions; invalid ride last
+    key = jnp.where(r_valid.astype(bool), hw_r, jnp.int32(1 << bits))
+    rid, perm1 = jax.lax.sort((key, qidx), num_keys=2)      # packed -> orig
     rstart, rend = _run_bounds(rid)
     fpos0 = jnp.where(rid >= (1 << bits), qidx, jnp.int32(-1))
     s_l = jnp.searchsorted(rid, hw_l, side="left")

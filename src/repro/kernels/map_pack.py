@@ -54,6 +54,8 @@ from .ref import MULT
 # Copies (row × route-rep) per Pallas tile: the (copies, copies) triangular
 # rank matrix and the (copies, k) fold one-hot must both fit VMEM.
 DEFAULT_BLOCK_COPIES = 256
+# Rows per `map_count` tile: 1-D int32 operands live in tiles of 1024 on TPU.
+COUNT_BLOCK = 1024
 INVALID = -1
 
 # One route = (hashed, rep_strides, offset, eq_constraints, notin_constraints)
@@ -68,39 +70,49 @@ def route_fanout(routes: RouteSpec) -> int:
     return sum(len(reps) for _, reps, _, _, _ in routes)
 
 
-def _route_block(rows: jnp.ndarray, routes: RouteSpec, k: int
-                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(logical (n, F) int32, valid (n, F) bool) for a block of rows.
+def _route_cols(cols, routes: RouteSpec, k: int
+                ) -> tuple[list[jnp.ndarray], list[jnp.ndarray]]:
+    """Per copy, (logical int32, member bool) over the rows of `cols`.
 
-    Shared by the kernel body and the host twin: pure jnp, constraints and
-    hashes unrolled from the static recipe, logical ids masked to INVALID on
-    non-members.  Flattening axis 1 reproduces the staged `_route_relation`
-    copy order (routes concatenated, reps in rep_strides order).
+    `cols[c]` holds column c of a block of rows, in any shape shared by all
+    columns; every output has that shape.  Shared by the kernel bodies and
+    the host twins: pure jnp, constraints and hashes unrolled from the static
+    recipe, logical ids masked to INVALID on non-members.  The copy order
+    (routes concatenated, reps in rep_strides order) reproduces the staged
+    `_route_relation` order.
     """
-    n = rows.shape[0]
-    member_base = rows[:, 0] != INVALID
+    member_base = cols[0] != INVALID
     logical_cols, valid_cols = [], []
     for hashed, reps, offset, eqs, notins in routes:
         member = member_base
         for col, val in eqs:
-            member &= rows[:, col] == val
+            member &= cols[col] == val
         for col, vals in notins:
             for v in vals:                      # tiny static HH set: unroll
-                member &= rows[:, col] != v
-        base = jnp.zeros((n,), jnp.int32)
+                member &= cols[col] != v
+        base = jnp.zeros_like(cols[0])
         for col, seed, share, stride in hashed:
             if share == 1:
                 continue
             b = share.bit_length() - 1
-            h = (rows[:, col].astype(jnp.uint32) * jnp.uint32(seed)) \
+            h = (cols[col].astype(jnp.uint32) * jnp.uint32(seed)) \
                 * jnp.uint32(MULT)
             base = base + (h >> jnp.uint32(32 - b)).astype(jnp.int32) * stride
         for r in reps:
             logical_cols.append(
                 jnp.where(member, base + (r + offset), INVALID))
             valid_cols.append(member)
-    logical = jnp.stack(logical_cols, axis=1)
-    return logical, jnp.stack(valid_cols, axis=1)
+    return logical_cols, valid_cols
+
+
+def _route_block(rows: jnp.ndarray, routes: RouteSpec, k: int
+                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(logical (n, F) int32, valid (n, F) bool) for a block of rows: the
+    copies of `_route_cols` stacked along axis 1, so flattening axis 1 gives
+    the staged copy order."""
+    logical, valid = _route_cols([rows[:, c] for c in range(rows.shape[1])],
+                                 routes, k)
+    return jnp.stack(logical, axis=1), jnp.stack(valid, axis=1)
 
 
 def _assemble_tagged(rows: jnp.ndarray, tag: jnp.ndarray, d: jnp.ndarray,
@@ -189,35 +201,36 @@ def _map_pack_kernel(rows_ref, table_ref, d_ref, tag_ref, rank_ref, hist_ref,
     hist_ref[...] = carry + oh_d.sum(axis=0)
 
 
-def _map_count_kernel(rows_ref, counts_ref, *, routes, k, n_src,
-                      rows_per_src, block):
+def _map_count_kernel(*refs, routes, k, n_src, rows_per_src, block):
+    *col_refs, counts_ref = refs
     b = pl.program_id(0)
 
     @pl.when(b == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    rows = rows_ref[...]                                    # (block, w)
-    logical, valid = _route_block(rows, routes, k)          # (block, F)
-    fanout = logical.shape[1]
-    wrapped = jnp.where(valid, logical % k, 0)
-    # Per-row wrapped-cell histogram C (block, k), summed over the F copies.
-    oh_c = (wrapped[:, :, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block, fanout, k), 2)) & valid[:, :, None]
-    cnt = oh_c.astype(jnp.int32).sum(axis=1)                # (block, k)
-    # Source-device one-hot S (block, n_src): src beyond range matches no bin.
-    idx = b * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
-    src = idx // rows_per_src                               # (block, 1)
-    oh_s = (src == jax.lax.broadcasted_iota(
-        jnp.int32, (block, n_src), 1)).astype(jnp.int32)
-    counts_ref[...] += jax.lax.dot_general(
-        oh_s, cnt, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)                   # S^T @ C
+    # One lane-dense (block,) vector per column: no (block, w) row tile,
+    # whose w < 128 lanes would be padded to 128 in HBM and VMEM.
+    logical, valid = _route_cols([r[...] for r in col_refs], routes, k)
+    # Per-row wrapped-cell histogram (block, k), summed over the F copies.
+    cells = jax.lax.broadcasted_iota(jnp.int32, (block, k), 1)
+    cnt = jnp.zeros((block, k), jnp.int32)
+    for lg, v in zip(logical, valid):
+        wrapped = jnp.where(v, lg % k, -1)      # non-members match no cell
+        cnt = cnt + (wrapped[:, None] == cells).astype(jnp.int32)
+    # Fold rows onto their source device with a masked sublane sum per
+    # source (VPU only; src beyond range matches no source).
+    idx = b * block + jax.lax.broadcasted_iota(jnp.int32, (block, k), 0)
+    src = idx // rows_per_src
+    for s in range(n_src):
+        counts_ref[s:s + 1, :] += jnp.sum(jnp.where(src == s, cnt, 0),
+                                          axis=0, keepdims=True)
 
 
 def _row_block(fanout: int, block_copies: int) -> int:
-    """Rows per tile so copies-per-tile stays near the VMEM budget."""
-    return max(1, block_copies // max(fanout, 1))
+    """Rows per tile so copies-per-tile stays near the VMEM budget, in
+    multiples of 8 (the TPU's sublane tiling)."""
+    return max(8, (block_copies // max(fanout, 1)) // 8 * 8)
 
 
 @functools.partial(jax.jit, static_argnames=("routes", "k", "n_dev", "cap",
@@ -294,9 +307,8 @@ def map_pack_host(rows: jnp.ndarray, ptable: jnp.ndarray, *,
 
 
 @functools.partial(jax.jit, static_argnames=("routes", "k", "n_src",
-                                             "block_copies", "interpret"))
+                                             "interpret"))
 def map_count(rows: jnp.ndarray, *, routes: RouteSpec, k: int, n_src: int,
-              block_copies: int = DEFAULT_BLOCK_COPIES,
               interpret: bool = False) -> jnp.ndarray:
     """Counting mode: (n_src, k) int32 routed copies per (source, cell).
 
@@ -304,13 +316,14 @@ def map_count(rows: jnp.ndarray, *, routes: RouteSpec, k: int, n_src: int,
     stripped out — rows [i·(n/n_src), (i+1)·(n/n_src)) count as source i,
     matching the executor's sharded layout.  No placement table needed: the
     histogram is over wrapped LOGICAL cells, exactly what LPT placement and
-    the capacity fold consume.
+    the capacity fold consume.  Each column enters as its own 1-D operand in
+    tiles of COUNT_BLOCK rows, the tiling the TPU keeps 1-D int32 arrays in.
     """
-    n, _ = rows.shape
+    n, w = rows.shape
     fanout = route_fanout(routes)
     if n == 0 or fanout == 0:
         return jnp.zeros((n_src, k), jnp.int32)
-    block = _row_block(fanout, block_copies)
+    block = COUNT_BLOCK
     rows_p = jnp.pad(rows, ((0, -n % block), (0, 0)),
                      constant_values=INVALID)
     grid = (rows_p.shape[0] // block,)
@@ -318,11 +331,11 @@ def map_count(rows: jnp.ndarray, *, routes: RouteSpec, k: int, n_src: int,
         functools.partial(_map_count_kernel, routes=routes, k=k, n_src=n_src,
                           rows_per_src=max(n // n_src, 1), block=block),
         grid=grid,
-        in_specs=[pl.BlockSpec((block, rows.shape[1]), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((block,), lambda i: (i,))] * w,
         out_specs=pl.BlockSpec((n_src, k), lambda i: (0, 0)),  # carry block
         out_shape=jax.ShapeDtypeStruct((n_src, k), jnp.int32),
         interpret=interpret,
-    )(rows_p)
+    )(*(rows_p[:, c] for c in range(w)))
 
 
 @functools.partial(jax.jit, static_argnames=("routes", "k", "n_src"))

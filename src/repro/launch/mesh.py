@@ -13,53 +13,22 @@ all-reduce (DCN), which the multi-pod dry-run proves shardable.
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axes):
-    """`jax.make_mesh` across jax versions.
-
-    Newer jax grew an `axis_types` kwarg (and `jax.sharding.AxisType`); older
-    releases have neither and default to Auto axes anyway.  All mesh creation
-    in this repo goes through here so the executor/tests run on both.
-    """
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """`jax.shard_map` across jax versions.
-
-    Older jax only has `jax.experimental.shard_map.shard_map`; the replication
-    check is called `check_rep` before the VMA rename and `check_vma` after —
-    and mid versions export top-level `jax.shard_map` still with `check_rep`.
-    Both are disabled here — the executor's collectives are explicit.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    for kwarg in ("check_vma", "check_rep"):
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{kwarg: False})
-        except TypeError:
-            continue
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(devices: int | None = None, model: int = 4):
     """Small mesh over whatever devices exist (tests / examples)."""
     n = devices or len(jax.devices())
     model = min(model, n)
-    return make_mesh_compat((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # TPU v5e hardware constants used by the roofline analysis (per chip).

@@ -71,6 +71,7 @@ class JoinRequest:
     data: Mapping[str, np.ndarray]
     bucket: tuple[int, ...] | None = None   # per-relation padded row counts
     rows: np.ndarray | None = None          # valid join rows, set when done
+    recv_counts: np.ndarray | None = None   # rows each device received
     latency_s: float = 0.0
     done: bool = False
 
@@ -296,6 +297,7 @@ class JoinServingEngine:
         rows = np.asarray(res["rows"])[np.asarray(res["valid"])]
         req.latency_s = time.perf_counter() - t0
         req.rows, req.done = rows, True
+        req.recv_counts = np.asarray(res["recv_counts"])
         s1 = ses.stats
         st = t.stats
         st["requests"] += 1

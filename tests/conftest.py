@@ -15,13 +15,8 @@ if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=8").strip()
 
-# Keep hypothesis fast on the 1-core container.  When hypothesis is absent the
-# suite must still load: property tests import the skip-stub in
-# tests/_hypothesis_stub.py instead.
-try:
-    from hypothesis import settings
-except ModuleNotFoundError:
-    settings = None
-if settings is not None:
-    settings.register_profile("ci", max_examples=20, deadline=None)
-    settings.load_profile("ci")
+# Keep hypothesis fast on the CPU container.
+from hypothesis import settings  # noqa: E402
+
+settings.register_profile("ci", max_examples=20, deadline=None)
+settings.load_profile("ci")

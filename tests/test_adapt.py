@@ -243,8 +243,8 @@ E2E_POL = AdaptPolicy(replace_threshold=0.02, replan_threshold=0.07,
 
 
 def _mesh():
-    from repro.launch.mesh import make_mesh_compat
-    return make_mesh_compat((N_DEV,), ("cells",))
+    from jax.sharding import AxisType
+    return jax.make_mesh((N_DEV,), ("cells",), axis_types=(AxisType.Auto,))
 
 
 def _hot_sets(plan):

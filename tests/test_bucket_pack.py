@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.executor import _pack_buckets_argsort
 from repro.kernels import bucket_pack as bp
 from repro.kernels import ops as kops

@@ -1,7 +1,7 @@
 """Dominance rule + Theorem 5.1 (auxiliary attributes get share 1)."""
 import numpy as np
 import pytest
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 
 from repro.core import (JoinQuery, Relation, cost_expression,

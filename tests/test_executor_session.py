@@ -13,8 +13,8 @@ pytestmark = pytest.mark.skipif(
 
 
 def _mesh():
-    from repro.launch.mesh import make_mesh_compat
-    return make_mesh_compat((8,), ("cells",))
+    from jax.sharding import AxisType
+    return jax.make_mesh((8,), ("cells",), axis_types=(AxisType.Auto,))
 
 
 def _executor(data, q, **cfg_kw):

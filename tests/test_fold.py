@@ -24,8 +24,8 @@ N_DEV = 8
 
 
 def _mesh():
-    from repro.launch.mesh import make_mesh_compat
-    return make_mesh_compat((N_DEV,), ("cells",))
+    from jax.sharding import AxisType
+    return jax.make_mesh((N_DEV,), ("cells",), axis_types=(AxisType.Auto,))
 
 
 def _check_exact(q, data, ex, placement=None):

@@ -11,14 +11,15 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.ckpt.checkpoint import Checkpointer
 from repro.ft import (HealthMonitor, NodeState, StragglerWatchdog,
                       elastic_remesh, survivors_mesh)
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices")
 
 
 def _mesh(shape=(4, 2)):
-    return make_mesh_compat(shape, ("data", "model"))
+    return jax.make_mesh(shape, ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _tree(mesh):
@@ -52,7 +53,8 @@ def test_checkpoint_reshard_to_smaller_mesh(tmp_path):
     ck = Checkpointer(str(tmp_path))
     ck.save(3, tree, blocking=True)
 
-    small = make_mesh_compat((2, 2), ("data", "model"))
+    small = jax.make_mesh((2, 2), ("data", "model"),
+                          axis_types=(AxisType.Auto,) * 2)
     sh2 = {"w": NamedSharding(small, P("data", "model")),
            "b": NamedSharding(small, P()),
            "step": NamedSharding(small, P())}

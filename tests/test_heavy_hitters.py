@@ -10,7 +10,7 @@ the hypothesis versions widen the search when hypothesis is installed.
 """
 import numpy as np
 import pytest
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import MisraGries, exact_heavy_hitters, two_way
 from repro.core.heavy_hitters import _reduce_counters

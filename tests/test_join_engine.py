@@ -6,7 +6,7 @@ import pytest
 from repro.core import canonical, reference_join, two_way
 from repro.core.adapt import AdaptPolicy, TenantDriftBank
 from repro.data import mixed_workload, skewed_join_dataset
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 from repro.serve import JoinServingEngine
 
 pytestmark = pytest.mark.skipif(
@@ -14,7 +14,7 @@ pytestmark = pytest.mark.skipif(
 
 
 def _mesh():
-    return make_mesh_compat((8,), ("cells",))
+    return jax.make_mesh((8,), ("cells",), axis_types=(AxisType.Auto,))
 
 
 def _engine(**kw):

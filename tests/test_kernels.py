@@ -2,7 +2,7 @@
 import numpy as np
 import pytest
 import jax.numpy as jnp
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 
 from repro.kernels import ops, ref

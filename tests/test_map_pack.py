@@ -15,7 +15,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.executor import (_Route, _build_routes, _count_matrix,
                                  _fold_dests, _pack_buckets, _route_relation,
                                  _route_specs)
@@ -226,8 +226,8 @@ def test_executor_fused_vs_staged_bit_identical():
     from repro.core import canonical, plan_skew_join, reference_join, two_way
     from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
     from repro.data import skewed_join_dataset
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((8,), ("cells",))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((8,), ("cells",), axis_types=(AxisType.Auto,))
     q = two_way()
     data = skewed_join_dataset(q, 600, 50, skew={"B": 1.6}, seed=42)
     plan = plan_skew_join(q, data, 32)
@@ -254,11 +254,12 @@ def test_prepare_skips_count_pass_when_given_everything():
     from repro.core import plan_skew_join, two_way
     from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
     from repro.data import skewed_join_dataset
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
     q = two_way()
     data = skewed_join_dataset(q, 200, 30, seed=43)
     plan = plan_skew_join(q, data, 8)
-    ex = ShardedJoinExecutor(plan, make_mesh_compat((8,), ("cells",)),
+    mesh = jax.make_mesh((8,), ("cells",), axis_types=(AxisType.Auto,))
+    ex = ShardedJoinExecutor(plan, mesh,
                              config=ExecutorConfig(out_capacity=1 << 16))
     s = ex.session().prepare(data, caps={r.name: 512 for r in q.relations},
                              placement=modulo_placement(8, 8))

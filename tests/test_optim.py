@@ -7,7 +7,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.optim import AdamWConfig, adamw, grad_compress
 from repro.optim.schedule import warmup_cosine
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 
 
 def _quad_problem():
@@ -101,7 +101,7 @@ def test_error_feedback_accumulates():
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 virtual devices")
 def test_compressed_psum_in_shard_map():
     """compressed_psum ≈ psum across a manual mesh axis (the cross-pod hop)."""
-    mesh = make_mesh_compat((8,), ("pod",))
+    mesh = jax.make_mesh((8,), ("pod",), axis_types=(AxisType.Auto,))
     rng = np.random.default_rng(2)
     xs = jnp.asarray(rng.normal(size=(8, 32)), jnp.float32)
     errs = jnp.zeros((8, 32), jnp.float32)
@@ -110,10 +110,9 @@ def test_compressed_psum_in_shard_map():
         total, new_e = grad_compress.compressed_psum(x[0], "pod", e[0])
         return total[None], new_e[None]
 
-    from repro.launch.mesh import shard_map_compat
-    out, _ = jax.jit(shard_map_compat(
+    out, _ = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-        out_specs=(P("pod"), P("pod"))))(xs, errs)
+        out_specs=(P("pod"), P("pod")), check_vma=False))(xs, errs)
     expect = np.asarray(xs).sum(axis=0)
     # each device holds the same decompressed sum
     got = np.asarray(out)

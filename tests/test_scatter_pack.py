@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.executor import _Route, _probe_sort, _route_specs
 from repro.core.placement import lpt_placement, modulo_placement
 from repro.kernels import map_pack as mp

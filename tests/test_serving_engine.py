@@ -9,14 +9,15 @@ from repro.models import api
 from repro.models.common import init_params
 from repro.serve import ServingEngine
 from repro.serve.serve_step import build_decode_step
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices")
 
 
 def _mesh():
-    return make_mesh_compat((2, 4), ("data", "model"))
+    return jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _engine(slots=4, max_seq=48, name="qwen2-0.5b"):

@@ -3,7 +3,7 @@ import math
 
 import numpy as np
 import pytest
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 
 from repro.core import (JoinQuery, Relation, brute_force_shares,

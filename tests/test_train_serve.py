@@ -11,14 +11,15 @@ from repro.models import api, common as C
 from repro.optim import AdamWConfig
 from repro.serve import build_decode_step, build_prefill
 from repro.train import build_train_step
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices")
 
 
 def _mesh():
-    return make_mesh_compat((2, 4), ("data", "model"))
+    return jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _setup(name, **overrides):
