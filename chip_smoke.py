@@ -113,7 +113,7 @@ def smoke(devices, seed: int, rows: int = ROWS) -> None:
         log(f"request {req.rid} {tenant}: {query}; heavy hitters {hhs}; "
             f"{len(plan.residuals)} residual joins; cascade sizes {sizes}; "
             f"output rows {len(got)}; received per device {recv}; "
-            f"served in {req.latency_s:.1f} s; "
+            f"served in {req.finished_at - req.started_at:.1f} s; "
             f"{'row-exact' if exact else 'MISMATCH'} vs reference_join")
         if not exact:
             raise RuntimeError(f"request {req.rid} ({tenant}) differs from "

@@ -76,6 +76,15 @@ from .skewjoin import SkewJoinPlan
 INVALID = -1
 
 
+def span(name: str, rid: int | None = None) -> jax.profiler.TraceAnnotation:
+    """A host span on the profiler's clock, carrying the request id as its
+    `rid` stat.  It costs about a microsecond when no trace is active and
+    never synchronizes with the device (docs/architecture.md, "Tracing")."""
+    if rid is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, rid=rid)
+
+
 # ---------------------------------------------------------------------------
 # Error taxonomy (the fault-tolerance layer's structured failures)
 # ---------------------------------------------------------------------------
@@ -757,30 +766,40 @@ class ShardedJoinExecutor:
         C = max(int(cfg.overlap_shuffle), 1)
 
         def step(ptable, *arrs):
+            # Each phase runs under a named scope (`phase_map`,
+            # `phase_exchange`, `phase_reduce`) that the compiled operations'
+            # `op_name` carries, so a profile credits them to their phase
+            # whatever their kernels are called.
             local = {r.name: a for r, a in zip(query.relations, arrs)}
             frags, overs = {}, []
             recv_count = jnp.int32(0)
 
             def pack_one(rows_in, rel_name):
-                if cfg.use_kernels and cfg.fuse_map:
-                    # Megakernel: route -> fold -> scatter assemble, one
-                    # streaming pass writing the send buffer directly.
-                    return kops.scatter_pack(rows_in, specs[rel_name], ptable,
-                                             k, n_dev, caps[rel_name])
-                # Staged oracle path (and the pure-jnp ref path).
-                dest, rows = _route_relation(rows_in, routes[rel_name],
-                                             cfg.use_kernels)
-                phys = _fold_dests(dest, ptable, cfg.use_kernels)
-                return _pack_buckets(phys, rows, n_dev, caps[rel_name],
-                                     cfg.use_kernels)
+                with jax.named_scope("phase_map"):
+                    if cfg.use_kernels and cfg.fuse_map:
+                        # Megakernel: route -> fold -> scatter assemble, one
+                        # streaming pass writing the send buffer directly.
+                        return kops.scatter_pack(rows_in, specs[rel_name],
+                                                 ptable, k, n_dev,
+                                                 caps[rel_name])
+                    # Staged oracle path (and the pure-jnp ref path).
+                    dest, rows = _route_relation(rows_in, routes[rel_name],
+                                                 cfg.use_kernels)
+                    phys = _fold_dests(dest, ptable, cfg.use_kernels)
+                    return _pack_buckets(phys, rows, n_dev, caps[rel_name],
+                                         cfg.use_kernels)
+
+            def exchange(buf):
+                with jax.named_scope("phase_exchange"):
+                    recv = jax.lax.all_to_all(buf, self.axis, split_axis=0,
+                                              concat_axis=0, tiled=True)
+                    return recv.reshape(-1, recv.shape[-1])
 
             for rel in query.relations:
                 rows_loc = local[rel.name]
                 if C <= 1:
                     buf, over = pack_one(rows_loc, rel.name)
-                    recv = jax.lax.all_to_all(buf, self.axis, split_axis=0,
-                                              concat_axis=0, tiled=True)
-                    frag = recv.reshape(-1, recv.shape[-1])
+                    frag = exchange(buf)
                 else:
                     # Chunked overlap: C tile-sized packs, each followed by
                     # its own all_to_all.  pack(i+1) has no data dependency
@@ -805,22 +824,23 @@ class ShardedJoinExecutor:
                             jax.lax.slice_in_dim(rows_loc, ci * tile,
                                                  (ci + 1) * tile, axis=0),
                             rel.name)
-                        recv = jax.lax.all_to_all(cbuf, self.axis,
-                                                  split_axis=0, concat_axis=0,
-                                                  tiled=True)
-                        parts.append(recv.reshape(-1, recv.shape[-1]))
+                        parts.append(exchange(cbuf))
                         chunk_overs.append(cover)
                     over = jnp.stack(chunk_overs).sum()
-                    frag = jnp.concatenate(parts, axis=0)
+                    with jax.named_scope("phase_exchange"):
+                        frag = jnp.concatenate(parts, axis=0)
                 overs.append(over)
-                recv_count = recv_count + (frag[:, -1] != INVALID).sum()
+                with jax.named_scope("phase_exchange"):
+                    recv_count = recv_count + (frag[:, -1] != INVALID).sum()
                 frags[rel.name] = frag
             # Per-relation overflow vector: the per-(device, phase, relation)
             # coordinates CapacityOverflowError and targeted retry need.
             sh_over = jnp.stack(overs)
-            out, valid, j_over = _local_join(frags, query, cap_out,
-                                             cfg.use_kernels, cfg.hash_reduce,
-                                             cfg.hash_bits)
+            with jax.named_scope("phase_reduce"):
+                out, valid, j_over = _local_join(frags, query, cap_out,
+                                                 cfg.use_kernels,
+                                                 cfg.hash_reduce,
+                                                 cfg.hash_bits)
             return (out[None], valid[None], sh_over[None], j_over[None],
                     recv_count[None])
 
@@ -1122,8 +1142,8 @@ class ExecutorSession:
         self.caps = self._derive_caps(counts, placement)
         return self
 
-    def run_batch(self, chunks: Mapping[str, np.ndarray] | None = None
-                  ) -> dict[str, np.ndarray]:
+    def run_batch(self, chunks: Mapping[str, np.ndarray] | None = None,
+                  rid: int | None = None) -> dict[str, np.ndarray]:
         """Execute one batch through the warm step.
 
         `chunks=None` re-runs the prepared relations; otherwise `chunks` maps
@@ -1132,7 +1152,8 @@ class ExecutorSession:
         Returns a `BatchResult` — a Mapping with the usual six keys whose
         values materialize on first access, so the call itself never blocks
         on a device->host transfer (per-batch overflow vectors are folded
-        into `session.stats` lazily too, on stats access)."""
+        into `session.stats` lazily too, on stats access).  `rid` tags the
+        `join.ingest` and `join.dispatch` spans with the serving request."""
         if self._shapes is None:
             raise RuntimeError("ExecutorSession.run_batch before prepare()")
         ex = self.executor
@@ -1151,15 +1172,16 @@ class ExecutorSession:
         if chunks is None:
             args = self._device_args
         else:
-            args = []
-            for rel, target in zip(query.relations, self._shapes):
-                sh = ex._shard(_validate_relation(rel.name, chunks[rel.name],
-                                                  len(rel.attrs)))
-                if sh.shape[0] < target[0]:
-                    pad = np.full((target[0] - sh.shape[0], sh.shape[1]),
-                                  INVALID, sh.dtype)
-                    sh = np.concatenate([sh, pad])
-                args.append(ex._upload(sh))
+            with span("join.ingest", rid):
+                args = []
+                for rel, target in zip(query.relations, self._shapes):
+                    sh = ex._shard(_validate_relation(
+                        rel.name, chunks[rel.name], len(rel.attrs)))
+                    if sh.shape[0] < target[0]:
+                        pad = np.full((target[0] - sh.shape[0], sh.shape[1]),
+                                      INVALID, sh.dtype)
+                        sh = np.concatenate([sh, pad])
+                    args.append(ex._upload(sh))
         self._last_args = args          # count_batch()'s observation target
         shapes = tuple(a.shape for a in args)
         if shapes != self._shapes:
@@ -1174,8 +1196,9 @@ class ExecutorSession:
                 f"capacities (compiles a new step for a new shape); "
                 f"re-prepare() to re-derive shapes/placement/capacities",
                 UserWarning, stacklevel=2)
-        f = ex._compiled_step(shapes, self.caps, self.cap_out)
-        out, valid, sh_over, j_over, recv = f(self._ptable_dev, *args)
+        with span("join.dispatch", rid):
+            f = ex._compiled_step(shapes, self.caps, self.cap_out)
+            out, valid, sh_over, j_over, recv = f(self._ptable_dev, *args)
         self._stats["batches"] += 1
         self._pending.append((sh_over, j_over))
         if len(self._pending) >= self._PENDING_MAX:
@@ -1183,8 +1206,8 @@ class ExecutorSession:
         return BatchResult(out, valid, sh_over, j_over, recv)
 
     def run_with_retry(self, chunks: Mapping[str, np.ndarray] | None = None,
-                       policy: RetryPolicy | None = None
-                       ) -> dict[str, np.ndarray]:
+                       policy: RetryPolicy | None = None,
+                       rid: int | None = None) -> dict[str, np.ndarray]:
         """Execute one batch, healing capacity overflow by bounded retry.
 
         Each overflowing attempt escalates EXACTLY the failing capacities —
@@ -1198,13 +1221,22 @@ class ExecutorSession:
         raises `RetryBudgetExceededError` with the full per-device,
         per-phase breakdown; the delivered result of a successful retry has
         zero overflow (every failed attempt's counters stay visible in
-        `session.stats`)."""
+        `session.stats`).  Each attempt's overflow read, where the host
+        waits for the step, is a `join.wait` span; each escalation and its
+        re-run is a `join.retry` span; all are tagged with `rid`."""
         policy = policy or RetryPolicy()
         ex = self.executor
         rels = tuple(r.name for r in ex.plan.query.relations)
-        res = self.run_batch(chunks)
+
+        def overflowed(res) -> bool:
+            with span("join.wait", rid):
+                return bool(res["shuffle_overflow"].sum()
+                            or res["join_overflow"].sum())
+
+        res = self.run_batch(chunks, rid)
+        over = overflowed(res)
         attempt = 1
-        while res["shuffle_overflow"].sum() or res["join_overflow"].sum():
+        while over:
             if attempt > policy.max_retries:
                 base = CapacityOverflowError.from_result(res, rels)
                 raise RetryBudgetExceededError(
@@ -1212,19 +1244,21 @@ class ExecutorSession:
                     f"({policy.max_retries} retries) and the last still "
                     f"overflowed — {base}", base.shuffle_by_rel,
                     base.join_overflow, rels, attempt)
-            per_rel = res["shuffle_overflow_by_rel"].sum(axis=0)
-            for i, rel in enumerate(rels):
-                if per_rel[i]:
-                    self.caps[rel] = quantize_capacity(
-                        int(np.ceil(self.caps[rel] * policy.escalation)),
+            with span("join.retry", rid):
+                per_rel = res["shuffle_overflow_by_rel"].sum(axis=0)
+                for i, rel in enumerate(rels):
+                    if per_rel[i]:
+                        self.caps[rel] = quantize_capacity(
+                            int(np.ceil(self.caps[rel] * policy.escalation)),
+                            ex.config.cap_bucket)
+                        self.stats["escalations"] += 1
+                if res["join_overflow"].sum():
+                    self.cap_out = quantize_capacity(
+                        int(np.ceil(self.cap_out * policy.escalation)),
                         ex.config.cap_bucket)
                     self.stats["escalations"] += 1
-            if res["join_overflow"].sum():
-                self.cap_out = quantize_capacity(
-                    int(np.ceil(self.cap_out * policy.escalation)),
-                    ex.config.cap_bucket)
-                self.stats["escalations"] += 1
-            self.stats["retries"] += 1
-            res = self.run_batch(chunks)
+                self.stats["retries"] += 1
+                res = self.run_batch(chunks, rid)
+                over = overflowed(res)
             attempt += 1
         return res

@@ -45,6 +45,7 @@ baseline.
 """
 from __future__ import annotations
 
+import math
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
@@ -55,7 +56,8 @@ import numpy as np
 from ..core.adapt import AdaptPolicy, TenantDriftBank
 from ..core.executor import (INVALID, ExecutorConfig, ExecutorSession,
                              RetryPolicy, ShardedJoinExecutor,
-                             _build_routes, _route_specs, quantize_capacity)
+                             _build_routes, _route_specs, quantize_capacity,
+                             span)
 from ..core.placement import lpt_placement
 from ..core.plan import JoinQuery
 from ..core.skewjoin import SkewJoinPlan, plan_skew_join
@@ -64,7 +66,12 @@ from .engine import SelfHealingSession
 
 @dataclass
 class JoinRequest:
-    """One tenant's join-the-current-batch request."""
+    """One tenant's join-the-current-batch request.
+
+    Three `time.perf_counter()` stamps: `submitted_at` (in `submit`),
+    `started_at` (service begins) and `finished_at` (the result rows are on
+    the host).  Queue wait is `started_at - submitted_at`, service time
+    `finished_at - started_at`, latency `finished_at - submitted_at`."""
     rid: int
     tenant: str
     query: JoinQuery
@@ -72,7 +79,9 @@ class JoinRequest:
     bucket: tuple[int, ...] | None = None   # per-relation padded row counts
     rows: np.ndarray | None = None          # valid join rows, set when done
     recv_counts: np.ndarray | None = None   # rows each device received
-    latency_s: float = 0.0
+    submitted_at: float = math.nan
+    started_at: float = math.nan
+    finished_at: float = math.nan
     done: bool = False
 
 
@@ -225,19 +234,22 @@ class JoinServingEngine:
     # -- admission ------------------------------------------------------------
     def submit(self, tenant: str, query: JoinQuery,
                data: Mapping[str, np.ndarray]) -> JoinRequest:
-        t = self.tenants.get(tenant)
-        if t is None:
-            t = _Tenant(tenant)
-            self.tenants[tenant] = t
-            self._arrival.append(tenant)
-        if t.plan is not None and t.plan.query != query:
-            raise ValueError(
-                f"tenant {tenant!r} switched query structure "
-                f"({t.plan.query} -> {query}); use a new tenant id per "
-                f"query shape")
-        req = JoinRequest(self._next_rid, tenant, query, dict(data))
-        self._next_rid += 1
-        t.queue.append(req)
+        now = time.perf_counter()
+        with span("join.submit", self._next_rid):
+            t = self.tenants.get(tenant)
+            if t is None:
+                t = _Tenant(tenant)
+                self.tenants[tenant] = t
+                self._arrival.append(tenant)
+            if t.plan is not None and t.plan.query != query:
+                raise ValueError(
+                    f"tenant {tenant!r} switched query structure "
+                    f"({t.plan.query} -> {query}); use a new tenant id per "
+                    f"query shape")
+            req = JoinRequest(self._next_rid, tenant, query, dict(data),
+                              submitted_at=now)
+            self._next_rid += 1
+            t.queue.append(req)
         return req
 
     def _bucket(self, query: JoinQuery, data: Mapping[str, np.ndarray]
@@ -251,8 +263,9 @@ class JoinServingEngine:
 
     def _ensure_plan(self, t: _Tenant, req: JoinRequest) -> None:
         if t.plan is None:
-            t.plan = plan_skew_join(req.query, req.data, self.k)
-            t.struct_key = _struct_key(t.plan)
+            with span("join.plan", req.rid):
+                t.plan = plan_skew_join(req.query, req.data, self.k)
+                t.struct_key = _struct_key(t.plan)
 
     def _session_for(self, t: _Tenant, req: JoinRequest
                      ) -> tuple[ExecutorSession, tuple]:
@@ -268,18 +281,20 @@ class JoinServingEngine:
             # Pad each relation with INVALID rows up to the bucket: invalid
             # rows route nowhere, so the prepared placement/capacities are
             # those of the real data, at the bucket's warm shape.
-            padded = {}
-            for rel in t.plan.query.relations:
-                arr = np.asarray(req.data[rel.name])
-                n_pad = req.bucket[t.plan.query.relations.index(rel)] - len(arr)
-                if n_pad > 0:
-                    pad = np.full((n_pad, arr.shape[1]), INVALID, arr.dtype)
-                    arr = np.concatenate([arr, pad])
-                padded[rel.name] = arr
-            ses = ex.session().prepare(padded)
-            t.stats["prepares"] += 1
-            if t.plan.residuals:
-                t.load_estimate = float(ses.cell_loads().sum())
+            with span("join.prepare", req.rid):
+                padded = {}
+                for i, rel in enumerate(t.plan.query.relations):
+                    arr = np.asarray(req.data[rel.name])
+                    n_pad = req.bucket[i] - len(arr)
+                    if n_pad > 0:
+                        pad = np.full((n_pad, arr.shape[1]), INVALID,
+                                      arr.dtype)
+                        arr = np.concatenate([arr, pad])
+                    padded[rel.name] = arr
+                ses = ex.session().prepare(padded)
+                t.stats["prepares"] += 1
+                if t.plan.residuals:
+                    t.load_estimate = float(ses.cell_loads().sum())
             return ses
 
         ses, _ = self.cache.session(skey, prepare)
@@ -287,32 +302,36 @@ class JoinServingEngine:
 
     # -- serving --------------------------------------------------------------
     def _serve(self, t: _Tenant, req: JoinRequest) -> None:
-        ses, _ = self._session_for(t, req)
-        s0 = ses.stats
-        snap = (s0["batches"], s0["retries"], s0["escalations"],
-                int(s0["shuffle_overflow"].sum() + s0["join_overflow"].sum()))
-        c0 = self.cache.compile_count()
-        t0 = time.perf_counter()
-        res = ses.run_with_retry(req.data, self.retry)
-        rows = np.asarray(res["rows"])[np.asarray(res["valid"])]
-        req.latency_s = time.perf_counter() - t0
-        req.rows, req.done = rows, True
-        req.recv_counts = np.asarray(res["recv_counts"])
-        s1 = ses.stats
-        st = t.stats
-        st["requests"] += 1
-        st["batches"] += s1["batches"] - snap[0]
-        st["retries"] += s1["retries"] - snap[1]
-        st["escalations"] += s1["escalations"] - snap[2]
-        st["overflow"] += int(s1["shuffle_overflow"].sum()
-                              + s1["join_overflow"].sum()) - snap[3]
-        st["compiles"] += self.cache.compile_count() - c0
-        st["rows_in"] += sum(len(req.data[r.name])
-                             for r in req.query.relations)
-        st["rows_out"] += len(rows)
-        self.requests += 1
-        if self.adapt is not None:
-            self._observe(t, ses, req)
+        req.started_at = time.perf_counter()
+        with span("join.request", req.rid):
+            ses, _ = self._session_for(t, req)
+            s0 = ses.stats
+            snap = (s0["batches"], s0["retries"], s0["escalations"],
+                    int(s0["shuffle_overflow"].sum()
+                        + s0["join_overflow"].sum()))
+            c0 = self.cache.compile_count()
+            res = ses.run_with_retry(req.data, self.retry, req.rid)
+            with span("join.egress", req.rid):
+                rows = np.asarray(res["rows"])[np.asarray(res["valid"])]
+                req.recv_counts = np.asarray(res["recv_counts"])
+            req.finished_at = time.perf_counter()
+            req.rows, req.done = rows, True
+            s1 = ses.stats
+            st = t.stats
+            st["requests"] += 1
+            st["batches"] += s1["batches"] - snap[0]
+            st["retries"] += s1["retries"] - snap[1]
+            st["escalations"] += s1["escalations"] - snap[2]
+            st["overflow"] += int(s1["shuffle_overflow"].sum()
+                                  + s1["join_overflow"].sum()) - snap[3]
+            st["compiles"] += self.cache.compile_count() - c0
+            st["rows_in"] += sum(len(req.data[r.name])
+                                 for r in req.query.relations)
+            st["rows_out"] += len(rows)
+            self.requests += 1
+            if self.adapt is not None:
+                with span("join.observe", req.rid):
+                    self._observe(t, ses, req)
 
     def _observe(self, t: _Tenant, ses: ExecutorSession,
                  req: JoinRequest) -> None:
