@@ -14,9 +14,13 @@ actual functions and counting opcodes with `launch.hlo_analysis.count_ops`
     its dynamic stores to dynamic-update-slice, never gather);
   * `expand_rows` interpret   -> zero `gather` ops (one-hot contraction);
   * teeth: the superseded `_assemble_tagged` and the `expand_rows_host`
-    searchsorted+indexing twin must BOTH count >= 1 gather on the same
-    inputs — proving the counter can see a gather in this very pipeline
-    (a parser that returns 0 for everything fails here, not silently).
+    indexing twin must BOTH count >= 1 gather on the same inputs — proving
+    the counter can see a gather in this very pipeline (a parser that
+    returns 0 for everything fails here, not silently);
+  * `expand_rows_host`        -> zero `while` ops: it finds each slot's left
+    row by a merge sort (`join_probe.sorted_locate`), and a binary search
+    (`jnp.searchsorted`'s default) would lower to a loop of gathers, the
+    reduce's largest cost on a v5e chip.
 
 Exit 1 on any violation.  Usage:  python scripts/check_hlo.py
 """
@@ -49,10 +53,11 @@ def main() -> int:
     rng = np.random.default_rng(17)
     failures: list[str] = []
 
-    def gate(name: str, text: str, want_zero: bool) -> None:
-        n = count_ops(text, "gather")
+    def gate(name: str, text: str, want_zero: bool, op: str = "gather"
+             ) -> None:
+        n = count_ops(text, op)
         ok = (n == 0) if want_zero else (n >= 1)
-        print(f"  {name}: {n} gather ops "
+        print(f"  {name}: {n} {op} ops "
               f"({'want 0' if want_zero else 'teeth, want >= 1'})"
               f"{'' if ok else '  <-- FAIL'}")
         if not ok:
@@ -90,15 +95,17 @@ def main() -> int:
     gate("expand_rows kernel (interpret)",
          _lower_text(expand_rows, left, right, counts, lo, perm,
                      cap=cap_out, interpret=True), want_zero=True)
-    gate("expand_rows_host twin",
-         _lower_text(expand_rows_host, left, right, counts, lo, perm,
-                     cap=cap_out), want_zero=False)
+    host = _lower_text(expand_rows_host, left, right, counts, lo, perm,
+                       cap=cap_out)
+    gate("expand_rows_host twin", host, want_zero=False)
+    gate("expand_rows_host slot search", host, want_zero=True, op="while")
 
     if failures:
         print(f"HLO GUARD FAILED: {', '.join(failures)}", file=sys.stderr)
         return 1
     print("HLO guard passed: assemble/expansion paths lower with zero "
-          "XLA gathers (and the counter's teeth bite).")
+          "XLA gathers (and the counter's teeth bite); the host expansion "
+          "has no search loop.")
     return 0
 
 

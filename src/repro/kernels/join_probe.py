@@ -438,6 +438,34 @@ def _run_bounds(rid: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return start, end
 
 
+def sorted_locate(a: jnp.ndarray, q: jnp.ndarray, side: str = "left"
+                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(pos, nxt) of each query q (m,) in the sorted int32 array a (n,):
+    pos is `jnp.searchsorted(a, q, side=side)`, nxt is a[pos], or the int32
+    maximum where pos == n.
+
+    A merge by sort, with no gather loop and no scatter: one two-key sort
+    lays a and q out in merged order, each tagged with its place in their
+    concatenation, so that the tag breaks ties (queries before equal
+    elements of a for side="left", after them for "right").  A query's pos
+    is then the running count of a's elements before it, and nxt the
+    running minimum of a's elements after it.  A second sort, on the tag,
+    returns both to query order.
+    """
+    n, m = a.shape[0], q.shape[0]
+    with jax.named_scope("locate"):
+        left = side == "left"
+        keys = jnp.concatenate([q, a] if left else [a, q]).astype(jnp.int32)
+        tag = jnp.arange(n + m, dtype=jnp.int32)
+        keys, tag = jax.lax.sort((keys, tag), num_keys=2)
+        is_a = (tag >= m) if left else (tag < n)
+        pos = jnp.cumsum(is_a.astype(jnp.int32))
+        nxt = jax.lax.cummin(
+            jnp.where(is_a, keys, jnp.iinfo(jnp.int32).max), reverse=True)
+        _, pos, nxt = jax.lax.sort((tag, pos, nxt), num_keys=1)
+        return (pos[:m], nxt[:m]) if left else (pos[n:], nxt[n:])
+
+
 def probe_tables(lk: jnp.ndarray, l_bkt: jnp.ndarray, rk: jnp.ndarray,
                  r_bkt: jnp.ndarray, rank: jnp.ndarray, hist: jnp.ndarray,
                  n_bits: int
@@ -494,8 +522,10 @@ def join_probe_host(lk: jnp.ndarray, l_valid: jnp.ndarray, rk: jnp.ndarray,
 
     The packed table comes from ONE sort of (hash, arrival) pairs: the
     sorted pairs ARE (partition, original row), partition bounds fall out of
-    two run scans, and left rows locate their partition with a single-column
-    searchsorted: no union buffer, no multi-column lexsort, no scatter.  The
+    two run scans, and left rows locate their partition, and whether it
+    exists, by merging their hashes into the sorted ones (`sorted_locate`:
+    two sorts and two scans, where a binary search would be a loop of
+    gathers, slow on a TPU): no multi-column lexsort, no scatter.  The
     hash keeps `HOST_BITS` bits (invalid rows take the sentinel above them,
     sorting last), so partitions are far finer than the kernel's histogram
     table and the chain loop converges in O(1) rounds at any n_r; an
@@ -513,7 +543,7 @@ def join_probe_host(lk: jnp.ndarray, l_valid: jnp.ndarray, rk: jnp.ndarray,
     rid, perm1 = jax.lax.sort((key, qidx), num_keys=2)      # packed -> orig
     rstart, rend = _run_bounds(rid)
     fpos0 = jnp.where(rid >= (1 << bits), qidx, jnp.int32(-1))
-    s_l = jnp.searchsorted(rid, hw_l, side="left")
-    exists = (s_l < n_r) & (rid[jnp.clip(s_l, 0, n_r - 1)] == hw_l)
+    s_l, nxt = sorted_locate(rid, hw_l, side="left")
+    exists = (s_l < n_r) & (nxt == hw_l)
     l_miss = ~l_valid.astype(bool) | ~exists
     return _chain_probe(lk, rk, perm1, rstart, rend, s_l, l_miss, fpos0)

@@ -33,10 +33,13 @@ the async-DMA HBM variant is the ROADMAP follow-up.
 twins (production off-TPU): the host assemble is ONE ``.at[slot].set`` row
 scatter into the same trash-row buffer — the copies move once, as in
 `_assemble_tagged`, but as a scatter instead of an inverse-permutation
-gather, which is what `scripts/check_hlo.py` pins.  `expand_rows_host` keeps
-the proven searchsorted + gather formulation (fast on CPU; the gather-free
-contract is the KERNEL path's).  `scatter_pack_ref` / `expand_rows_ref` in
-kernels/ref.py are the dead-simple oracles.
+gather, which is what `scripts/check_hlo.py` pins.  `expand_rows_host` finds
+each slot's left row by merging the slots into the group offsets with two
+sorts and a scan (`join_probe.sorted_locate`; a binary search is a loop of
+gathers, slow on a TPU, and `scripts/check_hlo.py` pins its absence), then
+gathers the rows (the gather-free contract is the KERNEL path's).
+`scatter_pack_ref` / `expand_rows_ref` in kernels/ref.py are the
+dead-simple oracles.
 
 Outputs are bit-identical to `map_pack` / the `_local_join` expansion gather
 they replace; `kernels.ops` dispatches Pallas on TPU, host twins elsewhere.
@@ -50,6 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .bucket_pack import DEFAULT_HOST_BLOCK, bucket_rank_host
+from .join_probe import sorted_locate
 from .map_pack import (DEFAULT_BLOCK_COPIES, RouteSpec, _empty_pack,
                        _route_block, _row_block, route_fanout)
 
@@ -294,8 +298,10 @@ def expand_rows_host(left: jnp.ndarray, right: jnp.ndarray,
                      *, cap: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     """`expand_rows` in vectorized XLA — bit-identical outputs.
 
-    Keeps the proven searchsorted + gather formulation (the fast CPU path);
-    the gather-free contract belongs to the kernel lowering.
+    Slot t's left row li is the last row whose group offset is at most t,
+    found by a merge sort of offsets and slots (`sorted_locate`) rather
+    than a binary search; the rows are then gathered.  The gather-free
+    contract belongs to the kernel lowering.
     """
     n_l, wl = left.shape
     n_r, wr = right.shape
@@ -304,7 +310,7 @@ def expand_rows_host(left: jnp.ndarray, right: jnp.ndarray,
                 jnp.zeros((cap,), bool))
     off = jnp.cumsum(counts) - counts
     t = jnp.arange(cap, dtype=jnp.int32)
-    li = jnp.clip(jnp.searchsorted(off, t, side="right") - 1, 0, n_l - 1)
+    li = jnp.clip(sorted_locate(off, t, side="right")[0] - 1, 0, n_l - 1)
     ri = perm[jnp.clip(lo[li] + t - off[li], 0, n_r - 1)]
     out = jnp.concatenate([left[li], right[ri]], axis=1)
     return out, t < counts.sum()

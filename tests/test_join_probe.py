@@ -130,6 +130,48 @@ def test_probe_empty_left():
     assert sorted(np.asarray(perm).tolist()) == list(range(6))
 
 
+_SENTINEL = 1 << jp.HOST_BITS            # `join_probe_host`'s invalid hash
+_I32MAX = np.iinfo(np.int32).max
+
+
+def _random_hashes(seed):
+    rng = np.random.default_rng(seed)
+    rid = np.sort(np.concatenate([rng.integers(0, 40, 150),
+                                  np.full(25, _SENTINEL)]))
+    return rid, rng.integers(-2, 45, 301)
+
+
+# (sorted right hashes `rid`, left hashes `hw_l`) as `join_probe_host` sees
+# them: invalid right rows carry the sentinel and sort last.
+_LOCATE_CASES = {
+    "ties_and_runs": ([0, 0, 3, 3, 3, 7, 7, 9], [3, 0, 7, 9, 5, 3, 3, -1, 0]),
+    "past_the_end": ([1, 2, 2, 4], [4, 5, 1 << 29, _SENTINEL, _I32MAX, 0]),
+    "all_invalid": ([_SENTINEL] * 6, [0, 17, _SENTINEL - 1, _SENTINEL]),
+    "sentinel_tail": ([2, 5, 5, _SENTINEL, _SENTINEL],
+                      [5, 2, 3, _SENTINEL - 1, 6]),
+    "one_left_row": ([1, 4, 4, 8], [4]),
+    "one_left_row_missing": ([1, 4, 4, 8], [5]),
+    "one_right_row": ([4], [3, 4, 5, 4]),
+    "random": _random_hashes(0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LOCATE_CASES))
+def test_sorted_locate_matches_searchsorted(case):
+    """`join_probe_host` locates each left row's partition by a merge sort
+    (`sorted_locate`): position and next right hash as `np.searchsorted`
+    and an index past the end give them."""
+    rid, hw_l = (np.asarray(x, np.int32) for x in _LOCATE_CASES[case])
+    for side in ("left", "right"):
+        pos, nxt = jp.sorted_locate(jnp.asarray(rid), jnp.asarray(hw_l),
+                                    side=side)
+        want = np.searchsorted(rid, hw_l, side=side)
+        np.testing.assert_array_equal(np.asarray(pos), want, err_msg=side)
+        np.testing.assert_array_equal(np.asarray(nxt),
+                                      np.append(rid, _I32MAX)[want],
+                                      err_msg=side)
+
+
 @pytest.mark.parametrize("n_bits", [1, 4, 8])
 @pytest.mark.parametrize("m", [0, 1, 63, 257])          # ragged, off-block
 def test_join_hash_and_build_table_bit_identity(m, n_bits):

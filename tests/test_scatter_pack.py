@@ -9,10 +9,11 @@ multi-residual recipes with replication fanout > 1, m = 0, all-invalid rows,
 capacity-overflow parity, and tile-boundary rank carry.
 
 `expand_rows` must be POSITIONALLY identical to the searchsorted + gather
-expansion it replaces (`expand_rows_host` keeps that formulation verbatim —
-it doubles as the oracle) on real probe outputs and on degenerate shapes:
-ragged caps that end mid-group, overflow truncation, zero-size sides, and
-zero total matches.
+expansion it replaces (`expand_rows_ref` keeps that formulation; the host
+twin finds each slot's left row by a merge sort instead, and is checked
+against `np.searchsorted` and the ref) on real probe outputs and on
+degenerate shapes: ragged caps that end mid-group, overflow truncation,
+zero-size sides, zero total matches, and zero-count rows at either end.
 """
 import numpy as np
 import pytest
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 from hypothesis import given, settings, strategies as st
 from repro.core.executor import _Route, _probe_sort, _route_specs
 from repro.core.placement import lpt_placement, modulo_placement
+from repro.kernels import join_probe as jp
 from repro.kernels import map_pack as mp
 from repro.kernels import ops as kops
 from repro.kernels import scatter_pack as sp
@@ -239,6 +241,51 @@ def test_expand_rows_zero_matches_and_zero_sizes():
         pz = jnp.arange(r.shape[0], dtype=jnp.int32)
         lz = jnp.zeros((l.shape[0],), jnp.int32)
         _assert_expand_paths_agree(l, r, c, lz, pz, 8)
+
+
+_COUNT_CASES = {
+    "leading_zeros": [0, 0, 2, 1, 3],
+    "trailing_zeros": [2, 1, 3, 0, 0, 0],
+    "interior_zero_runs": [1, 0, 0, 3, 0, 2, 0, 0, 1],
+    "all_zero": [0, 0, 0, 0],
+    "one_left_row": [5],
+    "one_left_row_zero": [0],
+}
+
+
+@pytest.mark.parametrize("cap_vs_total", [-3, 0, 7])
+@pytest.mark.parametrize("case", sorted(_COUNT_CASES))
+def test_expand_rows_host_locates_slots_like_searchsorted(case,
+                                                          cap_vs_total):
+    """`expand_rows_host` maps slot t to its left row by a merge sort:
+    li must be `np.searchsorted(off, t, "right") - 1` (clipped) for every
+    slot, including the slots past `counts.sum()`, and the expansion
+    positionally identical to the searchsorted oracle in kernels/ref.py."""
+    counts = np.asarray(_COUNT_CASES[case], np.int32)
+    total = int(counts.sum())
+    cap = max(total + cap_vs_total, 1)
+    n_l = len(counts)
+    off = np.cumsum(counts) - counts
+    t = np.arange(cap, dtype=np.int32)
+    pos, _ = jp.sorted_locate(jnp.asarray(off, jnp.int32), jnp.asarray(t),
+                              side="right")
+    np.testing.assert_array_equal(np.asarray(pos),
+                                  np.searchsorted(off, t, side="right"))
+    n_r = max(total, 1)
+    rng = np.random.default_rng(n_l * 10 + cap)
+    left = jnp.asarray(np.stack([np.arange(n_l), np.arange(n_l) * 7], 1),
+                       jnp.int32)
+    right = jnp.asarray(rng.integers(0, 99, (n_r, 2)), jnp.int32)
+    perm = jnp.asarray(rng.permutation(n_r), jnp.int32)
+    out, val = sp.expand_rows_host(left, right, jnp.asarray(counts),
+                                   jnp.asarray(off, jnp.int32), perm,
+                                   cap=cap)
+    li = np.clip(np.searchsorted(off, t, side="right") - 1, 0, n_l - 1)
+    np.testing.assert_array_equal(np.asarray(out)[:, 0], li)
+    out_r, val_r = expand_rows_ref(left, right, jnp.asarray(counts),
+                                   jnp.asarray(off, jnp.int32), perm, cap)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_r))
+    np.testing.assert_array_equal(np.asarray(val), np.asarray(val_r))
 
 
 def test_expand_rows_tile_boundaries():
