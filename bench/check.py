@@ -4,8 +4,21 @@ The reference is a left-to-right cascade of two-way natural joins in numpy,
 written here from the definition and importing nothing of the program.  A
 served request is right when its rows equal the reference's as a multiset:
 every result row of the bag join, each exactly as often as the join makes
-it.  `Expected.rows_wrong` counts the rows in which the two differ (missing
-plus extra), so its limit is 0.
+it.  `Reference.rows_wrong` counts the rows in which the two differ
+(missing plus extra), so its limit is 0.
+
+It decides first by fingerprint, without building the reference.  `Keys`
+draws, from a seed the program never sees, `N_KEYS` tables that map each
+attribute's values to uniform residues in [1, P).  A row's hash is the
+product of its columns' residues mod P, and a multiset's fingerprint the
+sum of its rows' hashes mod P: a multilinear polynomial in the residues
+whose coefficients are the rows' multiplicities.  Two different multisets
+of rows of c columns agree on one key with probability at most c / P
+(Schwartz-Zippel), on all keys with at most (c / P) ** N_KEYS.  The
+reference's fingerprint factorises along a path cascade
+(`fingerprint_join`), so only the answer is read row by row.  Where the
+row count or a fingerprint differs, the exact multiset (`Expected`) is
+built and counts the rows wrong.
 
 `hash_match_join` is the control: the same cascade matching on a hash of
 the key of `log2(rows) + 8` bits, with no check of the key itself, the step
@@ -69,30 +82,152 @@ def natural_join(relations: Relations, data: Mapping[str, np.ndarray],
     return acc[:, [acc_attrs.index(a) for a in attributes(relations)]]
 
 
-def prefix_sizes(relations: Relations, data: Mapping[str, np.ndarray]
-                 ) -> list[int]:
-    """Row counts of each prefix R0 ⋈ … ⋈ Rj of the cascade, counted
-    without building it where each relation joins the prefix only on
-    attributes of the relation before it (paths, the paper's example);
-    built otherwise."""
-    (name0, attrs0), *rest = relations
-    weight = np.ones(len(data[name0]), np.float64)
-    sizes, seen, prev = [len(weight)], set(attrs0), (name0, tuple(attrs0))
+def _path_keys(relations: Relations, data: Mapping[str, np.ndarray]
+               ) -> list[tuple[np.ndarray, np.ndarray]] | None:
+    """For each relation after the first, the ids of the previous
+    relation's rows and of its own on the attributes it shares with the
+    prefix (equal values, equal ids); None unless each relation joins the
+    prefix only on attributes of the relation before it (paths, the
+    paper's example)."""
+    (_, attrs0), *rest = relations
+    out, seen, prev = [], set(attrs0), relations[0]
     for name, attrs in rest:
         shared = [a for a in attrs if a in seen]
         if not shared or not set(shared) <= set(prev[1]):
-            return [len(natural_join(relations[:j + 1], data))
-                    for j in range(len(relations))]
-        pk = np.asarray(data[prev[0]])[:, [prev[1].index(a) for a in shared]]
+            return None
+        pk = np.asarray(data[prev[0]])[:, [tuple(prev[1]).index(a)
+                                           for a in shared]]
         rk = np.asarray(data[name])[:, [tuple(attrs).index(a) for a in shared]]
         ids = _key_ids(np.concatenate([pk, rk]))
-        hist = np.bincount(ids[:len(pk)], weights=weight,
-                           minlength=int(ids.max()) + 1)
-        weight = hist[ids[len(pk):]]
-        sizes.append(int(round(weight.sum())))
+        out.append((ids[:len(pk)], ids[len(pk):]))
         seen |= set(attrs)
-        prev = (name, tuple(attrs))
+        prev = (name, attrs)
+    return out
+
+
+def _group_sum(left: np.ndarray, weight: np.ndarray, right: np.ndarray
+               ) -> np.ndarray:
+    """For each right row, the sum of the weights of the left rows with its
+    id: integers, summed exactly in their own dtype."""
+    n = int(max(left.max(initial=0), right.max(initial=0))) + 1
+    acc = np.zeros(n, weight.dtype)
+    np.add.at(acc, left, weight)
+    return acc[right]
+
+
+def prefix_sizes(relations: Relations, data: Mapping[str, np.ndarray]
+                 ) -> list[int]:
+    """Row counts of each prefix R0 ⋈ … ⋈ Rj of the cascade, counted
+    without building it where the cascade is a path (`_path_keys`); built
+    otherwise."""
+    steps = _path_keys(relations, data)
+    if steps is None:
+        return [len(natural_join(relations[:j + 1], data))
+                for j in range(len(relations))]
+    weight = np.ones(len(data[relations[0][0]]), np.int64)
+    sizes = [len(weight)]
+    for left, right in steps:
+        weight = _group_sum(left, weight, right)
+        sizes.append(int(weight.sum()))
     return sizes
+
+
+P = (1 << 31) - 1   # prime; a product of two residues fits in uint64
+N_KEYS = 2
+CHUNK = 1 << 16     # rows hashed at a time, so that temporaries stay cached
+
+
+class Keys:
+    """`N_KEYS` random residues in [1, P) for every value of every
+    attribute, over the range of values that the request's relations hold:
+    `tables[a][k, v - lo[a]]` is key k's residue of value v.  Drawn from
+    `seed` alone, after the window, so the program never sees them."""
+
+    def __init__(self, relations: Relations, data: Mapping[str, np.ndarray],
+                 seed):
+        rng = np.random.default_rng(seed)
+        self.attrs = attributes(relations)
+        self.lo, self.tables = {}, {}
+        for a in self.attrs:
+            cols = [np.asarray(data[name])[:, tuple(attrs).index(a)]
+                    for name, attrs in relations if a in attrs]
+            lo = min(int(c.min(initial=0)) for c in cols)
+            hi = max(int(c.max(initial=0)) for c in cols)
+            self.lo[a] = lo
+            self.tables[a] = rng.integers(1, P, size=(N_KEYS, hi - lo + 1),
+                                          dtype=np.uint32)
+
+    def index(self, values: np.ndarray, attr: str) -> np.ndarray | None:
+        """Each value's place in `attr`'s tables; None where one lies
+        outside them."""
+        idx = np.asarray(values, np.intp) - self.lo[attr]
+        if len(idx) and (idx.min() < 0
+                         or idx.max() >= self.tables[attr].shape[1]):
+            return None
+        return idx
+
+
+def _row_hashes(rows: np.ndarray, attrs, keys: Keys) -> np.ndarray | None:
+    """(N_KEYS, n) products mod P of each row's residues over `attrs`, its
+    columns; None where a value lies outside the tables."""
+    idx = [keys.index(rows[:, c], a) for c, a in enumerate(attrs)]
+    if any(i is None for i in idx):
+        return None
+    h = np.ones((N_KEYS, len(rows)), np.uint64)
+    for k in range(N_KEYS):
+        for i, a in zip(idx, attrs):
+            h[k] *= keys.tables[a][k].take(i)
+            h[k] %= np.uint64(P)
+    return h
+
+
+def fingerprint_rows(rows: np.ndarray, keys: Keys) -> tuple[int, ...] | None:
+    """The fingerprints of a multiset of result rows, columns in
+    `attributes` order: per key, the sum of the rows' hashes mod P.  None
+    for rows that cannot be the join's: a wrong width, or a value outside
+    the tables (the -1 padding among them)."""
+    rows = np.asarray(rows)
+    if rows.ndim != 2 or rows.shape[1] != len(keys.attrs):
+        return None
+    acc = np.zeros(N_KEYS, np.uint64)
+    for start in range(0, len(rows), CHUNK):
+        h = _row_hashes(rows[start:start + CHUNK], keys.attrs, keys)
+        if h is None:
+            return None
+        acc = (acc + h.sum(axis=1)) % np.uint64(P)
+    return tuple(int(x) for x in acc)
+
+
+def fingerprint_join(relations: Relations, data: Mapping[str, np.ndarray],
+                     keys: Keys) -> tuple[int, tuple[int, ...]]:
+    """(rows, fingerprints) of the bag natural join, as `fingerprint_rows`
+    would give them for `natural_join`'s rows.  Along a path cascade
+    (`_path_keys`) each row of a relation carries the sum over the prefix
+    rows it joins of their hashes, times the residues of the attributes it
+    brings; the join is never built.  Built otherwise."""
+    steps = _path_keys(relations, data)
+    if steps is None:
+        rows = natural_join(relations, data)
+        return len(rows), fingerprint_rows(rows, keys)
+    seen: set[str] = set()
+    count = weight = None
+    for j, (name, attrs) in enumerate(relations):
+        new = [a for a in attrs if a not in seen]
+        own = _row_hashes(np.asarray(data[name])[:, [tuple(attrs).index(a)
+                                                     for a in new]],
+                          new, keys)
+        if j == 0:
+            count, weight = np.ones(own.shape[1], np.int64), own
+        else:
+            # A group's sum of residues stays below 2^64 while it holds
+            # fewer than 2^33 rows.
+            left, right = steps[j - 1]
+            count = _group_sum(left, count, right)
+            weight = np.stack([_group_sum(left, w, right) for w in weight])
+            weight = weight % np.uint64(P) * own % np.uint64(P)
+        seen |= set(attrs)
+    fps = weight.sum(axis=1) % np.uint64(P)
+    return int(count.sum()), tuple(int(x) for x in fps)
 
 
 def _mix64(x: np.ndarray) -> np.ndarray:
@@ -159,3 +294,28 @@ class Expected:
         cg = np.bincount(inv[:len(g)], minlength=len(keys))
         cw = np.bincount(inv[len(g):], minlength=len(keys))
         return outside + int(np.abs(cg - cw).sum())
+
+
+class Reference:
+    """One request's reference.  An answer is judged by its row count and
+    fingerprints (`fingerprint_join`); only where one differs is the
+    exact multiset built, once, to count the rows wrong."""
+
+    def __init__(self, relations: Relations, data: Mapping[str, np.ndarray],
+                 seed):
+        self.relations, self.data = relations, data
+        self.keys = Keys(relations, data, seed)
+        self.n, self.fingerprints = fingerprint_join(relations, data,
+                                                     self.keys)
+        self.exact: Expected | None = None
+
+    def rows_wrong(self, got: np.ndarray) -> int:
+        """Rows by which `got` differs from the reference as a multiset:
+        missing plus extra."""
+        got = np.asarray(got)
+        if (len(got) == self.n
+                and fingerprint_rows(got, self.keys) == self.fingerprints):
+            return 0
+        if self.exact is None:
+            self.exact = Expected(natural_join(self.relations, self.data))
+        return self.exact.rows_wrong(got)

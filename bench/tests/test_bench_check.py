@@ -69,3 +69,86 @@ def test_control_fails_the_comparison(tenant, seed):
     data = gen.tenant_data(CONFIG, tenant, 8193, (seed, 1, 0))
     want = check.natural_join(rels, data)
     assert check.Expected(want).rows_wrong(check.hash_match_join(rels, data)) > 0
+
+
+# A cyclic query: T joins the prefix on C and A, and S, the relation before
+# it, lacks A, so the fingerprint is taken over the built join.
+TRIANGLE = {"relations": [["R", ["A", "B"]], ["S", ["B", "C"]],
+                          ["T", ["C", "A"]]],
+            "heavy": {"A": "R", "B": "S", "C": "T"}}
+
+
+def _request(tenant, rows, seed):
+    config = {**CONFIG, "tenants": {**TENANTS, "triangle": TRIANGLE}}
+    rels = gen.relations(config, tenant)
+    return rels, gen.tenant_data(config, tenant, rows, (seed, 1, 0))
+
+
+@pytest.mark.parametrize("tenant,rows,seed", [
+    ("paper3", 4096, 1), ("paper3", 4096, 2), ("paper3", 65536, 3),
+    ("paper3", 16384, 2**31 + 9), ("pair", 8193, 4), ("chain4", 8193, 5),
+    ("triangle", 2048, 6)])
+def test_fingerprint_join_is_the_fingerprint_of_the_join(tenant, rows, seed):
+    rels, data = _request(tenant, rows, seed)
+    assert (check._path_keys(rels, data) is None) == (tenant == "triangle")
+    keys = check.Keys(rels, data, (seed, 2, 0))
+    want = check.natural_join(rels, data)
+    n, fps = check.fingerprint_join(rels, data, keys)
+    assert n == len(want) and len(fps) == check.N_KEYS
+    assert fps == check.fingerprint_rows(want, keys)
+    assert fps == check.fingerprint_rows(
+        np.random.default_rng(seed).permutation(want), keys)
+    assert check.Reference(rels, data, (seed, 2, 0)).rows_wrong(want) == 0
+
+
+def _swap(want):
+    """Two rows' values of the last column swapped, rows that differ in
+    that column and in the first."""
+    i = 0
+    j = int(np.nonzero((want[:, -1] != want[i, -1])
+                       & (want[:, 0] != want[i, 0]))[0][0])
+    bad = want.copy()
+    bad[[i, j], -1] = want[[j, i], -1]
+    return bad
+
+
+def _duplicated(want):
+    bad = want.copy()
+    j = int(np.nonzero((want != want[0]).any(axis=1))[0][0])
+    bad[j] = want[0]
+    return bad
+
+
+def _altered(want):
+    bad = want.copy()
+    bad[len(bad) // 2, 2] += 1
+    return bad
+
+
+FAULTS = {
+    "value_altered": _altered,
+    "row_dropped": lambda w: np.delete(w, 7, axis=0),
+    "row_duplicated_for_another": _duplicated,
+    "column_values_swapped": _swap,
+    "padding_row": lambda w: np.concatenate([w[1:], -np.ones((1, w.shape[1]),
+                                                             w.dtype)]),
+    "wrong_width": lambda w: w[:, :-1],
+    "hash_match": None,
+}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_fingerprint_tells_a_wrong_answer_apart(fault):
+    """Each fault changes the fingerprint or the row count, and the rows
+    wrong are then counted exactly, as by the sorted multisets."""
+    rels, data = _request("paper3", 8193, 11)
+    want = check.natural_join(rels, data)
+    bad = (check.hash_match_join(rels, data) if fault == "hash_match"
+           else FAULTS[fault](want))
+    ref = check.Reference(rels, data, (11, 2, 0))
+    assert (len(bad) != ref.n
+            or check.fingerprint_rows(bad, ref.keys) != ref.fingerprints)
+    assert ref.exact is None
+    n = ref.rows_wrong(bad)
+    assert n > 0 and n == check.Expected(want).rows_wrong(bad)
+    assert ref.exact is not None
