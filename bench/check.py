@@ -15,10 +15,11 @@ sum of its rows' hashes mod P: a multilinear polynomial in the residues
 whose coefficients are the rows' multiplicities.  Two different multisets
 of rows of c columns agree on one key with probability at most c / P
 (Schwartz-Zippel), on all keys with at most (c / P) ** N_KEYS.  The
-reference's fingerprint factorises along a path cascade
-(`fingerprint_join`), so only the answer is read row by row.  Where the
-row count or a fingerprint differs, the exact multiset (`Expected`) is
-built and counts the rows wrong.
+reference's fingerprint factorises along the join tree of an acyclic
+query (`fingerprint_join`), so only the answer is read row by row; a
+cyclic query's join is built.  Where the row count or a fingerprint
+differs, the exact multiset (`Expected`) is built and counts the rows
+wrong.
 
 `hash_match_join` is the control: the same cascade matching on a hash of
 the key of `log2(rows) + 8` bits, with no check of the key itself, the step
@@ -36,10 +37,24 @@ KeyFn = Callable[[np.ndarray], np.ndarray]
 
 
 def _key_ids(keys: np.ndarray) -> np.ndarray:
-    """One int64 id per row of a (n, c) key matrix; equal rows, equal ids."""
+    """One int64 id per row of a (n, c) key matrix; equal rows, equal ids.
+    A key of several columns gets its rank among the distinct rows in
+    lexicographic order, as `np.unique(keys, axis=0)` gives it; where the
+    columns' spans allow, the rows are packed into one int64 first, which
+    keeps that order and sorts scalars, not records."""
     if keys.shape[1] == 1:
         return keys[:, 0].astype(np.int64)
-    _, ids = np.unique(keys, axis=0, return_inverse=True)
+    keys = np.asarray(keys, np.int64)
+    if len(keys):
+        lo = keys.min(axis=0)
+        span = keys.max(axis=0) - lo + 1
+        if float(np.prod(span.astype(float))) < 2.0 ** 62:
+            packed = np.zeros(len(keys), np.int64)
+            for c in range(keys.shape[1]):
+                packed = packed * span[c] + (keys[:, c] - lo[c])
+            keys = packed
+    _, ids = np.unique(keys, axis=0 if keys.ndim == 2 else None,
+                       return_inverse=True)
     return ids.reshape(-1).astype(np.int64)
 
 
@@ -82,54 +97,85 @@ def natural_join(relations: Relations, data: Mapping[str, np.ndarray],
     return acc[:, [acc_attrs.index(a) for a in attributes(relations)]]
 
 
-def _path_keys(relations: Relations, data: Mapping[str, np.ndarray]
-               ) -> list[tuple[np.ndarray, np.ndarray]] | None:
-    """For each relation after the first, the ids of the previous
-    relation's rows and of its own on the attributes it shares with the
-    prefix (equal values, equal ids); None unless each relation joins the
-    prefix only on attributes of the relation before it (paths, the
-    paper's example)."""
-    (_, attrs0), *rest = relations
-    out, seen, prev = [], set(attrs0), relations[0]
-    for name, attrs in rest:
+def _join_tree(relations: Relations, data: Mapping[str, np.ndarray]
+               ) -> list[tuple[int, np.ndarray, np.ndarray]] | None:
+    """The cascade as a join tree: for each relation after the first, its
+    parent, an earlier relation that holds every attribute it shares with
+    the prefix (the latest such, so a path's parent is the relation before
+    it), and the ids of the parent's rows and of its own on those attributes
+    (equal values, equal ids).  None where some relation has no parent, or
+    shares nothing with the prefix: a cyclic query, or one in an order that
+    breaks the running intersection."""
+    out, seen = [], set(relations[0][1])
+    for j, (name, attrs) in enumerate(relations[1:], 1):
         shared = [a for a in attrs if a in seen]
-        if not shared or not set(shared) <= set(prev[1]):
+        parents = [i for i in range(j) if set(shared) <= set(relations[i][1])]
+        if not shared or not parents:
             return None
-        pk = np.asarray(data[prev[0]])[:, [tuple(prev[1]).index(a)
-                                           for a in shared]]
+        p_name, p_attrs = relations[parents[-1]]
+        pk = np.asarray(data[p_name])[:, [tuple(p_attrs).index(a)
+                                          for a in shared]]
         rk = np.asarray(data[name])[:, [tuple(attrs).index(a) for a in shared]]
         ids = _key_ids(np.concatenate([pk, rk]))
-        out.append((ids[:len(pk)], ids[len(pk):]))
+        out.append((parents[-1], ids[:len(pk)], ids[len(pk):]))
         seen |= set(attrs)
-        prev = (name, attrs)
     return out
 
 
-def _group_sum(left: np.ndarray, weight: np.ndarray, right: np.ndarray
-               ) -> np.ndarray:
+def _group_sum(left: np.ndarray, weight: np.ndarray | None,
+               right: np.ndarray) -> np.ndarray:
     """For each right row, the sum of the weights of the left rows with its
-    id: integers, summed exactly in their own dtype."""
+    id: integers, summed exactly in their own dtype; with no weight, the
+    number of such rows."""
     n = int(max(left.max(initial=0), right.max(initial=0))) + 1
+    if weight is None:
+        return np.bincount(left, minlength=n)[right]
     acc = np.zeros(n, weight.dtype)
     np.add.at(acc, left, weight)
     return acc[right]
 
 
+def _fold(tree: list, weights: list[np.ndarray | None],
+          modulus: int | None = None) -> np.ndarray:
+    """The sum over the rows of the join of relations 0..len(weights) - 1
+    of the product of their rows' weights, `weights[j]` of shape (K, rows
+    of j) for K sums at once, or None for K = 1 and a weight of 1 per row
+    (not for relation 0), without building the join.  Leaves first, each
+    relation's rows hold their own weight times their children's messages
+    and send their parent the sum of that over the rows of each key they
+    share with it.  With `modulus` each product is reduced, so that a
+    group's sum of residues below 2^31 stays below 2^64 while it holds
+    fewer than 2^33 rows."""
+    w = list(weights)
+    for j in range(len(w) - 1, 0, -1):
+        p, parent_ids, own_ids = tree[j - 1]
+        msg = np.stack([_group_sum(own_ids, wk, parent_ids)
+                        for wk in (w[j] if w[j] is not None else [None])])
+        if modulus is None:
+            w[p] = msg if w[p] is None else w[p] * msg
+        else:
+            w[p] = w[p] * (msg % np.uint64(modulus)) % np.uint64(modulus)
+    total = w[0].sum(axis=1)
+    return total if modulus is None else total % np.uint64(modulus)
+
+
+def _counts(relations: Relations, data: Mapping[str, np.ndarray]) -> list:
+    """`_fold`'s weights that count the join's rows."""
+    return [np.ones((1, len(data[relations[0][0]])), np.int64)] + [
+        None] * (len(relations) - 1)
+
+
 def prefix_sizes(relations: Relations, data: Mapping[str, np.ndarray]
                  ) -> list[int]:
-    """Row counts of each prefix R0 ⋈ … ⋈ Rj of the cascade, counted
-    without building it where the cascade is a path (`_path_keys`); built
-    otherwise."""
-    steps = _path_keys(relations, data)
-    if steps is None:
+    """Row counts of each prefix R0 ⋈ … ⋈ Rj of the cascade, each a subtree
+    of the join tree (`_join_tree`), counted without building it; built
+    where there is no tree."""
+    tree = _join_tree(relations, data)
+    if tree is None:
         return [len(natural_join(relations[:j + 1], data))
                 for j in range(len(relations))]
-    weight = np.ones(len(data[relations[0][0]]), np.int64)
-    sizes = [len(weight)]
-    for left, right in steps:
-        weight = _group_sum(left, weight, right)
-        sizes.append(int(weight.sum()))
-    return sizes
+    ones = _counts(relations, data)
+    return [int(_fold(tree, ones[:j + 1])[0]) for j in range(len(relations))]
 
 
 P = (1 << 31) - 1   # prime; a product of two residues fits in uint64
@@ -201,33 +247,25 @@ def fingerprint_rows(rows: np.ndarray, keys: Keys) -> tuple[int, ...] | None:
 def fingerprint_join(relations: Relations, data: Mapping[str, np.ndarray],
                      keys: Keys) -> tuple[int, tuple[int, ...]]:
     """(rows, fingerprints) of the bag natural join, as `fingerprint_rows`
-    would give them for `natural_join`'s rows.  Along a path cascade
-    (`_path_keys`) each row of a relation carries the sum over the prefix
-    rows it joins of their hashes, times the residues of the attributes it
-    brings; the join is never built.  Built otherwise."""
-    steps = _path_keys(relations, data)
-    if steps is None:
+    would give them for `natural_join`'s rows.  Over the join tree
+    (`_join_tree`) a relation's rows weigh the residues of the attributes
+    they bring to the prefix, each attribute brought once, and the sums of
+    the products are folded leaves first (`_fold`): the join is never
+    built.  Built where there is no tree."""
+    tree = _join_tree(relations, data)
+    if tree is None:
         rows = natural_join(relations, data)
         return len(rows), fingerprint_rows(rows, keys)
     seen: set[str] = set()
-    count = weight = None
-    for j, (name, attrs) in enumerate(relations):
+    own = []
+    for name, attrs in relations:
         new = [a for a in attrs if a not in seen]
-        own = _row_hashes(np.asarray(data[name])[:, [tuple(attrs).index(a)
-                                                     for a in new]],
-                          new, keys)
-        if j == 0:
-            count, weight = np.ones(own.shape[1], np.int64), own
-        else:
-            # A group's sum of residues stays below 2^64 while it holds
-            # fewer than 2^33 rows.
-            left, right = steps[j - 1]
-            count = _group_sum(left, count, right)
-            weight = np.stack([_group_sum(left, w, right) for w in weight])
-            weight = weight % np.uint64(P) * own % np.uint64(P)
+        own.append(_row_hashes(np.asarray(data[name])[
+            :, [tuple(attrs).index(a) for a in new]], new, keys))
         seen |= set(attrs)
-    fps = weight.sum(axis=1) % np.uint64(P)
-    return int(count.sum()), tuple(int(x) for x in fps)
+    fps = _fold(tree, own, P)
+    return (int(_fold(tree, _counts(relations, data))[0]),
+            tuple(int(x) for x in fps))
 
 
 def _mix64(x: np.ndarray) -> np.ndarray:

@@ -1,26 +1,38 @@
 """Request streams of the benchmark's cells, made from the seed.
 
 A configuration (`configs/<name>.json`) fixes the deployment: its tenants,
-each a query with the relation that holds each join attribute's heavy
-hitter, and the rows per relation.  A traffic mix (`traffic/<name>.json`)
-fixes the pool of requests one closed-loop client sends and which tenant
-sends each.  One generator reads both, so a new deployment or pool is a new
-data file and no code; a key or value it does not know is refused.
+each a query in cascade order, the rows per relation, and the generator
+that makes a request's rows, `generators/<generator>.py`, found by name.
+A traffic mix (`traffic/<name>.json`) fixes the pool of requests one
+closed-loop client sends and which tenant sends each.  So a new deployment
+or pool is new data files, and a new kind of data one new generator file;
+a key or value that neither this module nor the generator reads is
+refused.
 
 Every seed gets the same work: the same tenants and sizes, in an order the
-seed draws, and the seed draws each request's rows.  The rows come from `pinned_skew_batch`,
-a copy of the program's generator of the same name, kept here so that the
-yardstick does not move when the program's generators change.
+seed draws, and the seed draws each request's rows.
+
+A generator module exposes `KEYS`, `{"config": {...}, "tenant": {...}}`,
+the configuration and tenant keys it reads beyond `CONFIG_KEYS` and
+`TENANT_KEYS`, and `tenant_data(config, tenant, rows, data_seed)`, which
+returns each relation of the tenant's query as an int32 array of shape
+(its rows, its width), the same for the same arguments.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import math
 from dataclasses import dataclass
+from pathlib import Path
+from types import ModuleType
 from typing import Mapping, Sequence
 
 import numpy as np
 
 Relations = Sequence[tuple[str, Sequence[str]]]
+
+BENCH = Path(__file__).resolve().parent
 
 
 def attributes(relations: Relations) -> tuple[str, ...]:
@@ -36,39 +48,6 @@ def join_attributes(relations: Relations) -> tuple[str, ...]:
     """Attributes that two or more relations hold."""
     return tuple(a for a in attributes(relations)
                  if sum(a in attrs for _, attrs in relations) >= 2)
-
-
-def pinned_skew_batch(relations: Relations, n: int, heavy: Mapping[str, str],
-                      hh_rows: int, light_rows: int, tail_domain: int,
-                      seed) -> dict[str, np.ndarray]:
-    """One request's relations, `n` int32 rows each, heavy hitters pinned.
-
-    For every join attribute `a`, relation `heavy[a]` holds `hh_rows` rows of
-    value 0 and every other relation holding `a` holds `light_rows` of them,
-    on disjoint rows of a relation.  Every other row of a join column takes
-    a tail value in 1..tail_domain, each as often as any other, through a
-    seeded bijection of the tail; non-join columns cycle.  The seed also
-    permutes the rows."""
-    join = set(join_attributes(relations))
-    rng = np.random.default_rng(seed)
-    out = {}
-    for name, attrs in relations:
-        n_zero = {a: (hh_rows if heavy.get(a) == name else light_rows)
-                  for a in attrs if a in join}
-        if sum(n_zero.values()) > n:
-            raise ValueError(f"{name}: zero blocks exceed n={n}")
-        cols, start = [], 0
-        for a in attrs:
-            if a not in join:
-                cols.append(np.arange(n, dtype=np.int64) % max(tail_domain, 1))
-                continue
-            tail = 1 + rng.permutation(tail_domain)[
-                np.arange(n - n_zero[a]) % tail_domain]
-            cols.append(np.roll(np.concatenate(
-                [np.zeros(n_zero[a], np.int64), tail]), start))
-            start += n_zero[a]
-        out[name] = np.stack(cols, axis=1)[rng.permutation(n)].astype(np.int32)
-    return out
 
 
 @dataclass(frozen=True)
@@ -122,6 +101,60 @@ def check_traffic(traffic: Mapping, config: Mapping) -> None:
                          f"{config['rows']!r}")
 
 
+# Every key a configuration and a tenant may hold besides its generator's
+# `KEYS`: those this module and the harness read, and the notes that say
+# where the deployment comes from and what it assumes.
+CONFIG_KEYS = {"name", "source", "deployment", "guarantee", "generator",
+               "rehearsal", "tenants", "rows", "k_per_chip", "chips",
+               "out_capacity", "reduced", "assumed",
+               "output_rows_per_request"}
+TENANT_KEYS = {"relations"}
+
+
+def generator(name: str, bench: Path = BENCH) -> ModuleType:
+    """The module `generators/<name>.py`; an unknown name is an error,
+    never a default."""
+    path = bench / "generators" / f"{name}.py"
+    if not isinstance(name, str) or not path.is_file():
+        have = sorted(p.stem for p in (bench / "generators").glob("*.py"))
+        raise ValueError(f"unknown generator {name!r}; have {have}")
+    return load_module(path)
+
+
+@functools.cache
+def load_module(path: Path) -> ModuleType:
+    """The Python file at `path` as a module of its own, loaded once: a
+    generator or a metric's reader, found by name."""
+    name = f"bench_{path.parent.name}_{path.stem}"
+    mod_spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def check_config(config: Mapping, bench: Path = BENCH) -> None:
+    """Refuse a configuration that names no known generator, or holds a
+    configuration, tenant or rehearsal key that neither the common set nor
+    its generator's `KEYS` names."""
+    keys = generator(config.get("generator"), bench).KEYS
+    known = CONFIG_KEYS | set(keys["config"])
+    if set(config) - known:
+        raise ValueError(f"the configuration's keys "
+                         f"{sorted(set(config) - known)} are read by neither "
+                         f"the harness nor generator {config['generator']!r}")
+    rehearsal = config.get("rehearsal")
+    if not isinstance(rehearsal, dict) or set(rehearsal) - known:
+        raise ValueError(f"rehearsal must map configuration keys to their "
+                         f"values for the CPU rehearsal, not {rehearsal!r}")
+    for name, t in config["tenants"].items():
+        unknown = set(t) - TENANT_KEYS - set(keys["tenant"])
+        if unknown:
+            raise ValueError(f"tenant {name!r}: the keys {sorted(unknown)} "
+                             f"are read by neither the harness nor generator "
+                             f"{config['generator']!r}")
+
+
 def request_specs(config: Mapping, traffic: Mapping, seed: int
                   ) -> list[RequestSpec]:
     """The pool of one run: `pool` requests at the configuration's rows,
@@ -138,11 +171,10 @@ def request_specs(config: Mapping, traffic: Mapping, seed: int
 
 def tenant_data(config: Mapping, tenant: str, rows: int, data_seed
                 ) -> dict[str, np.ndarray]:
-    """The rows of one request of `tenant` at `rows` per relation."""
-    t = config["tenants"][tenant]
-    hh = rows // int(config["heavy_share"])
-    return pinned_skew_batch(relations(config, tenant), rows, t["heavy"], hh,
-                             int(config["light_rows"]), rows - hh, data_seed)
+    """The rows of one request of `tenant` at `rows`, from the generator
+    the configuration names."""
+    return generator(config["generator"]).tenant_data(config, tenant, rows,
+                                                      data_seed)
 
 
 def relations(config: Mapping, tenant: str) -> list[tuple[str, tuple]]:

@@ -4,7 +4,8 @@
 
 The cell, its configuration file and its metrics are found by name in
 `BENCHMARK.json` at the root of the checkout; the traffic mix is
-`bench/traffic/<traffic>.json` and each metric is read by
+`bench/traffic/<traffic>.json`, the configuration's rows are made by
+`bench/generators/<generator>.py` and each metric is read by
 `bench/metrics/<metric>.py`.  A run makes its requests from the seed,
 serves each request of its pool once as a warm-up, through
 `JoinServingEngine.submit` and `step_round` on a mesh of the cell's chips,
@@ -29,7 +30,6 @@ import time
 PROCESS_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import shutil  # noqa: E402
@@ -76,6 +76,7 @@ def load_cell(workload: str, root: Path = ROOT) -> tuple[dict, dict, dict,
     config = json.loads((root / configs[cell["config"]]["file"]).read_text())
     traffic = json.loads(
         (root / "bench" / "traffic" / f"{cell['traffic']}.json").read_text())
+    gen.check_config(config, root / "bench")
     gen.check_traffic(traffic, config)
     return spec, cell, config, traffic
 
@@ -95,12 +96,7 @@ def cell_metrics(spec: Mapping, cell: str, traced: bool) -> list[dict]:
 
 def metric_reader(name: str, bench: Path = BENCH) -> Callable:
     """`read(run)` of `metrics/<name>.py`."""
-    path = bench / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return gen.load_module(bench / "metrics" / f"{name}.py").read
 
 
 def device_peaks(kind: str, bench: Path = BENCH) -> dict:

@@ -1,19 +1,21 @@
-"""Drive a cell's whole run on the CPU at a tiny size: the harness's look
-for a chip is skipped, everything after it runs."""
+"""Drive a cell's whole run on the CPU at a tiny size, its configuration's
+`rehearsal` keys in place of its own: the harness's look for a chip is
+skipped, everything after it runs."""
 import json
 
 import jax
 
 from bench import run as harness
 
-SMALL_ROWS = {"paper3-batch": 4096}
 SEED = 2**31 + 7
 
 
 def rehearse(cell: str, seconds: float = 1.0, traced: bool = False,
              control=None, rows=None):
     spec, w, config, traffic = harness.load_cell(cell)
-    config = {**config, "rows": rows or SMALL_ROWS[cell]}
+    config = {**config, **config["rehearsal"]}
+    if rows:
+        config["rows"] = rows
     devices = jax.devices()[:w["chips"]]
     run, out = harness.run_cell(w, config, traffic, SEED, seconds, devices,
                                 harness.device_peaks("TPU v5 lite"),

@@ -6,7 +6,7 @@ from collections import Counter
 import numpy as np
 import pytest
 
-from bench import gen
+from bench import check, gen
 from bench import run as harness
 
 SEED = 2**31 + 12345
@@ -64,3 +64,22 @@ def test_pinned_batch_pins_the_heavy_hitters():
                 assert zeros == want, (tenant, name, attr)
         assert all(a.dtype == np.int32 and len(a) == n
                    for a in data.values())
+
+
+# `paper3-batch`'s stream and its first request's reference, as read before
+# its generator moved into `generators/`: a change of harness that moves
+# either changes what the cell measures.
+PAPER3_DIGEST = ("5b45a31af196f34657816a32fc5bf9bd"
+                 "1d44774708cf6cdf149708f5b7526a5e")
+PAPER3_FIRST = (6291448, (212160149, 1031203454))
+
+
+def test_paper3_batch_reads_the_same_bytes_and_fingerprints():
+    _, _, config, traffic = harness.load_cell("paper3-batch")
+    assert _digest(config, traffic, SEED) == PAPER3_DIGEST
+    s = gen.request_specs(config, traffic, SEED)[0]
+    ref = check.Reference(gen.relations(config, s.tenant),
+                          gen.tenant_data(config, s.tenant, s.rows,
+                                          s.data_seed),
+                          (SEED, 2, s.index))
+    assert (ref.n, ref.fingerprints) == PAPER3_FIRST
