@@ -9,7 +9,12 @@ rests on.  This script asserts the contract STRUCTURALLY, by lowering the
 actual functions and counting opcodes with `launch.hlo_analysis.count_ops`
 (which parses fusion bodies, so a fused gather still counts):
 
-  * `_scatter_assemble_host`  -> zero `gather` ops (the host-twin assemble);
+  * `_scatter_assemble_host`  -> zero `gather` ops (the host-twin assemble,
+    one stable sort on the destination);
+  * `scatter_pack_host`       -> zero `scatter` ops: the sort gives each
+    copy its stable rank by position, so neither the rank pass's
+    scatter-add nor the row scatter it replaced may come back; teeth:
+    `bucket_pack_host`, which still ranks and scatters, counts >= 1;
   * `scatter_pack` interpret  -> zero `gather` ops (the kernel body lowers
     its dynamic stores to dynamic-update-slice, never gather);
   * `expand_rows` interpret   -> zero `gather` ops (one-hot contraction);
@@ -44,10 +49,11 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.kernels.bucket_pack import bucket_pack_host
     from repro.kernels.map_pack import _assemble_tagged
     from repro.kernels.scatter_pack import (_scatter_assemble_host,
                                             expand_rows, expand_rows_host,
-                                            scatter_pack)
+                                            scatter_pack, scatter_pack_host)
     from repro.launch.hlo_analysis import count_ops
 
     rng = np.random.default_rng(17)
@@ -63,7 +69,7 @@ def main() -> int:
         if not ok:
             failures.append(name)
 
-    # --- map-phase assemble: scatter vs the superseded gather ------------
+    # --- map-phase assemble: sort vs the superseded gather ---------------
     n, w, fanout, n_dev, cap = 64, 3, 2, 4, 16
     m = n * fanout
     rows = jnp.asarray(rng.integers(0, 99, (n, w)), jnp.int32)
@@ -71,16 +77,25 @@ def main() -> int:
     d = jnp.asarray(rng.integers(0, n_dev, (m,)), jnp.int32)
     rank = jnp.asarray(rng.integers(0, cap, (m,)), jnp.int32)
     hist = jnp.asarray(rng.integers(0, cap, (n_dev,)), jnp.int32)
-    gate("scatter assemble (_scatter_assemble_host)",
-         _lower_text(_scatter_assemble_host, rows, tag, d, rank, hist,
+    gate("sort assemble (_scatter_assemble_host)",
+         _lower_text(_scatter_assemble_host, rows, tag, d,
                      n_dev=n_dev, cap=cap, fanout=fanout), want_zero=True)
     gate("old gather assemble (_assemble_tagged)",
          _lower_text(_assemble_tagged, rows, tag, d, rank, hist,
                      n_dev=n_dev, cap=cap, fanout=fanout), want_zero=False)
 
-    # --- map-phase megakernel body (interpret-mode lowering) -------------
+    # --- map-phase host twin: no scatter; teeth: rank-and-scatter pack ---
     routes = ((((0, 12345, 4, 1),), (0,), 0, (), ()),)
     ptable = jnp.asarray(np.arange(4, dtype=np.int32) % n_dev)
+    gate("scatter_pack_host twin",
+         _lower_text(scatter_pack_host, rows, ptable, routes=routes, k=4,
+                     n_dev=n_dev, cap=cap), want_zero=True, op="scatter")
+    gate("rank-and-scatter pack (bucket_pack_host)",
+         _lower_text(bucket_pack_host, d, jnp.repeat(rows, fanout, axis=0),
+                     k=n_dev, cap=cap),
+         want_zero=False, op="scatter")
+
+    # --- map-phase megakernel body (interpret-mode lowering) -------------
     gate("scatter_pack kernel (interpret)",
          _lower_text(scatter_pack, rows, ptable, routes=routes, k=4,
                      n_dev=n_dev, cap=cap, interpret=True), want_zero=True)
@@ -104,8 +119,8 @@ def main() -> int:
         print(f"HLO GUARD FAILED: {', '.join(failures)}", file=sys.stderr)
         return 1
     print("HLO guard passed: assemble/expansion paths lower with zero "
-          "XLA gathers (and the counter's teeth bite); the host expansion "
-          "has no search loop.")
+          "XLA gathers, the map's host twin with zero scatters (and the "
+          "counter's teeth bite); the host expansion has no search loop.")
     return 0
 
 

@@ -21,9 +21,11 @@ read-modify-write idiom as build_probe's segment scans).  Per tile of B rows:
 HBM traffic is O(m + k) (each destination read once, rank written once, one
 (k + 1,) histogram) versus the O(m·k) prefix-sum matrix of the old pack; VPU
 work is O(m·(B + k)) in cheap compare/reduce form with no scan over m.  The
-scatter itself is deliberately left to XLA (`bucket_pack` below): an inverse
-permutation is scattered as int32 row ids and the wide rows move in a single
-gather — scatter-heavy code is not where TPUs win; sizing + gather is.
+placement is left to XLA (`bucket_pack` below): an inverse permutation is
+scattered as int32 row ids and the wide rows move in a single gather.  The
+executor's map no longer ranks at all: `scatter_pack_host` places its copies
+with one stable sort on the destination (kernels/scatter_pack.py), as an
+XLA scatter of rows serializes on the TPU.
 
 `bucket_rank_host` is the identical algorithm phrased in vectorized XLA ops
 (scatter-add tile histograms, one small (T, k + 1) cumsum, batched triangular

@@ -30,10 +30,14 @@ flat buffer block is the VMEM budget to watch — cap · n_dev · (w + 1) words;
 the async-DMA HBM variant is the ROADMAP follow-up.
 
 `scatter_pack_host` / `expand_rows_host` are the bit-identical vectorized-XLA
-twins (production off-TPU): the host assemble is ONE ``.at[slot].set`` row
-scatter into the same trash-row buffer — the copies move once, as in
-`_assemble_tagged`, but as a scatter instead of an inverse-permutation
-gather, which is what `scripts/check_hlo.py` pins.  `expand_rows_host` finds
+twins, which the main path runs on every backend (`kernels.ops.TPU_IMPL`).
+The map twin assembles with ONE stable `lax.sort` keyed on the destination,
+the copies' columns and tag riding as operands: a copy's place in its
+bucket is its stable rank, so there is no rank pass, and each device's
+`cap` rows are a dynamic slice of the sorted copies at the histogram's
+exclusive prefix.  The assemble lowers with no gather and the twin with no
+scatter, which `scripts/check_hlo.py` pins: the row scatter onto a trash
+row that it replaced serialized on the TPU.  `expand_rows_host` finds
 each slot's left row by merging the slots into the group offsets with two
 sorts and a scan (`join_probe.sorted_locate`; a binary search is a loop of
 gathers, slow on a TPU, and `scripts/check_hlo.py` pins its absence), then
@@ -52,7 +56,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .bucket_pack import DEFAULT_HOST_BLOCK, bucket_rank_host
 from .join_probe import sorted_locate
 from .map_pack import (DEFAULT_BLOCK_COPIES, RouteSpec, _empty_pack,
                        _route_block, _row_block, route_fanout)
@@ -74,25 +77,41 @@ def _expand_block(block: int, n_l: int, n_r: int) -> int:
 # ---------------------------------------------------------------------------
 
 def _scatter_assemble_host(rows: jnp.ndarray, tag: jnp.ndarray,
-                           d: jnp.ndarray, rank: jnp.ndarray,
-                           hist: jnp.ndarray, n_dev: int, cap: int,
+                           d: jnp.ndarray, n_dev: int, cap: int,
                            fanout: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(buf (n_dev, cap, w+1), overflow) from per-copy streams — the final
-    SCATTER.  The assembled copies move once, `.at[slot].set` into a flat
-    buffer whose last row is the trash slot for invalid/overflow copies
-    (every valid (d, rank) slot is unique, so the scatter is race-free);
-    unwritten slots keep INVALID.  Bit-identical to `_assemble_tagged`, with
-    zero gather ops in the lowered HLO (`scripts/check_hlo.py` pins this)."""
+    """(buf (n_dev, cap, w+1), overflow) from per-copy destinations — ONE
+    stable sort.  The copies' columns and tag ride as operands of a
+    `lax.sort` keyed on the destination `d` (invalid copies carry the
+    sentinel ``n_dev`` and sort last); stability keeps each bucket in copy
+    order, so a copy's place in its bucket IS its stable rank.  Device dd's
+    rows are then the `cap` sorted rows from the exclusive prefix of the
+    bucket histogram, with rows past ``min(hist[dd], cap)`` reset to
+    INVALID.  Bit-identical to `_assemble_tagged`, with no rank pass, zero
+    gather and zero scatter ops in the lowered HLO (`scripts/check_hlo.py`
+    pins both)."""
     n, w = rows.shape
     m = n * fanout
+    cols = [jnp.broadcast_to(rows[:, c, None], (n, fanout)).reshape(m)
+            for c in range(w)]
+    _, *srt = jax.lax.sort((d, *cols, tag.astype(rows.dtype)), num_keys=1,
+                           is_stable=True)
+    # Histogram as a compare-and-sum over the n_dev real buckets (no
+    # scatter-add); the sentinel bucket needs no count.
+    hist = (d[None, :] == jnp.arange(n_dev, dtype=d.dtype)[:, None]).sum(
+        axis=1, dtype=jnp.int32)
     overflow = jnp.maximum(hist - cap, 0).sum()
-    expanded = jnp.broadcast_to(rows[:, None, :], (n, fanout, w)).reshape(m, w)
-    vals = jnp.concatenate([expanded, tag.astype(rows.dtype)[:, None]],
-                           axis=1)
-    slot = jnp.where((d < n_dev) & (rank < cap), d * cap + rank, n_dev * cap)
-    buf = jnp.full((n_dev * cap + 1, w + 1), INVALID, rows.dtype)
-    buf = buf.at[slot].set(vals, mode="drop")[:n_dev * cap]
-    return buf.reshape(n_dev, cap, w + 1), overflow
+    start = jnp.cumsum(hist) - hist
+    # INVALID tail so a slice starting anywhere in [0, m] never clamps.
+    pad = jnp.full((cap,), INVALID, rows.dtype)
+    srt = [jnp.concatenate([col, pad]) for col in srt]
+    j = jnp.arange(cap, dtype=jnp.int32)
+    bufs = []
+    for dd in range(n_dev):
+        keep = j < jnp.minimum(hist[dd], cap)
+        bufs.append(jnp.stack(
+            [jnp.where(keep, jax.lax.dynamic_slice(col, (start[dd],), (cap,)),
+                       INVALID) for col in srt], axis=1))
+    return jnp.stack(bufs), overflow
 
 
 def _scatter_pack_kernel(rows_ref, table_ref, buf_ref, hist_ref, *,
@@ -189,17 +208,16 @@ def scatter_pack(rows: jnp.ndarray, ptable: jnp.ndarray, *,
     return buf[:n_dev * cap].reshape(n_dev, cap, w + 1), overflow
 
 
-@functools.partial(jax.jit, static_argnames=("routes", "k", "n_dev", "cap",
-                                             "block"))
+@functools.partial(jax.jit, static_argnames=("routes", "k", "n_dev", "cap"))
 def scatter_pack_host(rows: jnp.ndarray, ptable: jnp.ndarray, *,
-                      routes: RouteSpec, k: int, n_dev: int, cap: int,
-                      block: int = DEFAULT_HOST_BLOCK
+                      routes: RouteSpec, k: int, n_dev: int, cap: int
                       ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """`scatter_pack` in vectorized XLA — bit-identical outputs.
 
-    Identical to `map_pack_host` up to the rank streams; the assemble stage
-    is the `.at[slot].set` scatter of `_scatter_assemble_host` instead of
-    the inverse-permutation gather.
+    Routing and the placement fold as in `map_pack_host`; the buffer is then
+    assembled by `_scatter_assemble_host`'s one stable sort on the
+    destination, which yields the stable ranks by position, so there is no
+    `bucket_rank_host` pass and no scatter.
     """
     n, w = rows.shape
     fanout = route_fanout(routes)
@@ -207,11 +225,9 @@ def scatter_pack_host(rows: jnp.ndarray, ptable: jnp.ndarray, *,
         return _empty_pack(w, n_dev, cap, rows.dtype)
     logical, valid = _route_block(rows, routes, k)          # (n, F)
     wrapped = jnp.where(valid, logical % k, 0)
-    phys = jnp.where(valid, ptable[wrapped], INVALID).reshape(-1)
-    rank, hist = bucket_rank_host(phys, k=n_dev, block=block)
-    d = jnp.where(phys >= 0, phys, jnp.int32(n_dev))
-    return _scatter_assemble_host(rows, logical.reshape(-1), d, rank, hist,
-                                  n_dev, cap, fanout)
+    d = jnp.where(valid, ptable[wrapped], n_dev).reshape(-1)
+    return _scatter_assemble_host(rows, logical.reshape(-1), d, n_dev, cap,
+                                  fanout)
 
 
 # ---------------------------------------------------------------------------

@@ -15,9 +15,12 @@ against `np.searchsorted` and the ref) on real probe outputs and on
 degenerate shapes: ragged caps that end mid-group, overflow truncation,
 zero-size sides, zero total matches, and zero-count rows at either end.
 """
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
-import jax.numpy as jnp
 
 from hypothesis import given, settings, strategies as st
 from repro.core.executor import _Route, _probe_sort, _route_specs
@@ -27,6 +30,7 @@ from repro.kernels import map_pack as mp
 from repro.kernels import ops as kops
 from repro.kernels import scatter_pack as sp
 from repro.kernels.ref import expand_rows_ref, scatter_pack_ref
+from repro.launch.hlo_analysis import count_ops
 
 SEED_A, SEED_B = 0x9E3779B1, 0x85EBCA77          # odd multiply-shift seeds
 
@@ -97,15 +101,70 @@ def test_scatter_pack_all_invalid(k, n_dev):
     assert (buf == -1).all()
 
 
-@pytest.mark.parametrize("k,n_dev", [(8, 4), (256, 8)])
-def test_scatter_pack_overflow_parity(k, n_dev):
-    """Tiny caps force overflow; trash-row routing must not disturb counts."""
+def _cap_for(case: str, m: int, hist: np.ndarray) -> int:
+    """The capacity an overflow case runs at: 2, or relative to the copies
+    m, or (`fit_after`) the largest bucket after the first, so bucket 0
+    overflows and the next buckets fit, one exactly."""
+    if case == "fit_after":
+        return int(hist[1:].max())
+    return {"2": 2, "m": m, "m-1": m - 1, "2m": 2 * m}[case]
+
+
+@pytest.mark.parametrize("k,n_dev,ptable,case", [
+    pytest.param(8, 4, None, "2", id="8-4"),
+    pytest.param(256, 8, None, "2", id="256-8"),
+    pytest.param(1, 1, None, "m", id="1-1-all-valid-cap-m"),
+    pytest.param(1, 1, None, "m-1", id="1-1-all-valid-cap-m-1"),
+    pytest.param(1, 1, None, "2m", id="1-1-all-valid-cap-2m"),
+    pytest.param(8, 4, (0, 0, 0, 0, 1, 1, 2, 2), "fit_after",
+                 id="8-4-overflow-then-fit"),
+])
+def test_scatter_pack_overflow_parity(k, n_dev, ptable, case):
+    """Caps at and past the copies' count: trash-row routing and the sort's
+    per-device slices must not disturb the buffer or the counts, also where
+    a device's slice starts past an overflowed bucket."""
     rng = np.random.default_rng(k)
     routes = _routes_for(k)
     rows = _rand_rows(rng, 150, invalid_frac=0.0)
-    _, over = _assert_matches_map_pack(
-        rows, routes, modulo_placement(k, n_dev).table, k, n_dev, 2)
-    assert over > 0
+    table = (modulo_placement(k, n_dev).table if ptable is None
+             else np.asarray(ptable, np.int32))
+    m = 150 * mp.route_fanout(_route_specs(routes))
+    # Copies per device, from the oracle at a capacity nothing overflows.
+    full, _ = scatter_pack_ref(jnp.asarray(rows), jnp.asarray(table),
+                               _route_specs(routes), k, n_dev, m)
+    hist = (np.asarray(full)[..., -1] >= 0).sum(axis=1)
+    cap = _cap_for(case, m, hist)
+    buf, over = _assert_matches_map_pack(rows, routes, table, k, n_dev, cap)
+    assert over == np.maximum(hist - cap, 0).sum()
+    np.testing.assert_array_equal((buf[..., -1] >= 0).sum(axis=1),
+                                  np.minimum(hist, cap))
+    if case == "2":
+        assert over > 0
+    if k == 1:
+        assert hist.sum() == m                      # every copy valid
+    if case == "fit_after":
+        assert hist[0] > cap and (hist[1:] <= cap).all() and hist[1] > 0
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_scatter_pack_host_lowers_without_scatter(n_dev):
+    """The host twin assembles by one sort: a row scatter (or the rank
+    pass's scatter-add) coming back fails here.  Teeth: the counter sees
+    the scatter `map_pack_host` keeps for its inverse permutation."""
+    k = 8
+    spec = _route_specs(_routes_for(k))
+    rows = jnp.asarray(_rand_rows(np.random.default_rng(5), 64))
+    pt = jnp.asarray(modulo_placement(k, n_dev).table)
+
+    def hlo(fn):
+        return jax.jit(functools.partial(
+            fn, routes=spec, k=k, n_dev=n_dev, cap=32)).lower(
+                rows, pt).compile().as_text()
+
+    text = hlo(sp.scatter_pack_host)
+    assert count_ops(text, "scatter") == 0
+    assert count_ops(text, "sort") == 1
+    assert count_ops(hlo(mp.map_pack_host), "scatter") >= 1
 
 
 def test_scatter_pack_tile_boundary_carry():
