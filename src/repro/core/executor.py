@@ -1,33 +1,32 @@
 """Distributed SkewShares execution engine: map -> shuffle -> reduce in JAX.
 
 The MapReduce round of the paper as a `shard_map` over a 1-D device axis.
-The full design narrative — per-phase kernel inventory, the logical-cell /
+The full design narrative — what each phase runs, the logical-cell /
 physical-device fold, capacity derivation, session caching — lives in
 docs/architecture.md; this docstring keeps only the invariants the code
 relies on.
 
-  map     the Pallas `scatter_pack` megakernel: route (all residual routes,
-          fused multiply-shift hashes), placement fold, radix rank, and the
-          in-kernel scatter assembly in ONE streaming pass per relation —
-          the routed (n·F, w+1) expansion is never materialized and the
-          shuffle buffer is written with zero XLA gathers.  The staged
-          `_route_relation` -> `_fold_dests` -> `_pack_buckets` composition
-          survives (fuse_map=False / use_kernels=False) as the bit-exactness
-          oracle.
-  shuffle the megakernel's (n_devices, cap, w+1) fixed-capacity buffer per
-          relation goes through one `all_to_all` — or, with
+  map     `kernels.scatter_pack.scatter_pack_host`, per relation: route
+          every row through all of its residual routes (fused multiply-shift
+          hashes, type constraints, replication offsets), fold each wrapped
+          logical cell onto its device through the placement table, and
+          assemble the (n_devices, cap, w+1) shuffle buffer with ONE stable
+          sort keyed on the destination — no rank pass, no scatter; each
+          device's rows are a slice of the sorted copies.
+  shuffle each relation's buffer goes through one `all_to_all` — or, with
           `overlap_shuffle = C ≥ 2`, through C chunked all_to_alls
           interleaved with the next chunk's pack (each chunk's send buffer
-          is final the moment its tiles are packed — the paper's one-round
-          structure is what makes the overlap legal; the serial path stays
-          the bit-exactness oracle up to fragment arrival order).
-  reduce  `_local_join`: radix hash-join cascade (the `join_probe` kernel
-          family — fused key hash, carried-histogram build, key-verified
-          chained probe), matching only within equal logical cell ids, with
-          the prefix-sum expansion running gather-free through
-          `kernels.scatter_pack.expand_rows`.  The sort-merge formulation
-          survives (hash_reduce=False) as the mid-fidelity oracle, the
-          dense match matrix as the ground oracle.
+          is final the moment it is packed — the paper's one-round structure
+          is what makes the overlap legal; the serial path stays the
+          bit-exactness oracle up to fragment arrival order).
+  reduce  `_local_join`: a cascade of radix hash joins matching only within
+          equal logical cell ids.  Each step probes with
+          `kernels.join_probe.join_probe_host` (30-bit hash partitions from
+          one sort, left rows located by a merge, key-verified chains) and
+          expands the (counts, lo, perm) result into `cap_out` rows with
+          `kernels.scatter_pack.expand_rows_host` (each slot's left row
+          located by a merge, then gathered).  `_local_join_dense`, the
+          O(n_l·n_r) match-matrix cascade, is the ground oracle.
 
 Invariants:
   * Logical cells of every residual join live in one flat id space
@@ -62,11 +61,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels import ops as kops
-from ..kernels.join_probe import default_bits, probe_tables
-from ..kernels.map_pack import count_scatter
-from ..kernels.ref import (bucket_pack_ref, build_table_ref, expand_rows_ref,
-                           fold_cells_ref, join_hash_ref, run_lengths_ref,
-                           segment_scan_ref)
 from .hypercube import hash_seed
 from .placement import (CellPlacement, check_fold, modulo_placement,
                         place_cells)
@@ -195,14 +189,6 @@ class RetryPolicy:
 class ExecutorConfig:
     capacity_factor: float = 2.0       # shuffle slack over the max observed load
     out_capacity: int = 4096           # per-cell join output rows (static)
-    use_kernels: bool = True           # hash/scan via Pallas (else jnp ref path)
-    fuse_map: bool = True              # map phase via the map_pack megakernel
-                                       # (else staged route->fold->pack oracle)
-    hash_reduce: bool = True           # reduce phase via the join_probe radix
-                                       # hash join (else sort-merge oracle)
-    hash_bits: int | None = None       # hash-table bits; None -> ~2·n_r
-                                       # buckets (tiny values force collision
-                                       # chains — resolution stays exact)
     cap_bucket: float = 2.0            # geometric grid DERIVED capacities are
                                        # quantized to (≤ 1 disables); aligns
                                        # retries + similar chunks on warm
@@ -232,15 +218,14 @@ class _Route:
     hashed: tuple[tuple[int, int, int, int], ...]  # (col, seed, share, stride)
     rep_strides: tuple[int, ...]                   # flattened replication offsets
     offset: int
-    k: int                                          # cells wrap modulo k
     # Type constraints (paper Example 3.2): which rows participate.
     eq_constraints: tuple[tuple[int, int], ...]    # (col, value) must equal
     notin_constraints: tuple[tuple[int, tuple[int, ...]], ...]  # (col, hh_values)
 
 
 def _route_specs(routes: list[_Route]) -> tuple:
-    """Flatten `_Route`s to the static nested-tuple `RouteSpec` the
-    `map_pack` megakernel compiles into its body (k rides separately)."""
+    """Flatten `_Route`s to the static nested-tuple `RouteSpec` the map and
+    the count pass compile in (k rides separately)."""
     return tuple((r.hashed, r.rep_strides, r.offset, r.eq_constraints,
                   r.notin_constraints) for r in routes)
 
@@ -275,67 +260,8 @@ def _build_routes(plan: SkewJoinPlan) -> dict[str, list[_Route]]:
                     notins.append((i, tuple(int(v) for v in hh_vals)))
             routes[rel.name].append(_Route(
                 rel.name, tuple(hashed), tuple(int(x) for x in reps),
-                cube.offset, plan.k, tuple(eqs), tuple(notins)))
+                cube.offset, tuple(eqs), tuple(notins)))
     return routes
-
-
-# ---------------------------------------------------------------------------
-# Map phase
-# ---------------------------------------------------------------------------
-
-def _route_relation(rows: jnp.ndarray, routes: list[_Route], use_kernels: bool
-                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Route one relation through ALL of its residual routes in a single pass.
-
-    Returns (dest (n·F,), rows_tagged (n·F, w+1)) where F is the total fanout
-    over every route.  Per-route logical cells are assembled into one (n, F)
-    buffer; the rows are broadcast and tagged with their UNWRAPPED logical
-    cell id (last column — the local-join key that makes shared cells exact)
-    exactly once.  `dest` is the WRAPPED logical cell (logical % k, in
-    [0, k)); -1 marks non-members.  Physical destinations are the caller's
-    concern: compose with `_fold_dests` and a placement table.
-    """
-    n, w = rows.shape
-    member_base = rows[:, 0] != INVALID        # shared by every route: hoisted
-    logical_cols, dest_cols = [], []
-    for route in routes:
-        member = member_base
-        for col, val in route.eq_constraints:
-            member &= rows[:, col] == val
-        for col, vals in route.notin_constraints:
-            # One comparison against the stacked HH values, not |vals| passes.
-            hh = jnp.asarray(vals, rows.dtype)
-            member &= ~(rows[:, col][:, None] == hh[None, :]).any(axis=1)
-        if route.hashed and use_kernels:
-            # Fused Pallas router: one VMEM pass for all hashed attributes.
-            base = kops.route_cells(rows, route.hashed)
-        elif route.hashed:
-            from ..kernels.ref import route_cells_ref
-            base = route_cells_ref(rows, route.hashed)
-        else:
-            base = jnp.zeros((n,), jnp.int32)
-        reps = jnp.asarray(route.rep_strides, jnp.int32)        # (fanout_r,)
-        logical = base[:, None] + reps[None, :] + route.offset  # (n, fanout_r)
-        logical = jnp.where(member[:, None], logical, INVALID)
-        logical_cols.append(logical)
-        dest_cols.append(jnp.where(member[:, None], logical % route.k, INVALID))
-    logical = jnp.concatenate(logical_cols, axis=1)             # (n, F)
-    dest = jnp.concatenate(dest_cols, axis=1)
-    fanout = logical.shape[1]
-    tagged = jnp.concatenate(
-        [jnp.broadcast_to(rows[:, None, :], (n, fanout, w)),
-         logical[:, :, None].astype(rows.dtype)], axis=-1)
-    return dest.reshape(-1), tagged.reshape(n * fanout, w + 1)
-
-
-def _count_matrix(dest: jnp.ndarray, n: int, k: int, n_src: int
-                  ) -> jnp.ndarray:
-    """(n_src, k) histogram of routed copies per (source block, wrapped cell).
-
-    The staged count formula — `map_count`'s semantic contract, shared by
-    `_count_pass`'s oracle branch, the map_scaling benchmark, and the tests
-    (the one scatter `kernels.map_pack.count_scatter` defines)."""
-    return count_scatter(dest, n, k, n_src)
 
 
 def _validate_relation(name: str, arr: np.ndarray, width: int | None = None
@@ -385,165 +311,11 @@ def _check_placement_compat(placement: CellPlacement, k: int, n_dev: int
             f"devices; plan/mesh need {k} -> {n_dev}")
 
 
-def _fold_dests(dest: jnp.ndarray, ptable: jnp.ndarray, use_kernels: bool
-                ) -> jnp.ndarray:
-    """Wrapped logical dests -> physical devices via the placement table.
-
-    `ptable` is the device-resident `CellPlacement.table` ((k,) int32,
-    replicated); -1 non-members pass through.  Pallas `fold_cells` on the
-    kernel path, `fold_cells_ref` otherwise."""
-    if use_kernels:
-        return kops.fold_cells(dest, ptable)
-    return fold_cells_ref(dest, ptable)
-
-
-# ---------------------------------------------------------------------------
-# Shuffle pack
-# ---------------------------------------------------------------------------
-
-def _pack_buckets(dest: jnp.ndarray, rows: jnp.ndarray, k: int, cap: int,
-                  use_kernels: bool = True
-                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Radix counting-sort scatter of (dest, rows) into a (k, cap, w) buffer.
-
-    One streaming pass via the `bucket_pack` kernel: per-tile histograms
-    carried across tiles give each row its stable within-bucket rank (bucket
-    contents keep arrival order, bit-identical to the argsort pack kept below
-    as the test oracle), and the accumulated histogram is the per-bucket load,
-    so the overflow count needs no extra pass.  O(m + k) for ANY k — there is
-    no argsort dispatch and no O(m·k) one-hot prefix-sum matrix.  Returns
-    (buf, overflow)."""
-    if use_kernels:
-        return kops.bucket_pack(dest, rows, k, cap)
-    return bucket_pack_ref(dest, rows, k, cap)
-
-
-def _pack_buckets_argsort(dest: jnp.ndarray, rows: jnp.ndarray, k: int, cap: int
-                          ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Full-argsort pack — superseded by the counting sort in `_pack_buckets`;
-    kept as the equivalence oracle for tests."""
-    m, w = rows.shape
-    big = jnp.where(dest < 0, jnp.int32(k), dest.astype(jnp.int32))  # invalid last
-    order = jnp.argsort(big, stable=True)
-    sd, sr = big[order], rows[order]
-    start = jnp.searchsorted(sd, sd, side="left")
-    pos = jnp.arange(m, dtype=jnp.int32) - start.astype(jnp.int32)
-    valid = sd < k
-    overflow = ((pos >= cap) & valid).sum()
-    buf = jnp.full((k, cap, w), INVALID, dtype=rows.dtype)
-    buf = buf.at[sd, pos].set(sr, mode="drop")   # pos ≥ cap or sd = k -> dropped
-    return buf, overflow
-
-
 # ---------------------------------------------------------------------------
 # Reduce phase
 # ---------------------------------------------------------------------------
 
-def _plain_lexsort(keys: jnp.ndarray) -> jnp.ndarray:
-    """w-pass stable lexsort (col 0 primary) — the width-overflow fallback."""
-    return jnp.lexsort(tuple(keys[:, j] for j in range(keys.shape[1] - 1, -1, -1)))
-
-
-def _lexsort_rows(keys: jnp.ndarray) -> jnp.ndarray:
-    """Stable lexicographic row order of a (n, w) key matrix (col 0 primary).
-
-    Narrow keys are packed into a SINGLE sort word: per-column bit widths are
-    measured at runtime (values are ≥ -3 — the executor's sentinel floor — so
-    a +3 shift makes them unsigned) and, when they sum to ≤ 31 bits, one
-    stable argsort of the packed word replaces the w XLA sort passes.  Ties
-    in the packed word are ties in every column, so stability makes the
-    permutation bit-identical to the lexsort.  31 bits is the single-word
-    budget because jax x64 is disabled repo-wide (a 64-bit pack needs
-    jax_enable_x64); wider keys take the fallback via `lax.cond` — the width
-    test is data-dependent, so both branches compile and the cheap one runs.
-    """
-    n, w = keys.shape
-    if w == 1:
-        return jnp.argsort(keys[:, 0], stable=True)
-    if n == 0:
-        return jnp.zeros((0,), jnp.int32)
-    maxes = keys.max(axis=0)                           # (w,) runtime, ≥ -3
-    # Exact integer bit widths of (max + 3): 1 + |{b ≥ 1 : max ≥ 2^b - 3}|
-    # (compared against int32-safe thresholds — max + 3 itself may overflow).
-    thresh = jnp.asarray([(1 << b) - 3 for b in range(1, 32)], jnp.int32)
-    widths = 1 + (maxes[:, None] >= thresh[None, :]).sum(axis=1)
-    # Col 0 most significant: shift_c = Σ widths of later columns.
-    shifts = jnp.cumsum(widths[::-1])[::-1] - widths
-    total = widths.sum()
-
-    def packed(_):
-        word = ((keys + 3) << shifts[None, :]).sum(axis=1)
-        return jnp.argsort(word, stable=True)
-
-    return jax.lax.cond(total <= 31, packed, lambda _: _plain_lexsort(keys),
-                        operand=None)
-
-
-def _group_ids(left_keys: jnp.ndarray, right_keys: jnp.ndarray,
-               use_kernels: bool) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Dense-rank the union of two key matrices: rows get equal group ids iff
-    their keys are equal (across or within sides)."""
-    n_l = left_keys.shape[0]
-    comb = jnp.concatenate([left_keys, right_keys], axis=0)
-    perm = _lexsort_rows(comb)
-    if use_kernels:
-        seg, _ = kops.segment_scan(comb[perm])
-    else:
-        seg, _ = segment_scan_ref(comb[perm])
-    g = jnp.zeros((comb.shape[0],), jnp.int32).at[perm].set(seg)
-    return g[:n_l], g[n_l:]
-
-
-def _probe_sort(lk: jnp.ndarray, l_valid: jnp.ndarray, rk: jnp.ndarray,
-                r_valid: jnp.ndarray, use_kernels: bool
-                ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """(counts, lo, perm) via the sort-merge formulation — the mid-fidelity
-    oracle of the hash path (and the PR-1 reduce phase, preserved bit for
-    bit): dense-rank the union of both sides' keys (lexsort + segment_scan,
-    with distinct per-side sentinels so invalid rows never match), stable-sort
-    the right side by group id, and read per-group run lengths through ONE
-    searchsorted lookup.  Stability is load-bearing: `perm` enumerates each
-    group in right-ARRIVAL order, never rely on the default sort."""
-    n_r = rk.shape[0]
-    lks = jnp.where(l_valid[:, None], lk, jnp.int32(-2))
-    rks = jnp.where(r_valid[:, None], rk, jnp.int32(-3))
-    g_l, g_r = _group_ids(lks, rks, use_kernels)
-    order_r = jnp.argsort(g_r, stable=True)
-    sg_r = g_r[order_r]
-    if use_kernels:
-        _, _, rlen = kops.run_lengths(sg_r[:, None])
-    else:
-        _, _, rlen = run_lengths_ref(sg_r[:, None])
-    lo = jnp.searchsorted(sg_r, g_l)               # group start in sorted right
-    safe_lo = jnp.minimum(lo, n_r - 1)
-    hit = (lo < n_r) & (sg_r[safe_lo] == g_l)
-    counts = jnp.where(hit, rlen[safe_lo], 0)      # per-left-row match count
-    return counts, lo, order_r
-
-
-def _probe_hash(lk: jnp.ndarray, l_valid: jnp.ndarray, rk: jnp.ndarray,
-                r_valid: jnp.ndarray, use_kernels: bool,
-                hash_bits: int | None
-                ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """(counts, lo, perm) via the `join_probe` radix hash join: fused key
-    hash of both sides, carried-histogram compact table build over the right
-    side, key-verified chained probe — no (n_l + n_r, w) union buffer and no
-    multi-column lexsort.  `use_kernels=False` composes the kernels/ref.py
-    oracles through the same chained resolution — their one-hot rank is
-    O(n_r · 2^bits), so the default ref table is capped at 2^10 buckets:
-    collision chains deepen but stay exact (debug/test fidelity, never a
-    hot path)."""
-    if use_kernels:
-        return kops.join_probe(lk, l_valid, rk, r_valid, hash_bits)
-    bits = hash_bits or min(default_bits(rk.shape[0]), 10)
-    bl = join_hash_ref(lk, l_valid, bits)
-    br, rank, hist = build_table_ref(rk, r_valid, bits)
-    return probe_tables(lk, bl, rk, br, rank, hist, bits)
-
-
-def _local_join(frags: dict[str, jnp.ndarray], query: JoinQuery, cap_out: int,
-                use_kernels: bool, hash_reduce: bool = False,
-                hash_bits: int | None = None
+def _local_join(frags: dict[str, jnp.ndarray], query: JoinQuery, cap_out: int
                 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Cascade natural join of one cell's fragments.
 
@@ -556,15 +328,13 @@ def _local_join(frags: dict[str, jnp.ndarray], query: JoinQuery, cap_out: int,
       1. a probe pass over the shared key columns (incl. `__cell__`) yields
          per-left-row match counts, group-start offsets, and a grouped
          right-side permutation whose groups are contiguous and internally in
-         ARRIVAL order — `_probe_hash` (the `join_probe` radix hash-join
-         kernels, default) or `_probe_sort` (the retained sort-merge oracle);
+         ARRIVAL order — `kops.join_probe`, the radix hash join;
       2. expand to the static `cap_out` shape from the exclusive prefix sum
-         of per-left-row counts — `kernels.scatter_pack.expand_rows` (the
-         gather-free one-hot-contraction kernel / its host twin; the ref
-         oracle on use_kernels=False), output order (left row, right arrival
-         order), bit-identical across BOTH probes and the dense-matrix
-         ground oracle.  Output columns are carved out of the expanded
-         (left ++ right) rows with STATIC slices — no column gather.
+         of per-left-row counts — `kops.expand_rows`, output order (left
+         row, right arrival order), bit-identical to the dense-matrix ground
+         oracle `_local_join_dense`.  Output columns are carved out of the
+         expanded (left ++ right) rows with STATIC slices — no column
+         gather.
 
     Returns (rows (cap_out, n_attrs), valid (cap_out,), overflow ())."""
     rels = list(query.relations)
@@ -580,20 +350,11 @@ def _local_join(frags: dict[str, jnp.ndarray], query: JoinQuery, cap_out: int,
                   for a in right_attrs if a in acc_attrs]   # incl. __cell__
         lk = acc[:, jnp.asarray([l for l, _ in shared])]
         rk = right[:, jnp.asarray([r for _, r in shared])]
-        if hash_reduce:
-            counts, lo, perm = _probe_hash(lk, acc_valid, rk, r_valid,
-                                           use_kernels, hash_bits)
-        else:
-            counts, lo, perm = _probe_sort(lk, acc_valid, rk, r_valid,
-                                           use_kernels)
+        counts, lo, perm = kops.join_probe(lk, acc_valid, rk, r_valid)
         n_match = counts.sum()
         overflow = overflow + jnp.maximum(0, n_match - cap_out)
-        if use_kernels:
-            exp, valid_out = kops.expand_rows(acc, right, counts, lo, perm,
-                                              cap_out)
-        else:
-            exp, valid_out = expand_rows_ref(acc, right, counts, lo, perm,
-                                             cap_out)
+        exp, valid_out = kops.expand_rows(acc, right, counts, lo, perm,
+                                          cap_out)
         wa = acc.shape[1]
         extra_names = [a for a in rel.attrs if a not in acc_attrs]
         extra_cols = [right_attrs.index(a) for a in extra_names]
@@ -614,7 +375,7 @@ def _local_join_dense(frags: dict[str, jnp.ndarray], query: JoinQuery,
                       cap_out: int
                       ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """O(n_l·n_r) match-matrix cascade — the superseded reduce phase, kept as
-    the exactness oracle and the `reduce_scaling` benchmark baseline.
+    the exactness oracle of the tests.
 
     Output (rows, valid, overflow) is bit-identical to `_local_join`."""
     rels = list(query.relations)
@@ -673,9 +434,8 @@ class ShardedJoinExecutor:
         self.n_devices = n_dev
         self.placement = placement            # None -> per-session default
         self.placement_strategy = placement_strategy
-        self.routes = _build_routes(plan)
         self.route_specs = {name: _route_specs(rs)
-                            for name, rs in self.routes.items()}
+                            for name, rs in _build_routes(plan).items()}
         self._step_cache: dict[tuple, object] = {}
         self._count_fn = None
         self.compile_count = 0          # step builds (one per distinct key)
@@ -704,34 +464,25 @@ class ShardedJoinExecutor:
     def _count_pass(self):
         """Jitted routing/histogram pass shared by every session.
 
-        One call routes ALL relations on device — the `map_pack` megakernel
-        in scatter-free COUNTING mode (so placement, capacities, and the
-        step all see identical destinations) — and returns each relation's
+        One call routes ALL relations on device — `kops.map_count`, the
+        map's routing in scatter-free COUNTING mode (so placement,
+        capacities, and the step all see identical destinations) — and
+        returns each relation's
         (n_devices, k) count matrix of routed copies per (source device,
         wrapped LOGICAL cell).  The session folds these tiny matrices on
         host: column-sums are the per-cell loads LPT placement bin-packs,
         and folding columns through a placement table yields the
         per-(source, destination device) counts that set shuffle capacities.
-        This is the ONLY routing of the data prepare() performs: the staged
-        `_route_relation` histogram it replaces materialized the full
-        (n·F, w+1) tagged expansion just to throw it away (kept below as the
-        fuse_map=False oracle).  Each device counts its own shard, which is
-        one source row of the matrix: a Pallas kernel cannot be partitioned
-        by XLA, so the pass runs under `shard_map` like the step."""
+        This is the ONLY routing of the data prepare() performs.  Each
+        device counts its own shard, which is one source row of the matrix:
+        a Pallas kernel cannot be partitioned by XLA, so the pass runs under
+        `shard_map` like the step."""
         if self._count_fn is None:
-            k, cfg, query = self.plan.k, self.config, self.plan.query
-            routes, specs = self.routes, self.route_specs
+            k, query, specs = self.plan.k, self.plan.query, self.route_specs
 
             def count_matrices(*arrs):
-                outs = []
-                for rel, a in zip(query.relations, arrs):
-                    if cfg.use_kernels and cfg.fuse_map:
-                        outs.append(kops.map_count(a, specs[rel.name], k, 1))
-                        continue
-                    dest, _ = _route_relation(a, routes[rel.name],
-                                              cfg.use_kernels)
-                    outs.append(_count_matrix(dest, a.shape[0], k, 1))
-                return tuple(outs)
+                return tuple(kops.map_count(a, specs[rel.name], k, 1)
+                             for rel, a in zip(query.relations, arrs))
 
             n_rel = len(query.relations)
             self._count_fn = jax.jit(jax.shard_map(
@@ -759,8 +510,6 @@ class ShardedJoinExecutor:
             self._step_cache[key] = f     # re-insert: LRU, not FIFO, eviction
             self.step_hits += 1
             return f
-        routes = self.routes
-
         specs, k = self.route_specs, self.plan.k
 
         C = max(int(cfg.overlap_shuffle), 1)
@@ -776,18 +525,9 @@ class ShardedJoinExecutor:
 
             def pack_one(rows_in, rel_name):
                 with jax.named_scope("phase_map"):
-                    if cfg.use_kernels and cfg.fuse_map:
-                        # Megakernel: route -> fold -> scatter assemble, one
-                        # streaming pass writing the send buffer directly.
-                        return kops.scatter_pack(rows_in, specs[rel_name],
-                                                 ptable, k, n_dev,
-                                                 caps[rel_name])
-                    # Staged oracle path (and the pure-jnp ref path).
-                    dest, rows = _route_relation(rows_in, routes[rel_name],
-                                                 cfg.use_kernels)
-                    phys = _fold_dests(dest, ptable, cfg.use_kernels)
-                    return _pack_buckets(phys, rows, n_dev, caps[rel_name],
-                                         cfg.use_kernels)
+                    # Route -> fold -> sort assemble: the send buffer.
+                    return kops.scatter_pack(rows_in, specs[rel_name], ptable,
+                                             k, n_dev, caps[rel_name])
 
             def exchange(buf):
                 with jax.named_scope("phase_exchange"):
@@ -837,10 +577,7 @@ class ShardedJoinExecutor:
             # coordinates CapacityOverflowError and targeted retry need.
             sh_over = jnp.stack(overs)
             with jax.named_scope("phase_reduce"):
-                out, valid, j_over = _local_join(frags, query, cap_out,
-                                                 cfg.use_kernels,
-                                                 cfg.hash_reduce,
-                                                 cfg.hash_bits)
+                out, valid, j_over = _local_join(frags, query, cap_out)
             return (out[None], valid[None], sh_over[None], j_over[None],
                     recv_count[None])
 

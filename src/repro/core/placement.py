@@ -5,7 +5,7 @@ The Shares plan allocates ``k`` LOGICAL reducer cells sized to the data
 hardware provides ``n_devices`` physical devices — usually far fewer.  This
 module is the layer between them: a `CellPlacement` is a static table
 ``table[logical_cell] = device`` that the executor composes with hypercube
-routing (`route_cells` then a `fold_cells` lookup), so any power-of-two
+routing (the map looks each wrapped cell up in it), so any power-of-two
 k >= n_devices executes on any mesh.
 
 Beame–Koutris–Suciu state their load guarantees for p servers each receiving
@@ -20,7 +20,7 @@ meets real hardware.  Two strategies:
           routing histogram): place cells in decreasing load order, each onto
           the currently least-loaded device.  Classic 4/3-OPT makespan bound;
           on zipf-skewed workloads it restores the balance the modulo wrap
-          destroys (see the `fold_scaling` benchmark / BENCH_fold.json).
+          destroys (tests/test_fold.py compares the two).
 
 Correctness never depends on the placement: every routed tuple carries its
 logical cell id and the executor's local join matches only within equal

@@ -1,12 +1,12 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp oracles for the kernels and the step's phases in this package.
 
-Each `<name>_ref` is the semantic ground truth the kernels are tested against
-(interpret mode on CPU, compiled on TPU).  Keep these dead simple — no
-blocking, no tricks.
+Each `<name>_ref` is the semantic ground truth the tests hold an
+implementation to: the Pallas kernels (interpret mode on CPU, compiled on
+TPU) and the vectorized-XLA map, probe and expansion.  Keep these dead
+simple — no blocking, no tricks.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 # Knuth's multiplicative constant — must match core.hypercube._MULT.
@@ -31,45 +31,6 @@ def hash_partition_ref(keys: jnp.ndarray, seed: int, nbuckets: int
     return ids, hist
 
 
-def match_counts_ref(probe: jnp.ndarray, build: jnp.ndarray) -> jnp.ndarray:
-    """counts[i] = |{j : probe[i] == build[j]}|  (int32 (n_probe,))."""
-    return (probe[:, None] == build[None, :]).sum(axis=1).astype(jnp.int32)
-
-
-def first_match_ref(probe: jnp.ndarray, build: jnp.ndarray) -> jnp.ndarray:
-    """Index of the first matching build row per probe, or -1 (int32)."""
-    eq = probe[:, None] == build[None, :]
-    idx = jnp.where(eq, jnp.arange(build.shape[0], dtype=jnp.int32)[None, :],
-                    jnp.int32(2**31 - 1))
-    m = idx.min(axis=1)
-    return jnp.where(m == 2**31 - 1, jnp.int32(-1), m)
-
-
-def segment_scan_ref(keys: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(seg_ids, run_start) over lexicographically sorted keys (n, w).
-
-    seg_ids densely ranks equal-key runs; run_start[i] is the index of the
-    first row of the run containing row i.
-    """
-    n = keys.shape[0]
-    if n == 0:
-        return jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.int32)
-    idx = jnp.arange(n, dtype=jnp.int32)
-    neq = jnp.any(keys[1:] != keys[:-1], axis=1)
-    flags = jnp.concatenate([jnp.ones((1,), bool), neq])
-    seg = jnp.cumsum(flags.astype(jnp.int32)) - 1
-    start = jax.lax.cummax(jnp.where(flags, idx, jnp.int32(-1)))
-    return seg, start
-
-
-def run_lengths_ref(keys: jnp.ndarray
-                    ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """(seg_ids, run_start, run_length) over sorted keys (n, w)."""
-    seg, start = segment_scan_ref(keys)
-    counts = jnp.zeros((keys.shape[0],), jnp.int32).at[seg].add(1)
-    return seg, start, counts[seg]
-
-
 def segment_histogram_ref(values: jnp.ndarray, n_bins: int) -> jnp.ndarray:
     """Frequency histogram of int values in [0, n_bins) (int32 (n_bins,)).
 
@@ -86,8 +47,8 @@ def bucket_rank_ref(dest: jnp.ndarray, k: int
     """(rank, hist): stable within-bucket rank + histogram, one-hot cumsum.
 
     rank[i] = |{j < i : dest[j] == dest[i]}| for dest in [0, k); values
-    outside the range rank within a sentinel bucket.  Ground truth for the
-    `bucket_pack` radix kernel — O(m·k), dead simple on purpose.
+    outside the range rank within a sentinel bucket.  O(m·k), dead simple
+    on purpose.
     """
     m = dest.shape[0]
     d = jnp.where((dest >= 0) & (dest < k), dest.astype(jnp.int32),
@@ -104,7 +65,7 @@ def bucket_pack_ref(dest: jnp.ndarray, rows: jnp.ndarray, k: int, cap: int
                     ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Stable counting-sort pack into (k, cap, w) + overflow count.
 
-    Semantic oracle for `bucket_pack`: row i lands at buf[dest[i], rank[i]];
+    The map's assemble, as its contract: row i lands at buf[dest[i], rank[i]];
     invalid destinations and ranks beyond cap are dropped; overflow counts
     the dropped valid rows.
     """
@@ -121,7 +82,7 @@ def bucket_pack_ref(dest: jnp.ndarray, rows: jnp.ndarray, k: int, cap: int
 def route_cells_ref(rows: jnp.ndarray,
                     recipe: tuple[tuple[int, int, int, int], ...]
                     ) -> jnp.ndarray:
-    """Fused hypercube routing oracle: Σ_i h_i(row[col_i]) · stride_i."""
+    """Hypercube routing oracle: Σ_i h_i(row[col_i]) · stride_i."""
     cell = jnp.zeros((rows.shape[0],), jnp.int32)
     for col, seed, share, stride in recipe:
         if share == 1:
@@ -157,13 +118,13 @@ def _map_route_ref(rows: jnp.ndarray, routes, k: int
     return (jnp.stack(logical_cols, axis=1), jnp.stack(wrapped_cols, axis=1))
 
 
-def map_pack_ref(rows: jnp.ndarray, ptable: jnp.ndarray, routes, k: int,
+def scatter_pack_ref(rows: jnp.ndarray, ptable: jnp.ndarray, routes, k: int,
                  n_dev: int, cap: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Map-phase oracle: the staged route -> fold -> pack composition.
 
-    Deliberately materializes the (n·F, w+1) tagged expansion the `map_pack`
-    megakernel exists to avoid — ground truth, not a hot path.  Returns
-    ((n_dev, cap, w+1) buffer, overflow), bit-identical to the kernel.
+    Deliberately materializes the (n·F, w+1) tagged expansion that
+    `scatter_pack_host` never builds — ground truth, not a hot path.
+    Returns ((n_dev, cap, w+1) buffer, overflow), bit-identical to it.
     """
     n, w = rows.shape
     if n == 0 or not routes:
@@ -198,25 +159,13 @@ def map_count_ref(rows: jnp.ndarray, routes, k: int, n_src: int
     return counts[:n_src * k].reshape(n_src, k)
 
 
-def scatter_pack_ref(rows: jnp.ndarray, ptable: jnp.ndarray, routes, k: int,
-                     n_dev: int, cap: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Scatter-assemble oracle — semantically the staged map-phase
-    composition of `map_pack_ref` (the in-kernel scatter changes HOW the
-    buffer is written, never WHAT it holds), kept as its own name so the
-    `scatter_pack` kernels test against an explicit ground truth."""
-    return map_pack_ref(rows, ptable, routes, k, n_dev, cap)
-
-
 def expand_rows_ref(left: jnp.ndarray, right: jnp.ndarray,
                     counts: jnp.ndarray, lo: jnp.ndarray, perm: jnp.ndarray,
                     cap: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Prefix-sum expansion oracle: slot t of the (cap, wl + wr) output is
     ``left[li] ++ right[perm[lo[li] + t - off[li]]]`` where li is the row
     whose [off, off + counts) window covers t.  searchsorted + plain jnp
-    gathers — oracles may gather (and must stay linear: `_local_join`'s
-    use_kernels=False arm runs this at million-row caps, where the kernel's
-    O(cap·n_l) dense compare-count would allocate terabytes); the
-    gather-free contract belongs to the kernel lowering."""
+    gathers, where `expand_rows_host` merges by sorts."""
     n_l = left.shape[0]
     n_r = right.shape[0]
     if n_l == 0 or n_r == 0:
@@ -237,7 +186,7 @@ def join_hash_ref(keys: jnp.ndarray, valid: jnp.ndarray, n_bits: int
     h = (Σ_c key_c · seed_c) · MULT over uint32, bucket = top n_bits bits;
     seed_c = (0x9E3779B1 + 2c·0x85EBCA77) | 1.  Invalid rows land in the
     sentinel bucket 2^n_bits.  The formula is a cross-side contract — the
-    kernel, host twin, and this oracle must agree bit for bit.
+    host functions and this oracle must agree bit for bit.
     """
     h = jnp.zeros((keys.shape[0],), jnp.uint32)
     for c in range(keys.shape[1]):
@@ -252,7 +201,7 @@ def build_table_ref(keys: jnp.ndarray, valid: jnp.ndarray, n_bits: int
                     ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """(bucket, stable within-bucket rank, histogram) — one-hot cumsum.
 
-    Ground truth for the `build_table` kernel: O(n·P), dead simple.
+    Ground truth for `build_table_host`: O(n·P), dead simple.
     """
     d = join_hash_ref(keys, valid, n_bits)
     rank, hist = bucket_rank_ref(d, 1 << n_bits)
@@ -283,7 +232,7 @@ def fold_cells_ref(dest: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     dest int32 (m,) in [0, k) with -1 marking non-members (passed through);
     table int32 (k,) maps logical cell -> physical device.  This is the
     logical->physical fold of `core.placement.CellPlacement`, composed after
-    `route_cells` in the executor's map phase.
+    the routing in the map-phase oracle.
     """
     valid = dest >= 0
     safe = jnp.where(valid, dest, 0)

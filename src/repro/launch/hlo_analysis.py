@@ -186,8 +186,8 @@ def count_ops(text: str, op_name: str) -> int:
     `op_name` is the HLO opcode as printed (e.g. "gather", "scatter",
     "dynamic-slice", "all-to-all"); matching is exact on the parsed op kind,
     so "gather" never matches "all-gather".  This is the structural gate
-    scripts/check_hlo.py builds on: the scatter-assemble and expansion paths
-    must lower with count_ops(hlo, "gather") == 0."""
+    the lowering pins in tests/test_scatter_pack.py build on: the map's sort
+    assemble must lower with count_ops(hlo, "gather") == 0."""
     comps, _ = _split_computations(text)
     return sum(1 for comp in comps.values()
                for op in comp.ops.values() if op.kind == op_name)

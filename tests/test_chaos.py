@@ -172,8 +172,11 @@ def test_straggler_is_evicted_and_result_exact():
     while eng.evicted == [] and eng.session.stats["batches"] < 8:
         _exact(eng.run_batch(), q, data)
     assert eng.evicted == [slow]
+    assert eng.refold_compiles == 0             # caps stayed in their bucket
+    compiles_before = ex.compile_count
     res = eng.run_batch()
     _exact(res, q, data)
+    assert ex.compile_count == compiles_before  # traced table: warm step
     assert res["recv_counts"][slow] == 0
 
 

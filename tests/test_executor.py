@@ -77,19 +77,6 @@ def test_overflow_detection():
         ex.result_rows(data)
 
 
-def test_jnp_ref_path_matches_kernel_path():
-    q = two_way()
-    data = skewed_join_dataset(q, 300, 30, skew={"B": 1.5}, seed=7)
-    plan = plan_skew_join(q, data, 8)
-    rows_k = ShardedJoinExecutor(
-        plan, _mesh(), config=ExecutorConfig(out_capacity=8192, use_kernels=True)
-    ).result_rows(data)
-    rows_j = ShardedJoinExecutor(
-        plan, _mesh(), config=ExecutorConfig(out_capacity=8192, use_kernels=False)
-    ).result_rows(data)
-    np.testing.assert_array_equal(canonical(rows_k), canonical(rows_j))
-
-
 def test_shuffle_balance_metric():
     """Received-tuple counts per device are balanced under skew."""
     q = two_way()
@@ -133,17 +120,35 @@ def test_empty_relation():
     assert len(rows) == 0
 
 
+def _argsort_pack(dest, rows, k, cap):
+    """(buf, overflow): bucket contents in arrival order by a stable numpy
+    argsort, places past cap and dest < 0 dropped."""
+    big = np.where(dest < 0, k, dest)
+    order = np.argsort(big, kind="stable")
+    sd, sr = big[order], rows[order]
+    pos = np.arange(len(sd)) - np.searchsorted(sd, sd, side="left")
+    buf = np.full((k, cap, rows.shape[1]), -1, np.int32)
+    keep = (sd < k) & (pos < cap)
+    buf[sd[keep], pos[keep]] = sr[keep]
+    return buf, int(((pos >= cap) & (sd < k)).sum())
+
+
 def _assert_pack_equal(dest, rows, k, cap):
-    """Radix bucket_pack (kernel-backed and jnp-ref paths) vs argsort oracle."""
-    from repro.core.executor import _pack_buckets, _pack_buckets_argsort
+    """The map's sort assemble (`_scatter_assemble_host`, one copy per row,
+    the row's arrival index as its tag) vs the argsort pack."""
     import jax.numpy as jnp
-    d, r = jnp.asarray(dest, jnp.int32), jnp.asarray(rows, jnp.int32)
-    buf_ref, over_ref = _pack_buckets_argsort(d, r, k, cap)
-    for use_kernels in (True, False):
-        buf, over = _pack_buckets(d, r, k, cap, use_kernels)
-        np.testing.assert_array_equal(np.asarray(buf), np.asarray(buf_ref))
-        assert int(over) == int(over_ref)
-    return np.asarray(buf_ref), int(over_ref)
+    from repro.kernels.scatter_pack import _scatter_assemble_host
+    dest = np.asarray(dest, np.int32)
+    rows = np.asarray(rows, np.int32)
+    arrival = np.arange(len(dest), dtype=np.int32)
+    buf_ref, over_ref = _argsort_pack(
+        dest, np.concatenate([rows, arrival[:, None]], axis=1), k, cap)
+    buf, over = _scatter_assemble_host(
+        jnp.asarray(rows), jnp.asarray(arrival),
+        jnp.asarray(np.where(dest < 0, k, dest), jnp.int32), k, cap, 1)
+    np.testing.assert_array_equal(np.asarray(buf), buf_ref)
+    assert int(over) == over_ref
+    return buf_ref[..., :-1], over_ref
 
 
 @pytest.mark.parametrize("k", [8, 64])          # spans the old pack's k=32
@@ -181,78 +186,91 @@ def test_pack_buckets_overflow():
     assert (buf[1] == rows[:cap]).all()         # first cap rows kept, in order
 
 
-@pytest.mark.parametrize("use_kernels", [False, True])
-def test_route_relation_matches_numpy_router(use_kernels):
-    """Fused one-pass `_route_relation` vs the plan's numpy routing oracle.
+@pytest.mark.parametrize("n_dev", [8, 2])       # identity, and folded
+def test_route_relation_matches_numpy_router(n_dev):
+    """The map (`scatter_pack_host`) vs the plan's numpy routing oracle.
 
     Compares the multiset of (phys dest, logical cell, row values) routed
-    copies — the fused path interleaves routes row-major while the oracle is
-    route-major, so order is not part of the contract.  Runs without a mesh.
+    copies in the shuffle buffer — the map sorts copies by device while the
+    oracle is route-major, so order is not part of the contract.  Runs
+    without a mesh.
     """
     import jax.numpy as jnp
-    from repro.core.executor import _build_routes, _route_relation
+    from repro.core.executor import _build_routes, _route_specs
+    from repro.core.placement import lpt_placement
+    from repro.kernels.scatter_pack import scatter_pack_host
     q = two_way()
     data = skewed_join_dataset(q, 400, 30, skew={"B": 1.6}, seed=12)
     plan = plan_skew_join(q, data, 8)
     routes = _build_routes(plan)
+    place = lpt_placement(np.asarray(plan.cell_loads(data), float), n_dev)
     for rel in q.relations:
         rows = np.asarray(data[rel.name], np.int32)
-        dest, tagged = _route_relation(jnp.asarray(rows), routes[rel.name],
-                                       use_kernels)
-        dest, tagged = np.asarray(dest), np.asarray(tagged)
-        valid = dest >= 0
-        # The hidden logical-cell tag must be consistent with the phys dest.
-        assert (tagged[valid][:, -1] % plan.k == dest[valid]).all()
-        got = np.concatenate([dest[valid, None], tagged[valid][:, :-1]], axis=1)
+        buf, over = scatter_pack_host(
+            jnp.asarray(rows), jnp.asarray(place.table),
+            routes=_route_specs(routes[rel.name]), k=plan.k, n_dev=n_dev,
+            cap=len(rows) * plan.k)
+        assert int(over) == 0
+        buf = np.asarray(buf)
+        dev = np.broadcast_to(np.arange(n_dev)[:, None], buf.shape[:2])
+        valid = buf[..., -1] >= 0
+        tagged, dev = buf[valid], dev[valid]
+        # (device, wrapped logical cell, row values) of every copy; the
+        # hidden logical-cell tag must agree with the device it reached.
+        cell = tagged[:, -1] % plan.k
+        assert (place.device_of(cell) == dev).all()
+        got = np.concatenate([dev[:, None], cell[:, None], tagged[:, :-1]],
+                             axis=1)
         ridx, odest = plan.route_relation(rel.name, rows)
-        expect = np.concatenate([odest[:, None], rows[ridx]], axis=1)
+        expect = np.concatenate([place.device_of(odest)[:, None],
+                                 odest[:, None], rows[ridx]], axis=1)
         np.testing.assert_array_equal(canonical(got), canonical(expect))
 
 
 def _assert_local_join_parity(frags, q, caps):
-    """Bit-parity of `_local_join` across the dense ground oracle, the
-    sort-merge mid-fidelity oracle, and the radix hash path — every
-    use_kernels combination, plus a forced-collision tiny hash table."""
+    """Bit-parity of `_local_join` with the dense ground oracle."""
     from repro.core.executor import _local_join, _local_join_dense
     for cap in caps:
         out_d, val_d, ov_d = _local_join_dense(frags, q, cap)
-        for use_kernels in (False, True):
-            for hash_reduce, bits in [(False, None), (True, None), (True, 1)]:
-                out, val, ov = _local_join(frags, q, cap, use_kernels,
-                                           hash_reduce, bits)
-                tag = f"cap={cap} kernels={use_kernels} " \
-                      f"hash={hash_reduce} bits={bits}"
-                np.testing.assert_array_equal(
-                    np.asarray(out), np.asarray(out_d), err_msg=tag)
-                np.testing.assert_array_equal(
-                    np.asarray(val), np.asarray(val_d), err_msg=tag)
-                assert int(ov) == int(ov_d), tag
+        out, val, ov = _local_join(frags, q, cap)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(out_d),
+                                      err_msg=f"cap={cap}")
+        np.testing.assert_array_equal(np.asarray(val), np.asarray(val_d),
+                                      err_msg=f"cap={cap}")
+        assert int(ov) == int(ov_d), f"cap={cap}"
 
 
-@pytest.mark.parametrize("use_kernels", [False, True])
-@pytest.mark.parametrize("hash_reduce", [False, True])
+def _query(shape):
+    """A query of each shape the reduce cascades over, in a cascade order in
+    which every relation joins the prefix."""
+    from repro.core import JoinQuery, Relation
+    if shape == "two_way":
+        return two_way()
+    if shape == "three_way":
+        return running_example()
+    if shape == "chain4":
+        return JoinQuery((Relation("R", ("A", "B")), Relation("S", ("B", "C")),
+                          Relation("T", ("C", "D")), Relation("U", ("D", "E"))))
+    return JoinQuery((Relation("F", ("A", "B", "C")), Relation("R", ("A", "X")),
+                      Relation("S", ("B", "Y")), Relation("T", ("C", "Z"))))
+
+
+@pytest.mark.parametrize("shape", ["two_way", "three_way", "chain4", "star"])
 @pytest.mark.parametrize("seed", [0, 1])
-def test_local_join_probes_match_dense(use_kernels, hash_reduce, seed):
-    """Both probe formulations are bit-identical to the dense-matrix oracle."""
+def test_local_join_probes_match_dense(shape, seed):
+    """The hash-join cascade is bit-identical to the dense-matrix oracle on
+    every query shape, at an overflowing and a slack capacity."""
     import jax.numpy as jnp
-    from repro.core import running_example
-    from repro.core.executor import _local_join, _local_join_dense
     rng = np.random.default_rng(seed)
-    q = running_example()
+    q = _query(shape)
     frags = {}
-    for rel, n in [("R", 60), ("S", 90), ("T", 40)]:
-        w = len(q.relation(rel).attrs)
+    for rel, n in zip(q.relations, (60, 90, 40, 50)):
+        w = len(rel.attrs)
         rows = rng.integers(0, 8, size=(n, w + 1)).astype(np.int32)
         rows[:, -1] = rng.integers(0, 3, size=n)          # logical cell ids
         rows[rng.random(n) < 0.25] = -1                   # invalid rows
-        frags[rel] = jnp.asarray(rows)
-    for cap in (16, 4096):                                # overflow + slack
-        out_s, val_s, ov_s = _local_join(frags, q, cap, use_kernels,
-                                         hash_reduce)
-        out_d, val_d, ov_d = _local_join_dense(frags, q, cap)
-        np.testing.assert_array_equal(np.asarray(out_s), np.asarray(out_d))
-        np.testing.assert_array_equal(np.asarray(val_s), np.asarray(val_d))
-        assert int(ov_s) == int(ov_d)
+        frags[rel.name] = jnp.asarray(rows)
+    _assert_local_join_parity(frags, q, caps=(16, 4096))  # overflow + slack
 
 
 def test_local_join_cap1_fragments():
@@ -268,8 +286,8 @@ def test_local_join_cap1_fragments():
 
 
 def test_local_join_all_invalid_right():
-    """An all-invalid right fragment must produce zero matches on every path
-    (the `safe_lo = minimum(lo, n_r - 1)` / `hit` masking edge)."""
+    """An all-invalid right fragment must produce zero matches (the probe's
+    absent-partition masking edge)."""
     import jax.numpy as jnp
     rng = np.random.default_rng(5)
     q = two_way()
@@ -279,14 +297,14 @@ def test_local_join_all_invalid_right():
     }
     _assert_local_join_parity(frags, q, caps=(8, 128))
     from repro.core.executor import _local_join
-    _, valid, over = _local_join(frags, q, 128, True, True)
+    _, valid, over = _local_join(frags, q, 128)
     assert int(np.asarray(valid).sum()) == 0 and int(over) == 0
 
 
 def test_local_join_all_invalid_accumulator_mid_cascade():
     """Disjoint R/S keys make step 1 emit zero rows; step 2 then joins an
-    ALL-INVALID accumulator against a live T fragment — every path must
-    agree bit for bit (and emit nothing)."""
+    ALL-INVALID accumulator against a live T fragment — the cascade must
+    agree with the dense oracle bit for bit (and emit nothing)."""
     import jax.numpy as jnp
     from repro.core import running_example
     rng = np.random.default_rng(6)
@@ -299,81 +317,44 @@ def test_local_join_all_invalid_accumulator_mid_cascade():
         frags[rel] = jnp.asarray(rows)
     _assert_local_join_parity(frags, q, caps=(4, 256))
     from repro.core.executor import _local_join
-    _, valid, over = _local_join(frags, q, 256, True, True)
+    _, valid, over = _local_join(frags, q, 256)
     assert int(np.asarray(valid).sum()) == 0 and int(over) == 0
 
 
-def test_lexsort_rows_packs_narrow_keys():
-    """`_lexsort_rows` single-word pack is bit-identical to the plain lexsort
-    on narrow keys, and falls back on width overflow (wide values)."""
-    import jax.numpy as jnp
-    from repro.core.executor import _lexsort_rows, _plain_lexsort
-    rng = np.random.default_rng(8)
-    for hi in (5, 1 << 10, 1 << 20, (1 << 30) + 7):       # last: overflow
-        keys = rng.integers(-3, hi, (257, 3)).astype(np.int32)
-        got = np.asarray(_lexsort_rows(jnp.asarray(keys)))
-        want = np.asarray(_plain_lexsort(jnp.asarray(keys)))
-        np.testing.assert_array_equal(got, want, err_msg=f"hi={hi}")
-    # Heavy duplication: stability of the packed sort is load-bearing.
-    keys = rng.integers(0, 2, (301, 4)).astype(np.int32)
-    np.testing.assert_array_equal(
-        np.asarray(_lexsort_rows(jnp.asarray(keys))),
-        np.asarray(_plain_lexsort(jnp.asarray(keys))))
+@pytest.mark.parametrize("n_cells", [1, 3])
+def test_stable_argsort_locks_arrival_order(n_cells):
+    """Regression lock for the stable sorts.
 
-
-def test_executor_hash_and_sort_configs_agree():
-    """End-to-end: hash_reduce True/False (and a forced-collision table)
-    produce identical result sets, both equal to the reference join."""
-    q = two_way()
-    data = skewed_join_dataset(q, 400, 40, skew={"B": 1.5}, seed=13)
-    plan = plan_skew_join(q, data, 8)
-    expect = reference_join(q, data)
-    for cfg in (ExecutorConfig(out_capacity=32768, hash_reduce=True),
-                ExecutorConfig(out_capacity=32768, hash_reduce=False),
-                ExecutorConfig(out_capacity=32768, hash_reduce=True,
-                               hash_bits=2)):
-        got = ShardedJoinExecutor(plan, _mesh(), config=cfg).result_rows(data)
-        np.testing.assert_array_equal(canonical(got), expect)
-
-
-@pytest.mark.parametrize("use_kernels", [False, True])
-def test_stable_argsort_locks_arrival_order(use_kernels):
-    """Regression lock for the explicit stable=True argsorts.
-
-    `_local_join` gathers right-side matches through `order_r`; with heavy
-    key duplication an unstable sort would permute equal-keyed rows and break
-    bit-identity with the dense oracle's (left row, right ARRIVAL order)
-    output.  Likewise `_pack_buckets_argsort` must keep bucket contents in
-    arrival order to stay the pack equivalence oracle.  Runs without a mesh.
+    `_local_join` enumerates right-side matches through the probe's grouped
+    permutation; with heavy key duplication an unstable sort would permute
+    equal-keyed rows and break bit-identity with the dense oracle's (left
+    row, right ARRIVAL order) output, within each logical cell.  Likewise
+    the map's sort assemble must keep each bucket's rows in arrival order.
+    Runs without a mesh.
     """
     import jax.numpy as jnp
-    from repro.core.executor import (_local_join, _local_join_dense,
-                                     _pack_buckets_argsort)
-    from repro.kernels.ref import bucket_pack_ref
+    from repro.core.executor import _local_join, _local_join_dense
     rng = np.random.default_rng(99)
     q = two_way()
     n = 120
     frags = {}
     for rel in ("R", "S"):
         rows = rng.integers(0, 3, size=(n, 3)).astype(np.int32)  # ~40 dups/key
-        rows[:, -1] = 0                                   # one logical cell
+        rows[:, -1] = rng.integers(0, n_cells, size=n)    # logical cell ids
         frags[rel] = jnp.asarray(rows)
-    out_s, val_s, ov_s = _local_join(frags, q, 1 << 14, use_kernels)
+    out_s, val_s, ov_s = _local_join(frags, q, 1 << 14)
     out_d, val_d, ov_d = _local_join_dense(frags, q, 1 << 14)
     np.testing.assert_array_equal(np.asarray(out_s), np.asarray(out_d))
     np.testing.assert_array_equal(np.asarray(val_s), np.asarray(val_d))
     assert int(ov_s) == int(ov_d)
-    # Argsort pack: rows of one bucket must land in arrival order.
+    # Sort assemble: rows of one bucket must land in arrival order.
     k, cap = 4, 64
-    dest = jnp.asarray(rng.integers(0, k, size=n), jnp.int32)
-    rows = jnp.asarray(np.arange(n * 2, dtype=np.int32).reshape(n, 2))
-    buf_a, _ = _pack_buckets_argsort(dest, rows, k, cap)
-    buf_r, _ = bucket_pack_ref(dest, rows, k, cap)
-    np.testing.assert_array_equal(np.asarray(buf_a), np.asarray(buf_r))
-    d = np.asarray(dest)
+    dest = rng.integers(0, k, size=n)
+    rows = np.arange(n * 2, dtype=np.int32).reshape(n, 2)
+    buf, _ = _assert_pack_equal(dest, rows, k, cap)
     for b in range(k):                                    # explicit order lock
-        want = np.asarray(rows)[d == b][:cap]
-        np.testing.assert_array_equal(np.asarray(buf_a)[b][:len(want)], want)
+        want = rows[dest == b][:cap]
+        np.testing.assert_array_equal(buf[b][:len(want)], want)
 
 
 @pytest.mark.parametrize("C", [1, 2, 4, 7])     # 7: ragged remainder tiles
@@ -395,20 +376,6 @@ def test_overlap_shuffle_matches_serial_and_reference(C):
     got = canonical(ex.result_rows(data))
     np.testing.assert_array_equal(got, got_serial)
     assert ex.compile_count == 1
-
-
-def test_overlap_shuffle_staged_and_ref_paths():
-    """Chunking composes with the staged oracle and the pure-jnp ref path."""
-    q = two_way()
-    data = skewed_join_dataset(q, 300, 30, skew={"B": 1.5}, seed=22)
-    plan = plan_skew_join(q, data, 8)
-    expect = reference_join(q, data)
-    for use_kernels, fuse_map in ((True, False), (False, False)):
-        ex = ShardedJoinExecutor(
-            plan, _mesh(),
-            config=ExecutorConfig(out_capacity=32768, overlap_shuffle=3,
-                                  use_kernels=use_kernels, fuse_map=fuse_map))
-        np.testing.assert_array_equal(canonical(ex.result_rows(data)), expect)
 
 
 def test_overlap_warm_batches_zero_new_compiles():

@@ -184,14 +184,29 @@ def test_mismatched_placement_raises():
         ex.session().prepare(data, placement=wrong)
 
 
-@pytest.mark.parametrize("use_kernels", [False, True])
-def test_fold_dests_matches_numpy(use_kernels):
-    """`_fold_dests` (Pallas fold_cells + ref twin) vs CellPlacement lookup."""
-    from repro.core.executor import _fold_dests
+@pytest.mark.parametrize("strategy", ["lpt", "modulo"])
+def test_fold_dests_matches_numpy(strategy):
+    """The map's placement fold vs `CellPlacement.device_of`: every copy in
+    `scatter_pack_host`'s buffer sits on the device its wrapped logical
+    cell folds to, and each device holds exactly its cells' copies."""
+    from repro.core.placement import place_cells
+    from repro.kernels.map_pack import route_fanout
+    from repro.kernels.scatter_pack import scatter_pack_host
     rng = np.random.default_rng(41)
     k = 64
-    p = lpt_placement(rng.uniform(0, 100, k), N_DEV)
-    dest = rng.integers(-1, k, size=2048).astype(np.int32)
-    got = np.asarray(_fold_dests(jnp.asarray(dest),
-                                 jnp.asarray(p.table), use_kernels))
-    np.testing.assert_array_equal(got, p.device_of(dest))
+    p = place_cells(rng.uniform(0, 100, k), k, N_DEV, strategy)
+    # One route over k cells, fanout 2: col 0 hashed over k/2 cells, each
+    # row replicated to both halves.
+    spec = ((((0, 0x9E3779B1, k // 2, 1),), (0, k // 2), 0, (), ()),)
+    rows = rng.integers(0, 1000, size=(1024, 2)).astype(np.int32)
+    rows[rng.random(1024) < 0.1] = -1                     # padding rows
+    m = len(rows) * route_fanout(spec)
+    buf, over = scatter_pack_host(jnp.asarray(rows), jnp.asarray(p.table),
+                                  routes=spec, k=k, n_dev=N_DEV, cap=m)
+    assert int(over) == 0
+    buf = np.asarray(buf)
+    valid = buf[..., -1] >= 0
+    dev = np.broadcast_to(np.arange(N_DEV)[:, None], valid.shape)[valid]
+    cells = buf[valid][:, -1] % k
+    np.testing.assert_array_equal(dev, p.device_of(cells))
+    assert valid.sum() == 2 * (rows[:, 0] >= 0).sum()

@@ -72,6 +72,26 @@ def test_multi_tenant_interleaved_exact_with_split_stats():
     assert eng.cache.stats["executors"] == 3
 
 
+def test_multi_tenant_steady_replay_is_warm():
+    """After two cycles of the three-tenant mix, a replay with fresh data
+    at the same sizes compiles nothing and hits the session cache at a rate
+    of at least 0.9; every request stays exact."""
+    eng = _engine()
+    for t, q, d in mixed_workload(12, seed=0):
+        eng.submit(t, q, d)
+    eng.run()
+    c0, h0, m0 = eng.cache.compile_count(), eng.cache.hits, eng.cache.misses
+    reqs = [(eng.submit(t, q, d), q, d)
+            for t, q, d in mixed_workload(6, seed=1)]
+    eng.run()
+    for req, q, d in reqs:
+        _check_exact(req, q, d)
+    assert len({str(q) for _, q, _ in reqs}) == 3    # distinct structures
+    assert eng.cache.compile_count() == c0           # zero recompiles
+    hits, misses = eng.cache.hits - h0, eng.cache.misses - m0
+    assert hits / max(hits + misses, 1) >= 0.9
+
+
 def test_shape_bucketing_shares_executables():
     """Requests whose row counts land in one geometric bucket share a
     prepared session (cache hit); a count past the bucket edge is a miss."""

@@ -1,9 +1,10 @@
-"""join_probe radix hash join vs its oracles — every path, forced collisions.
+"""join_probe radix hash join vs its oracles, forced collisions included.
 
-The reduce-phase hash join has three implementations that must agree: the
-Pallas kernels (interpret mode here, compiled on TPU), their vectorized-XLA
-host twins (the non-TPU hot path, including the packed-word fused build),
-and the dead-simple oracles in kernels/ref.py.  The semantic contract is the
+The reduce's probe `join_probe_host` (30-bit partitions from one sort) must
+agree with the chained probe over the dead-simple bucket-table oracles in
+kernels/ref.py (`probe_tables` on `join_hash_ref` / `build_table_ref`), and
+`join_hash_host` / `build_table_host` with those oracles.  The semantic
+contract is the
 expanded match list — per left row, its matching right rows in ARRIVAL order
 (`join_probe_ref`) — reproduced through the executor's prefix-sum expansion
 gather from (counts, lo, perm).  Coverage: tiny-hash-bits tables where every
@@ -44,12 +45,9 @@ def _all_paths(lk, lv, rk, rv, n_bits):
     lv, rv = jnp.asarray(lv), jnp.asarray(rv)
     bits = n_bits or jp.default_bits(rk.shape[0])
     return {
-        "kernel": jp.join_probe(lk, lv, rk, rv, n_bits=n_bits,
-                                interpret=True),
         "host": jp.join_probe_host(lk, lv, rk, rv, n_bits=n_bits),
         "ref": jp.probe_tables(lk, join_hash_ref(lk, lv, bits), rk,
                                *build_table_ref(rk, rv, bits), bits),
-        "ops": kops.join_probe(lk, lv, rk, rv, n_bits),
     }
 
 
@@ -172,70 +170,23 @@ def test_sorted_locate_matches_searchsorted(case):
                                       err_msg=side)
 
 
-@pytest.mark.parametrize("n_bits", [1, 4, 8])
+@pytest.mark.parametrize("n_bits", [1, 2, 3, 4, 7, 8, 11])
 @pytest.mark.parametrize("m", [0, 1, 63, 257])          # ragged, off-block
 def test_join_hash_and_build_table_bit_identity(m, n_bits):
-    """The kernel legs themselves: bucket ids, stable within-bucket ranks,
-    and histograms bit-identical across kernel / host twin / ref."""
+    """The bucket-table legs themselves: bucket ids, stable within-bucket
+    ranks, and histograms bit-identical across host function and ref, at
+    table widths from 2 to 2^11 buckets."""
     rng = np.random.default_rng(m * 10 + n_bits)
     keys = jnp.asarray(rng.integers(0, 1 << 20, (m, 3)), jnp.int32)
     valid = jnp.asarray(rng.random(m) > 0.2)
     h_ref = np.asarray(join_hash_ref(keys, valid, n_bits))
-    for name, h in [
-            ("kernel", jp.join_hash(keys, valid, n_bits=n_bits,
-                                    interpret=True)),
-            ("host", jp.join_hash_host(keys, valid, n_bits=n_bits)),
-            ("ops", kops.join_hash(keys, valid, n_bits))]:
-        np.testing.assert_array_equal(np.asarray(h), h_ref,
-                                      err_msg=f"path={name}")
-    b_ref, r_ref, hist_ref = (np.asarray(x) for x in
-                              build_table_ref(keys, valid, n_bits))
-    for name, (b, r, hist) in [
-            ("kernel", jp.build_table(keys, valid, n_bits=n_bits,
-                                      interpret=True)),
-            ("host", jp.build_table_host(keys, valid, n_bits=n_bits)),
-            ("ops", kops.build_table(keys, valid, n_bits))]:
-        np.testing.assert_array_equal(np.asarray(b), b_ref,
-                                      err_msg=f"path={name}")
-        np.testing.assert_array_equal(np.asarray(r), r_ref,
-                                      err_msg=f"path={name}")
-        np.testing.assert_array_equal(np.asarray(hist), hist_ref,
-                                      err_msg=f"path={name}")
-
-
-@pytest.mark.parametrize("n_bits", [2, 3, 7, 11])
-@pytest.mark.parametrize("m", [0, 1, 257])
-def test_build_table_multi_pass_bit_identical(m, n_bits):
-    """The factored (recursion-on-high-bits) build must be BIT-identical to
-    the single-pass one-hot build at every width — forced both ways, below
-    and above the SINGLE_PASS_BITS dispatch point."""
-    rng = np.random.default_rng(m + n_bits)
-    keys = jnp.asarray(rng.integers(0, 1 << 20, (m, 2)), jnp.int32)
-    valid = jnp.asarray(rng.random(m) > 0.2)
-    single = jp.build_table(keys, valid, n_bits=n_bits, multi_pass=False,
-                            interpret=True)
-    multi = jp.build_table(keys, valid, n_bits=n_bits, multi_pass=True,
-                           interpret=True)
-    host = jp.build_table_host(keys, valid, n_bits=n_bits)
-    for s, g, h, tag in zip(single, multi, host, ("bucket", "rank", "hist")):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(s),
+    np.testing.assert_array_equal(
+        np.asarray(jp.join_hash_host(keys, valid, n_bits=n_bits)), h_ref)
+    want = build_table_ref(keys, valid, n_bits)
+    got = jp.build_table_host(keys, valid, n_bits=n_bits)
+    for g, w, tag in zip(got, want, ("bucket", "rank", "hist")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
                                       err_msg=f"{tag} n_bits={n_bits}")
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(h),
-                                      err_msg=f"{tag} n_bits={n_bits} host")
-
-
-def test_build_table_multi_pass_auto_dispatch_lifts_bucket_cap():
-    """n_bits > SINGLE_PASS_BITS auto-dispatches the factored kernel; the
-    probe built on it stays exact (the lifted ~2^14-bucket cap in action)."""
-    assert jp.SINGLE_PASS_BITS < 14
-    rng = np.random.default_rng(5)
-    n_l, n_r = 40, 120
-    bits = jp.SINGLE_PASS_BITS + 2
-    lk = rng.integers(0, 50, (n_l, 2))
-    rk = rng.integers(0, 50, (n_r, 2))
-    ones_l, ones_r = np.ones(n_l, bool), np.ones(n_r, bool)
-    matches = _assert_matches_ref(lk, ones_l, rk, ones_r, bits)
-    assert matches > 0
 
 
 def test_hash_partition_multi_pass_bit_identical():

@@ -1,19 +1,24 @@
-"""scatter_pack / expand_rows vs their gather-formulation oracles.
+"""scatter_pack_host / expand_rows_host vs their oracles, and their lowering.
 
-The scatter-assemble megakernel must be BIT-identical to `map_pack` (whose
-`_assemble_tagged` inverse-permutation gather it retires) on every path: the
-Pallas kernel (interpret mode here, compiled on TPU), the vectorized-XLA host
-twin, the kernels/ref.py oracle, and the `kernels.ops` dispatcher.  Coverage
-mirrors test_map_pack.py: k in {1, 8, 256} with the placement fold engaged,
-multi-residual recipes with replication fanout > 1, m = 0, all-invalid rows,
-capacity-overflow parity, and tile-boundary rank carry.
+The map, `scatter_pack_host` (route, placement fold, one stable sort), must
+be BIT-identical to the staged oracle `scatter_pack_ref` in kernels/ref.py
+(route, fold, one-hot rank, scatter) and through the `kernels.ops` entry
+point.  Coverage: k from 1 to 256 with the placement fold engaged and k ==
+n_devices, multi-residual recipes with replication fanout > 1, eq / not-in
+constraints, m = 0, all-invalid rows, capacity overflow, every cell folded
+onto one device, and recipes from a real plan.
 
-`expand_rows` must be POSITIONALLY identical to the searchsorted + gather
-expansion it replaces (`expand_rows_ref` keeps that formulation; the host
-twin finds each slot's left row by a merge sort instead, and is checked
-against `np.searchsorted` and the ref) on real probe outputs and on
-degenerate shapes: ragged caps that end mid-group, overflow truncation,
-zero-size sides, zero total matches, and zero-count rows at either end.
+`expand_rows_host` must be POSITIONALLY identical to the searchsorted +
+gather expansion of `expand_rows_ref` (the host function finds each slot's
+left row by a merge sort instead, and is checked against `np.searchsorted`
+and the ref) on real probe outputs and on degenerate shapes: ragged caps
+that end mid-group, overflow truncation, zero-size sides, zero total
+matches, and zero-count rows at either end.
+
+The lowering pins count HLO ops (`launch.hlo_analysis.count_ops`, fusion
+bodies included) in what the chip runs: no gather in the sort assemble, no
+scatter in the map, no `while` in the expansion.  Controls written here
+show the counter sees each of those ops.
 """
 import functools
 
@@ -23,7 +28,7 @@ import numpy as np
 import pytest
 
 from hypothesis import given, settings, strategies as st
-from repro.core.executor import _Route, _probe_sort, _route_specs
+from repro.core.executor import _Route, _build_routes, _route_specs
 from repro.core.placement import lpt_placement, modulo_placement
 from repro.kernels import join_probe as jp
 from repro.kernels import map_pack as mp
@@ -39,13 +44,13 @@ def _routes_for(k: int) -> list[_Route]:
     """Synthetic multi-residual recipe (same shape as test_map_pack's):
     hashed attrs, fanout > 1 via replication, eq / not-in constraints."""
     if k == 1:
-        return [_Route("T", ((0, SEED_A, 1, 1),), (0,), 0, k, (), ())]
+        return [_Route("T", ((0, SEED_A, 1, 1),), (0,), 0, (), ())]
     half, quarter = max(k // 2, 1), max(k // 4, 1)
     return [
-        _Route("T", ((0, SEED_A, half, 1),), (0, half), 0, k, (),
+        _Route("T", ((0, SEED_A, half, 1),), (0, half), 0, (),
                ((1, (7, 13)),)),
         _Route("T", ((0, SEED_B, quarter, 1), (2, SEED_A, 2, quarter)),
-               (0,), quarter, k, ((1, 7),), ()),
+               (0,), quarter, ((1, 7),), ()),
     ]
 
 
@@ -55,20 +60,17 @@ def _rand_rows(rng, m, w=3, domain=50, invalid_frac=0.1):
     return rows
 
 
-def _assert_matches_map_pack(rows, routes, ptable, k, n_dev, cap):
-    """Every scatter_pack path vs the map_pack gather oracle, bit for bit."""
+def _assert_matches_ref(rows, routes, ptable, k, n_dev, cap):
+    """The map and its `ops` entry point vs the staged oracle, bit for
+    bit."""
     rows = jnp.asarray(rows, jnp.int32)
     spec = _route_specs(routes)
     pt = jnp.asarray(ptable)
-    buf_o, over_o = mp.map_pack_host(rows, pt, routes=spec, k=k, n_dev=n_dev,
-                                     cap=cap)
+    buf_o, over_o = scatter_pack_ref(rows, pt, spec, k, n_dev, cap)
     buf_o, over_o = np.asarray(buf_o), int(over_o)
     paths = {
-        "kernel": sp.scatter_pack(rows, pt, routes=spec, k=k, n_dev=n_dev,
-                                  cap=cap, interpret=True),
         "host": sp.scatter_pack_host(rows, pt, routes=spec, k=k, n_dev=n_dev,
                                      cap=cap),
-        "ref": scatter_pack_ref(rows, pt, spec, k, n_dev, cap),
         "ops": kops.scatter_pack(rows, spec, pt, k, n_dev, cap),
     }
     for name, (buf, over) in paths.items():
@@ -78,9 +80,12 @@ def _assert_matches_map_pack(rows, routes, ptable, k, n_dev, cap):
     return buf_o, over_o
 
 
-@pytest.mark.parametrize("k,n_dev", [(1, 1), (8, 4), (256, 8)])
+FOLDS = [(1, 1), (4, 4), (8, 4), (32, 8), (64, 2), (256, 8)]   # (k, n_dev)
+
+
+@pytest.mark.parametrize("k,n_dev", FOLDS)
 @pytest.mark.parametrize("m", [0, 1, 63, 257])              # ragged, off-block
-def test_scatter_pack_matches_map_pack(k, n_dev, m):
+def test_scatter_pack_matches_ref(k, n_dev, m):
     rng = np.random.default_rng(m * 1000 + k)
     routes = _routes_for(k)
     ptable = lpt_placement(rng.uniform(0, 100, k), n_dev).table
@@ -88,13 +93,13 @@ def test_scatter_pack_matches_map_pack(k, n_dev, m):
     fanout = mp.route_fanout(_route_specs(routes))
     assert k == 1 or fanout > 1                             # replication live
     cap = max(4, (2 * m * fanout) // max(n_dev, 1))
-    _assert_matches_map_pack(rows, routes, ptable, k, n_dev, cap)
+    _assert_matches_ref(rows, routes, ptable, k, n_dev, cap)
 
 
-@pytest.mark.parametrize("k,n_dev", [(8, 4), (256, 8)])
+@pytest.mark.parametrize("k,n_dev", [(1, 1), (4, 4), (8, 4), (256, 8)])
 def test_scatter_pack_all_invalid(k, n_dev):
     routes = _routes_for(k)
-    buf, over = _assert_matches_map_pack(
+    buf, over = _assert_matches_ref(
         np.full((70, 3), -1, np.int32), routes,
         modulo_placement(k, n_dev).table, k, n_dev, 4)
     assert over == 0
@@ -112,6 +117,8 @@ def _cap_for(case: str, m: int, hist: np.ndarray) -> int:
 
 @pytest.mark.parametrize("k,n_dev,ptable,case", [
     pytest.param(8, 4, None, "2", id="8-4"),
+    pytest.param(32, 8, None, "2", id="32-8"),
+    pytest.param(64, 2, None, "2", id="64-2"),
     pytest.param(256, 8, None, "2", id="256-8"),
     pytest.param(1, 1, None, "m", id="1-1-all-valid-cap-m"),
     pytest.param(1, 1, None, "m-1", id="1-1-all-valid-cap-m-1"),
@@ -120,9 +127,9 @@ def _cap_for(case: str, m: int, hist: np.ndarray) -> int:
                  id="8-4-overflow-then-fit"),
 ])
 def test_scatter_pack_overflow_parity(k, n_dev, ptable, case):
-    """Caps at and past the copies' count: trash-row routing and the sort's
-    per-device slices must not disturb the buffer or the counts, also where
-    a device's slice starts past an overflowed bucket."""
+    """Caps at and past the copies' count: the sort's per-device slices must
+    not disturb the buffer or the counts, also where a device's slice starts
+    past an overflowed bucket."""
     rng = np.random.default_rng(k)
     routes = _routes_for(k)
     rows = _rand_rows(rng, 150, invalid_frac=0.0)
@@ -134,7 +141,7 @@ def test_scatter_pack_overflow_parity(k, n_dev, ptable, case):
                                _route_specs(routes), k, n_dev, m)
     hist = (np.asarray(full)[..., -1] >= 0).sum(axis=1)
     cap = _cap_for(case, m, hist)
-    buf, over = _assert_matches_map_pack(rows, routes, table, k, n_dev, cap)
+    buf, over = _assert_matches_ref(rows, routes, table, k, n_dev, cap)
     assert over == np.maximum(hist - cap, 0).sum()
     np.testing.assert_array_equal((buf[..., -1] >= 0).sum(axis=1),
                                   np.minimum(hist, cap))
@@ -146,75 +153,141 @@ def test_scatter_pack_overflow_parity(k, n_dev, ptable, case):
         assert hist[0] > cap and (hist[1:] <= cap).all() and hist[1] > 0
 
 
+def test_pack_adversarial_all_cells_one_device():
+    """Every cell folded to device 0: every copy streams into one bucket."""
+    k, n_dev = 32, 8
+    rng = np.random.default_rng(3)
+    routes = _routes_for(k)
+    table = np.zeros(k, np.int32)
+    rows = _rand_rows(rng, 120)
+    buf, _ = _assert_matches_ref(rows, routes, table, k, n_dev, 1024)
+    assert (buf[1:] == -1).all()                            # only device 0 fed
+
+
+def test_pack_real_plan_routes():
+    """Recipes from a real SkewShares plan (multi-residual, HH constraints)."""
+    from repro.core import plan_skew_join, two_way
+    from repro.data import skewed_join_dataset
+    k, n_dev = 64, 8
+    q = two_way()
+    data = skewed_join_dataset(q, 400, 40, skew={"B": 1.6}, seed=41)
+    plan = plan_skew_join(q, data, k)
+    assert len(plan.residuals) >= 2
+    routes = _build_routes(plan)
+    ptable = lpt_placement(np.asarray(plan.cell_loads(data), float),
+                           n_dev).table
+    for rel in ("R", "S"):
+        rows = np.concatenate(
+            [data[rel], np.full((9, 2), -1)]).astype(np.int32)
+        _assert_matches_ref(rows, routes[rel], ptable, k, n_dev, 2048)
+
+
+def _lowered(fn, *args, **static) -> str:
+    return jax.jit(functools.partial(fn, **static)).lower(
+        *args).compile().as_text()
+
+
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_scatter_pack_host_lowers_without_scatter(n_dev):
-    """The host twin assembles by one sort: a row scatter (or the rank
-    pass's scatter-add) coming back fails here.  Teeth: the counter sees
-    the scatter `map_pack_host` keeps for its inverse permutation."""
+    """The map assembles by one sort: a row scatter (or a rank pass's
+    scatter-add) coming back fails here.  Control: the counter sees the
+    scatter of an `.at[].set` on the same rows."""
     k = 8
     spec = _route_specs(_routes_for(k))
     rows = jnp.asarray(_rand_rows(np.random.default_rng(5), 64))
     pt = jnp.asarray(modulo_placement(k, n_dev).table)
-
-    def hlo(fn):
-        return jax.jit(functools.partial(
-            fn, routes=spec, k=k, n_dev=n_dev, cap=32)).lower(
-                rows, pt).compile().as_text()
-
-    text = hlo(sp.scatter_pack_host)
+    text = _lowered(sp.scatter_pack_host, rows, pt, routes=spec, k=k,
+                    n_dev=n_dev, cap=32)
     assert count_ops(text, "scatter") == 0
     assert count_ops(text, "sort") == 1
-    assert count_ops(hlo(mp.map_pack_host), "scatter") >= 1
+    idx = jnp.asarray(np.random.default_rng(6).permutation(64), jnp.int32)
+    control = _lowered(lambda r, i: jnp.zeros_like(r).at[i].set(r), rows, idx)
+    assert count_ops(control, "scatter") >= 1
 
 
-def test_scatter_pack_tile_boundary_carry():
-    """Shrinking block_copies forces multi-tile grids: the carried histogram
-    and the in-kernel stores must agree across tile boundaries."""
-    k, n_dev = 8, 4
-    rng = np.random.default_rng(8)
-    routes = _routes_for(k)
-    rows = jnp.asarray(_rand_rows(rng, 300))
-    spec = _route_specs(routes)
-    pt = jnp.asarray(modulo_placement(k, n_dev).table)
-    buf_o, over_o = mp.map_pack_host(rows, pt, routes=spec, k=k, n_dev=n_dev,
-                                     cap=512)
-    for bc in (8, 64, 1024):
-        buf, over = sp.scatter_pack(rows, pt, routes=spec, k=k, n_dev=n_dev,
-                                    cap=512, block_copies=bc, interpret=True)
-        np.testing.assert_array_equal(np.asarray(buf), np.asarray(buf_o),
-                                      err_msg=f"block_copies={bc}")
-        assert int(over) == int(over_o)
+def _pin_inputs():
+    rng = np.random.default_rng(17)
+    n, fanout, n_dev, cap = 64, 2, 4, 16
+    m = n * fanout
+    rows = jnp.asarray(rng.integers(0, 99, (n, 3)), jnp.int32)
+    tag = jnp.asarray(rng.integers(0, 32, (m,)), jnp.int32)
+    d = jnp.asarray(rng.integers(0, n_dev + 1, (m,)), jnp.int32)
+    n_l, n_r = 24, 16
+    left = jnp.asarray(rng.integers(0, 9, (n_l, 3)), jnp.int32)
+    right = jnp.asarray(rng.integers(0, 9, (n_r, 4)), jnp.int32)
+    counts = jnp.asarray(rng.integers(0, 3, (n_l,)), jnp.int32)
+    lo = jnp.asarray(rng.integers(0, n_r, (n_l,)), jnp.int32)
+    perm = jnp.asarray(rng.permutation(n_r), jnp.int32)
+    off = jnp.cumsum(counts) - counts
+    return {
+        # The sort assemble, as the map compiles it.
+        "assemble": (0, "gather", lambda: _lowered(
+            sp._scatter_assemble_host, rows, tag, d, n_dev=n_dev, cap=cap,
+            fanout=fanout)),
+        # The expansion locates each slot by a merge, not a binary search.
+        "expand_rows_host": (0, "while", lambda: _lowered(
+            sp.expand_rows_host, left, right, counts, lo, perm, cap=64)),
+        # Controls: each op the pins forbid, in a form the counter must see.
+        "control_take": (1, "gather", lambda: _lowered(
+            lambda r, i: jnp.take(r, i, axis=0), rows, d)),
+        "control_searchsorted": (1, "while", lambda: _lowered(
+            lambda a, q: jnp.searchsorted(a, q, side="right"), off,
+            jnp.arange(64, dtype=jnp.int32))),
+        "control_at_set": (1, "scatter", lambda: _lowered(
+            lambda t, i: jnp.zeros((m,), jnp.int32).at[i].set(t), tag, d)),
+    }
 
 
-@settings(max_examples=10, deadline=None)
+@pytest.mark.parametrize("case", ["assemble", "expand_rows_host",
+                                  "control_take", "control_searchsorted",
+                                  "control_at_set"])
+def test_lowering_pins(case):
+    """Zero gathers in the sort assemble and zero `while` loops in the
+    expansion (a binary search is a loop of gathers, the reduce's largest
+    cost on a v5e chip); each control must count at least one of its op."""
+    at_least, op, lower = _pin_inputs()[case]
+    n = count_ops(lower(), op)
+    if at_least:
+        assert n >= at_least, f"{case}: the counter sees no {op}"
+    else:
+        assert n == 0, f"{case}: {n} {op} ops"
+
+
+@settings(max_examples=7, deadline=None)
 @given(
     st.integers(min_value=0, max_value=250),                # m
-    st.sampled_from([(1, 1), (8, 4), (256, 8)]),            # (k, n_dev)
     st.integers(min_value=1, max_value=10),                 # cap (overflows)
     st.integers(min_value=0, max_value=2**31 - 1),          # seed
 )
-def test_scatter_pack_property_bit_identical(m, kn, cap, seed):
+@pytest.mark.parametrize("kn", [(1, 1), (8, 4), (256, 8)])  # (k, n_dev)
+def test_scatter_pack_property_bit_identical(kn, m, cap, seed):
     k, n_dev = kn
     rng = np.random.default_rng(seed)
     routes = _routes_for(k)
     ptable = lpt_placement(rng.uniform(0, 100, k), n_dev).table
-    _assert_matches_map_pack(_rand_rows(rng, m), routes, ptable, k, n_dev,
-                             cap)
+    _assert_matches_ref(_rand_rows(rng, m), routes, ptable, k, n_dev, cap)
 
 
 # -- expand_rows --------------------------------------------------------------
 
+def _probe(left, right, l_valid=None, r_valid=None):
+    """A REAL probe output (counts, lo, perm) on the first column."""
+    n_l, n_r = left.shape[0], right.shape[0]
+    return jp.join_probe_host(
+        left[:, :1], jnp.ones(n_l, bool) if l_valid is None else l_valid,
+        right[:, :1], jnp.ones(n_r, bool) if r_valid is None else r_valid)
+
+
 def _probe_inputs(rng, n_l, n_r, domain=6, wl=3, wr=4):
-    """Random fragments + a REAL probe output (counts, lo, perm) from the
-    sort-merge formulation — the distribution expand_rows actually sees."""
+    """Random fragments + a REAL probe output (counts, lo, perm) from
+    `join_probe_host` — the distribution the expansion actually sees."""
     left = rng.integers(0, domain, (n_l, wl)).astype(np.int32)
     right = rng.integers(0, domain, (n_r, wr)).astype(np.int32)
-    lk = jnp.asarray(left[:, :1])
-    rk = jnp.asarray(right[:, :1])
+    left, right = jnp.asarray(left), jnp.asarray(right)
     l_valid = jnp.asarray(rng.random(n_l) > 0.2)
     r_valid = jnp.asarray(rng.random(n_r) > 0.2)
-    counts, lo, perm = _probe_sort(lk, l_valid, rk, r_valid, False)
-    return (jnp.asarray(left), jnp.asarray(right), counts, lo, perm)
+    counts, lo, perm = _probe(left, right, l_valid, r_valid)
+    return left, right, counts, lo, perm
 
 
 def _numpy_expand_valid(left, right, counts, lo, perm, cap):
@@ -235,13 +308,11 @@ def _numpy_expand_valid(left, right, counts, lo, perm, cap):
 
 
 def _assert_expand_paths_agree(left, right, counts, lo, perm, cap):
-    """Kernel / host / ref / ops, positionally identical everywhere; the
-    valid region checked against the explicit numpy loop."""
+    """Host / ref / ops, positionally identical everywhere; the valid
+    region checked against the explicit numpy loop."""
     out_o, val_o = sp.expand_rows_host(left, right, counts, lo, perm, cap=cap)
     out_o, val_o = np.asarray(out_o), np.asarray(val_o)
     paths = {
-        "kernel": sp.expand_rows(left, right, counts, lo, perm, cap=cap,
-                                 interpret=True),
         "ref": expand_rows_ref(left, right, counts, lo, perm, cap),
         "ops": kops.expand_rows(left, right, counts, lo, perm, cap),
     }
@@ -274,8 +345,7 @@ def test_expand_rows_fanout_groups():
     left = jnp.asarray(np.stack([np.full(6, 3), np.arange(6)], 1), jnp.int32)
     right = jnp.asarray(np.stack([np.full(30, 3), np.arange(30)], 1),
                         jnp.int32)
-    counts, lo, perm = _probe_sort(left[:, :1], jnp.ones(6, bool),
-                                   right[:, :1], jnp.ones(30, bool), False)
+    counts, lo, perm = _probe(left, right)
     assert int(np.asarray(counts).max()) == 30          # full fanout
     out, val = _assert_expand_paths_agree(left, right, counts, lo, perm, 256)
     got = out[val]
@@ -288,8 +358,7 @@ def test_expand_rows_zero_matches_and_zero_sizes():
     # Disjoint keys: total == 0, all-INVALID output.
     left = jnp.asarray(rng.integers(0, 5, (10, 2)), jnp.int32)
     right = jnp.asarray(rng.integers(50, 55, (8, 2)), jnp.int32)
-    counts, lo, perm = _probe_sort(left[:, :1], jnp.ones(10, bool),
-                                   right[:, :1], jnp.ones(8, bool), False)
+    counts, lo, perm = _probe(left, right)
     out, val = _assert_expand_paths_agree(left, right, counts, lo, perm, 16)
     assert val.sum() == 0
     # Zero-size sides: the static guard path, all paths agree.
@@ -345,23 +414,3 @@ def test_expand_rows_host_locates_slots_like_searchsorted(case,
                                    jnp.asarray(off, jnp.int32), perm, cap)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out_r))
     np.testing.assert_array_equal(np.asarray(val), np.asarray(val_r))
-
-
-def test_expand_rows_tile_boundaries():
-    """Multi-tile grids (explicit tiny block, so groups straddle tile edges)
-    must stay positionally identical to the single-pass host twin; and the
-    VMEM auto-shrink really shrinks once the one-hots outgrow the budget."""
-    rng = np.random.default_rng(11)
-    left, right, counts, lo, perm = _probe_inputs(rng, 60, 80, domain=10)
-    total = int(np.asarray(counts).sum())
-    cap = max(total + 32, 64)
-    out_o, val_o = sp.expand_rows_host(left, right, counts, lo, perm,
-                                       cap=cap)
-    for block in (8, 16, 64):
-        out, val = sp.expand_rows(left, right, counts, lo, perm, cap=cap,
-                                  block=block, interpret=True)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(out_o),
-                                      err_msg=f"block={block}")
-        np.testing.assert_array_equal(np.asarray(val), np.asarray(val_o),
-                                      err_msg=f"block={block}")
-    assert sp._expand_block(256, 3000, 4000) < 256      # the shrink engages

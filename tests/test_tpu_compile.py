@@ -3,11 +3,12 @@
 Nothing runs: each test lowers and compiles for a chip that is described,
 not attached, so it catches what interpret mode cannot — tiles the TPU
 refuses, operations Mosaic cannot lower, programs XLA cannot partition.
+The one Pallas kernel on the main path is the count pass's `map_count`.
 Shapes come from `chip_smoke.py`'s 3-way request (2^21 rows per relation,
 k = 4 logical cells on one chip).  The topology is described inside a
 fixture, never at import, so every test worker collects the same tests.
-The dispatch in `kernels/ops.py` asks `jax.default_backend()`, which is the
-CPU here, so the tests steer it to the TPU branch with monkeypatch.
+The `map_count` fork in `kernels/ops.py` asks `jax.default_backend()`, which
+is the CPU here, so the tests steer it to the TPU branch with monkeypatch.
 """
 import functools
 import importlib.util
@@ -23,7 +24,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import cascade_sizes, plan_skew_join
 from repro.core.executor import ExecutorConfig, ShardedJoinExecutor
-from repro.kernels import join_probe as jp
 from repro.kernels import map_pack as mp
 from repro.kernels import ops
 
@@ -122,26 +122,11 @@ def test_map_count_compiles(smoke_case, one_chip):
         assert _custom_calls(c) == 1, rel.name
 
 
-def test_join_hash_compiles(smoke_case, one_chip):
-    n_l = smoke_case["caps"]["R"]               # the first probe's left side
-    c = _compile(lambda a, v: jp.join_hash(a, v, n_bits=jp.MAX_BITS),
-                 _sds((n_l, 2), one_chip), _sds((n_l,), one_chip))
-    assert _custom_calls(c) == 1
-
-
-@pytest.mark.parametrize("n_bits", [jp.SINGLE_PASS_BITS, jp.MAX_BITS])
-def test_build_table_compiles(smoke_case, one_chip, n_bits):
-    """One single-pass and one multi-pass (factored histogram) width."""
-    n_r = smoke_case["caps"]["S"]               # the first probe's right side
-    c = _compile(lambda a, v: jp.build_table(a, v, n_bits=n_bits),
-                 _sds((n_r, 2), one_chip), _sds((n_r,), one_chip))
-    assert _custom_calls(c) == 1
-
-
 @pytest.mark.parametrize("phase", sorted(ops.TPU_IMPL))
 def test_dispatch_follows_tpu_impl(phase, one_chip, tpu_dispatch):
-    """Each main-path wrapper lowers to a kernel on TPU exactly when
-    `TPU_IMPL` says so (small shapes: this checks the choice, not size)."""
+    """Each phase's entry point lowers to a kernel on TPU exactly when
+    `TPU_IMPL` says so: `map_count` to one, the other three to none (small
+    shapes: this checks the choice, not size)."""
     n, sd = 4096, one_chip
     spec = ((((0, 0x9E3779B1, 4, 1),), (0,), 0, (), ()),)
     calls = {
@@ -176,8 +161,8 @@ def test_count_pass_compiles(chips_case, topo, tpu_dispatch):
 
 def test_executor_step_compiles(smoke_case, topo, tpu_dispatch):
     """The whole map -> all_to_all -> reduce step on one chip at the smoke's
-    shapes: it fits the chip's 16 GB.  Every phase of the step runs its
-    twin on TPU (`TPU_IMPL`), so it holds no kernel call."""
+    shapes: it fits the chip's 16 GB.  Every phase of the step runs XLA on
+    TPU (`TPU_IMPL`), so it holds no kernel call."""
     case = smoke_case
     mesh = Mesh(np.array(topo.devices[:1]), ("cells",))
     ex = ShardedJoinExecutor(case["plan"], mesh, config=case["cfg"])
