@@ -19,10 +19,10 @@ relies on.
           is final the moment it is packed — the paper's one-round structure
           is what makes the overlap legal; the serial path stays the
           bit-exactness oracle up to fragment arrival order).
-  reduce  `_local_join`: a cascade of radix hash joins matching only within
-          equal logical cell ids.  Each step probes with
-          `kernels.join_probe.join_probe_host` (30-bit hash partitions from
-          one sort, left rows located by a merge, key-verified chains) and
+  reduce  `_local_join`: a cascade of joins matching only within equal
+          logical cell ids.  Each step probes with
+          `kernels.join_probe.join_probe_host` (an exact-key merge sort of
+          both sides, then scans: no hash, loop, gather or scatter) and
           expands the (counts, lo, perm) result into `cap_out` rows with
           `kernels.scatter_pack.expand_rows_host` (each slot's left row
           located by a merge, then gathered).  `_local_join_dense`, the
@@ -328,7 +328,7 @@ def _local_join(frags: dict[str, jnp.ndarray], query: JoinQuery, cap_out: int
       1. a probe pass over the shared key columns (incl. `__cell__`) yields
          per-left-row match counts, group-start offsets, and a grouped
          right-side permutation whose groups are contiguous and internally in
-         ARRIVAL order — `kops.join_probe`, the radix hash join;
+         ARRIVAL order — `kops.join_probe`, an exact-key merge sort;
       2. expand to the static `cap_out` shape from the exclusive prefix sum
          of per-left-row counts — `kops.expand_rows`, output order (left
          row, right arrival order), bit-identical to the dense-matrix ground

@@ -1,34 +1,32 @@
-"""The reduce's probe: a radix hash join with key-verified chains.
+"""The reduce's probe: an exact-key merge sort of both sides.
 
 A reducer cell's local join cascades over its fragments; every cascade step
 must produce, per left (accumulator) row, its matching right rows in ARRIVAL
 order — the load-bearing output contract of `core.executor._local_join`.
-`join_probe_host` produces it as (counts, lo, perm) with no union buffer and
-no multi-column lexsort:
+`join_probe_host` produces it as (counts, lo, perm) from two sorts and a
+few scans, with no hash, no loop, no gather and no scatter:
 
-  hash    a fused multiply-shift hash of ALL shared key columns (named
-          attributes + the hidden `__cell__` id) in one elementwise pass:
-          h = (Σ_c key_c · seed_c) · MULT, keeping the top `HOST_BITS` bits.
-          Invalid right rows take a sentinel above every valid hash.
-  build   ONE sort of (hash, arrival) pairs lays the right side out as a
-          packed table: the sorted pairs ARE (partition, original row), and
-          partition bounds fall out of two run scans (`_run_bounds`).
-  locate  left rows find their partition, and whether it exists, by merging
-          their hashes into the sorted ones (`sorted_locate`: two sorts and
-          two scans, where a binary search would be a loop of gathers).
-  probe   `_chain_probe` — key-verified chained resolution.  Distinct keys
-          colliding in one partition are resolved EXACTLY: each round peels
-          the chain one link, and resolving rows take contiguous slots in a
-          grouped final order via segmented prefix sums (groups contiguous,
-          arrival order inside).  Round count = max distinct keys per
-          partition (+1): O(1) at 30 bits; a tiny `n_bits` forces deep
-          chains (the forced-collision test knob).
+  merge   ONE sort of left ++ right keyed on (validity, every shared key
+          column including the hidden `__cell__` id, tag): equal keys form
+          one run, left entries first, each side in arrival order.
+  scan    a running count of right entries gives each left entry its `lo`;
+          a reverse running minimum carries each run's end count back, and
+          the difference is its `counts`.
+  return  ONE sort on a single key puts (counts, lo) back in left order and
+          the right entries' tags, in merged order, out as `perm`.
 
-`join_hash_host` / `build_table_host` are the same hash and a bucket table
-with stable within-bucket ranks over a table of `n_bits` buckets;
-`probe_tables` runs the chained probe on such a table.  With
-`join_hash_ref` / `build_table_ref` / `join_probe_ref` in kernels/ref.py
-they are the oracles the tests hold `join_probe_host` to.
+Its cost depends on n_l + n_r alone, not on the key count or the skew: a
+heavy key is one long run.  `sorted_locate`, a merge of queries into a
+sorted array by the same two-sort pattern, serves the expansion
+(`scatter_pack.expand_rows_host`).
+
+The rest of the module serves only the tests as an independent oracle:
+`join_hash_host` / `build_table_host` hash ALL shared key columns with a
+fused multiply-shift into a table of 2^n_bits buckets with stable
+within-bucket ranks, and `probe_tables` runs `_chain_probe`, a key-verified
+chained probe, on such a table (a tiny `n_bits` forces deep collision
+chains).  With `join_hash_ref` / `build_table_ref` / `join_probe_ref` in
+kernels/ref.py they are what the tests hold `join_probe_host` to.
 """
 from __future__ import annotations
 
@@ -40,7 +38,6 @@ import jax.numpy as jnp
 from .ref import MULT
 
 MAX_BITS = 16             # `default_bits` table-size cap (2^16 buckets)
-HOST_BITS = 30            # hash bits of `join_probe_host`'s sorted partitions
 INVALID = -1
 
 # Per-column odd multipliers of the fused key hash (host functions and ref
@@ -118,7 +115,7 @@ def build_table_host(keys: jnp.ndarray, valid: jnp.ndarray, *, n_bits: int
 
 
 # ---------------------------------------------------------------------------
-# Chained build + probe
+# Chained build + probe: the tests' bucket-table oracle
 # ---------------------------------------------------------------------------
 
 def _chain_probe(lk: jnp.ndarray, rk: jnp.ndarray, perm1: jnp.ndarray,
@@ -127,8 +124,8 @@ def _chain_probe(lk: jnp.ndarray, rk: jnp.ndarray, perm1: jnp.ndarray,
                  ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Key-verified chained resolution over a partitioned packed table.
 
-    Partition-scheme-agnostic core shared by `join_probe_host` (sorted
-    30-bit partitions) and `probe_tables` (bucket tables).  `perm1` maps
+    The tests' oracle probe, run by `probe_tables` on a bucket table
+    (`join_probe_host` no longer partitions by hash).  `perm1` maps
     packed position -> original right row (rows grouped by hash partition,
     ARRIVAL order inside, invalid rows last);
     `rstart`/`rend` give each packed row its partition's [start, end) range;
@@ -279,37 +276,49 @@ def probe_tables(lk: jnp.ndarray, l_bkt: jnp.ndarray, rk: jnp.ndarray,
                         fpos0)
 
 
-@functools.partial(jax.jit, static_argnames=("n_bits",))
+@jax.jit
 def join_probe_host(lk: jnp.ndarray, l_valid: jnp.ndarray, rk: jnp.ndarray,
-                    r_valid: jnp.ndarray, *, n_bits: int | None = None
+                    r_valid: jnp.ndarray
                     ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The reduce's probe: (counts, lo, perm) of left keys lk (n_l, w)
     against right keys rk (n_r, w), in the same key-column order.
 
-    The packed table comes from ONE sort of (hash, arrival) pairs: the
-    sorted pairs ARE (partition, original row), partition bounds fall out of
-    two run scans, and left rows locate their partition, and whether it
-    exists, by merging their hashes into the sorted ones (`sorted_locate`:
-    two sorts and two scans, where a binary search would be a loop of
-    gathers, slow on a TPU): no multi-column lexsort, no scatter.  The
-    hash keeps `HOST_BITS` bits (invalid rows take the sentinel above them,
-    sorting last), so partitions are far finer than a bucket table of
-    `default_bits` and the chain loop converges in O(1) rounds at any n_r; an
-    explicit tiny `n_bits` still forces deep chains for the collision tests.
+    An exact-key merge sort, with no hash, no loop, no gather and no
+    scatter.  Both sides go into one sort keyed on (validity, the key
+    columns, tag): validity is 0 for valid rows, 1 for invalid right rows and
+    2 for invalid left rows, so an invalid row never shares a run of equal
+    keys with a valid one; the tag is the row's place in left ++ right, so
+    on equal keys left entries come first and each side keeps its arrival
+    order.  In merged order a left entry's `lo` is the running count of
+    right entries before it, and its `counts` the count at the end of its
+    run less `lo`: a reverse running minimum carries each run's end count
+    back, as the count never decreases.  One sort on a single key then
+    returns the left entries' (counts, lo) to left order and lays the
+    right entries' tags out in merged order, which is `perm`: every exact-key
+    group contiguous and in arrival order, invalid rows last.  Rows with no
+    match get counts = lo = 0.
     """
     n_l, n_r = lk.shape[0], rk.shape[0]
     if n_r == 0:
         return (jnp.zeros((n_l,), jnp.int32), jnp.zeros((n_l,), jnp.int32),
                 jnp.zeros((0,), jnp.int32))
-    bits = min(n_bits or HOST_BITS, HOST_BITS)
-    qidx = jnp.arange(n_r, dtype=jnp.int32)
-    hw_l = _hash_block(lk, bits)
-    hw_r = _hash_block(rk, bits)
-    key = jnp.where(r_valid.astype(bool), hw_r, jnp.int32(1 << bits))
-    rid, perm1 = jax.lax.sort((key, qidx), num_keys=2)      # packed -> orig
-    rstart, rend = _run_bounds(rid)
-    fpos0 = jnp.where(rid >= (1 << bits), qidx, jnp.int32(-1))
-    s_l, nxt = sorted_locate(rid, hw_l, side="left")
-    exists = (s_l < n_r) & (nxt == hw_l)
-    l_miss = ~l_valid.astype(bool) | ~exists
-    return _chain_probe(lk, rk, perm1, rstart, rend, s_l, l_miss, fpos0)
+    n = n_l + n_r
+    inv = jnp.concatenate([jnp.where(l_valid.astype(bool), 0, 2),
+                           jnp.where(r_valid.astype(bool), 0, 1)]
+                          ).astype(jnp.int32)
+    cols = [jnp.concatenate([lk[:, c], rk[:, c]]) for c in range(lk.shape[1])]
+    tag = jnp.arange(n, dtype=jnp.int32)
+    *keys, tag = jax.lax.sort((inv, *cols, tag), num_keys=len(cols) + 2)
+    is_r = tag >= n_l
+    cum_r = jnp.cumsum(is_r.astype(jnp.int32))             # inclusive
+    new_run = jnp.zeros((n - 1,), bool)
+    for k in keys:
+        new_run = new_run | (k[1:] != k[:-1])
+    run_end = jnp.concatenate([new_run, jnp.ones((1,), bool)])
+    end_r = jax.lax.cummin(
+        jnp.where(run_end, cum_r, jnp.iinfo(jnp.int32).max), reverse=True)
+    counts = end_r - cum_r            # a left entry's right rows after it
+    lo = jnp.where(counts > 0, cum_r, 0)
+    back = jnp.where(is_r, n_l + jnp.arange(n, dtype=jnp.int32), tag)
+    _, counts, lo, tag = jax.lax.sort((back, counts, lo, tag), num_keys=1)
+    return counts[:n_l], lo[:n_l], tag[n_l:] - n_l

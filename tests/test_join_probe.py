@@ -1,16 +1,17 @@
-"""join_probe radix hash join vs its oracles, forced collisions included.
+"""The reduce's probe vs its oracles, forced collisions included.
 
-The reduce's probe `join_probe_host` (30-bit partitions from one sort) must
-agree with the chained probe over the dead-simple bucket-table oracles in
-kernels/ref.py (`probe_tables` on `join_hash_ref` / `build_table_ref`), and
-`join_hash_host` / `build_table_host` with those oracles.  The semantic
-contract is the
-expanded match list — per left row, its matching right rows in ARRIVAL order
-(`join_probe_ref`) — reproduced through the executor's prefix-sum expansion
-gather from (counts, lo, perm).  Coverage: tiny-hash-bits tables where every
-partition sees colliding distinct keys (the key-verified chaining path),
-duplicates-heavy zipf keys, fanout > 1 match recipes, invalid rows on both
-sides, all-invalid sides, and empty left sides.
+The probe `join_probe_host` (an exact-key merge sort of both sides) must
+agree with the dense oracle `join_probe_ref` and with the chained probe
+over the dead-simple bucket-table oracles in kernels/ref.py
+(`probe_tables` on `join_hash_ref` / `build_table_ref`); `join_hash_host`
+/ `build_table_host` must agree with those oracles.  The semantic contract
+is the expanded match list — per left row, its matching right rows in
+ARRIVAL order (`join_probe_ref`) — reproduced through the executor's
+prefix-sum expansion gather from (counts, lo, perm).  Coverage: tiny-hash-
+bits tables where every partition of the oracle sees colliding distinct
+keys, duplicates-heavy zipf keys, one key holding most rows, fanout > 1,
+invalid rows on both sides (also under valid keys), keys that differ only
+in the cell column, all-invalid sides, and empty left sides.
 """
 import numpy as np
 import pytest
@@ -41,11 +42,13 @@ def _expand(counts, lo, perm, n_l, n_r, cap):
 
 
 def _all_paths(lk, lv, rk, rv, n_bits):
+    """The probe, and the bucket-table oracle at `n_bits` (by default the
+    table size `default_bits` picks)."""
     lk, rk = jnp.asarray(lk, jnp.int32), jnp.asarray(rk, jnp.int32)
     lv, rv = jnp.asarray(lv), jnp.asarray(rv)
     bits = n_bits or jp.default_bits(rk.shape[0])
     return {
-        "host": jp.join_probe_host(lk, lv, rk, rv, n_bits=n_bits),
+        "host": jp.join_probe_host(lk, lv, rk, rv),
         "ref": jp.probe_tables(lk, join_hash_ref(lk, lv, bits), rk,
                                *build_table_ref(rk, rv, bits), bits),
     }
@@ -123,12 +126,80 @@ def test_probe_empty_left():
     rk = np.arange(12).reshape(6, 2)
     counts, lo, perm = jp.join_probe_host(
         jnp.zeros((0, 2), jnp.int32), jnp.zeros((0,), bool),
-        jnp.asarray(rk, jnp.int32), jnp.ones(6, bool), n_bits=3)
+        jnp.asarray(rk, jnp.int32), jnp.ones(6, bool))
     assert counts.shape == (0,) and lo.shape == (0,)
     assert sorted(np.asarray(perm).tolist()) == list(range(6))
 
 
-_SENTINEL = 1 << jp.HOST_BITS            # `join_probe_host`'s invalid hash
+def test_probe_invalid_left_rows_under_valid_keys():
+    """An invalid left row whose key equals a valid right key matches
+    nothing, and a valid left row with the same key still matches."""
+    rk = np.array([[3, 0], [5, 1], [3, 0], [7, 0]])
+    lk = np.array([[3, 0], [3, 0], [5, 1], [5, 1], [7, 0]])
+    lv = np.array([False, True, False, True, False])
+    rv = np.ones(4, bool)
+    assert _assert_matches_ref(lk, lv, rk, rv, None) == 3
+    counts, _, _ = jp.join_probe_host(jnp.asarray(lk, jnp.int32),
+                                      jnp.asarray(lv),
+                                      jnp.asarray(rk, jnp.int32),
+                                      jnp.asarray(rv))
+    np.testing.assert_array_equal(np.asarray(counts), [0, 2, 0, 1, 0])
+
+
+@pytest.mark.parametrize("n_bits", [None, 1])
+def test_probe_keys_equal_but_for_the_cell(n_bits):
+    """Rows equal in the attribute column but in different logical cells
+    (the last key column) never match across cells."""
+    rng = np.random.default_rng(11)
+    attr = rng.integers(0, 3, 40)
+    lk = np.stack([attr, rng.integers(0, 4, 40)], axis=1)
+    rk = np.stack([rng.integers(0, 3, 50), rng.integers(0, 4, 50)], axis=1)
+    ones_l, ones_r = np.ones(40, bool), np.ones(50, bool)
+    matches = _assert_matches_ref(lk, ones_l, rk, ones_r, n_bits)
+    assert 0 < matches < int((lk[:, None, 0] == rk[None, :, 0]).sum())
+
+
+@pytest.mark.parametrize("n_bits", [None, 2])
+def test_probe_one_heavy_key(n_bits):
+    """One key holds most rows on both sides (the cell's heavy hitter in
+    small): one long run, with invalid rows of that key on both sides."""
+    rng = np.random.default_rng(12)
+    n_l, n_r = 90, 120
+    lk = np.zeros((n_l, 2), np.int32)
+    rk = np.zeros((n_r, 2), np.int32)
+    lk[::5] = rng.integers(1, 6, (len(lk[::5]), 2))
+    rk[::7] = rng.integers(1, 6, (len(rk[::7]), 2))
+    lv = rng.random(n_l) > 0.1
+    rv = rng.random(n_r) > 0.1
+    matches = _assert_matches_ref(lk, lv, rk, rv, n_bits, cap=1 << 14)
+    heavy = int(((lk == 0).all(1) & lv).sum() * ((rk == 0).all(1) & rv).sum())
+    assert matches >= heavy > 0
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_probe_perm_puts_invalid_right_rows_last(seed):
+    """perm is a permutation of the right rows: the valid rows first,
+    grouped by key in arrival order, the invalid rows after them."""
+    rng = np.random.default_rng(seed)
+    n_r = 64
+    rk = rng.integers(0, 4, (n_r, 2))
+    rv = rng.random(n_r) > 0.4
+    lk = rng.integers(0, 4, (20, 2))
+    _, _, perm = (np.asarray(x) for x in jp.join_probe_host(
+        jnp.asarray(lk, jnp.int32), jnp.ones(20, bool),
+        jnp.asarray(rk, jnp.int32), jnp.asarray(rv)))
+    assert sorted(perm.tolist()) == list(range(n_r))
+    n_valid = int(rv.sum())
+    assert rv[perm[:n_valid]].all() and not rv[perm[n_valid:]].any()
+    keys = [tuple(rk[i]) for i in perm[:n_valid]]
+    for key in set(keys):
+        at = [j for j, k in enumerate(keys) if k == key]
+        assert at == list(range(at[0], at[-1] + 1))       # contiguous
+        rows = perm[:n_valid][at]
+        assert (np.diff(rows) > 0).all()                  # arrival order
+
+
+_SENTINEL = 1 << 30                     # a tail of invalid entries, last
 _I32MAX = np.iinfo(np.int32).max
 
 
@@ -139,8 +210,8 @@ def _random_hashes(seed):
     return rid, rng.integers(-2, 45, 301)
 
 
-# (sorted right hashes `rid`, left hashes `hw_l`) as `join_probe_host` sees
-# them: invalid right rows carry the sentinel and sort last.
+# (a sorted array `rid`, queries `hw_l`): the sentinel stands for entries
+# that sort after every query of interest.
 _LOCATE_CASES = {
     "ties_and_runs": ([0, 0, 3, 3, 3, 7, 7, 9], [3, 0, 7, 9, 5, 3, 3, -1, 0]),
     "past_the_end": ([1, 2, 2, 4], [4, 5, 1 << 29, _SENTINEL, _I32MAX, 0]),
@@ -156,9 +227,8 @@ _LOCATE_CASES = {
 
 @pytest.mark.parametrize("case", sorted(_LOCATE_CASES))
 def test_sorted_locate_matches_searchsorted(case):
-    """`join_probe_host` locates each left row's partition by a merge sort
-    (`sorted_locate`): position and next right hash as `np.searchsorted`
-    and an index past the end give them."""
+    """`sorted_locate` (the expansion's merge): position and next element
+    as `np.searchsorted` and an index past the end give them."""
     rid, hw_l = (np.asarray(x, np.int32) for x in _LOCATE_CASES[case])
     for side in ("left", "right"):
         pos, nxt = jp.sorted_locate(jnp.asarray(rid), jnp.asarray(hw_l),

@@ -227,6 +227,10 @@ def _pin_inputs():
         # The expansion locates each slot by a merge, not a binary search.
         "expand_rows_host": (0, "while", lambda: _lowered(
             sp.expand_rows_host, left, right, counts, lo, perm, cap=64)),
+        # The probe is a merge sort and scans: no loop, gather or scatter.
+        **{f"join_probe_host.{op}": (0, op, lambda: _lowered(
+            jp.join_probe_host, left[:, :2], left[:, 2] > 2, right[:, :2],
+            right[:, 3] > 2)) for op in ("while", "gather", "scatter")},
         # Controls: each op the pins forbid, in a form the counter must see.
         "control_take": (1, "gather", lambda: _lowered(
             lambda r, i: jnp.take(r, i, axis=0), rows, d)),
@@ -239,12 +243,16 @@ def _pin_inputs():
 
 
 @pytest.mark.parametrize("case", ["assemble", "expand_rows_host",
+                                  "join_probe_host.while",
+                                  "join_probe_host.gather",
+                                  "join_probe_host.scatter",
                                   "control_take", "control_searchsorted",
                                   "control_at_set"])
 def test_lowering_pins(case):
-    """Zero gathers in the sort assemble and zero `while` loops in the
+    """Zero gathers in the sort assemble, zero `while` loops in the
     expansion (a binary search is a loop of gathers, the reduce's largest
-    cost on a v5e chip); each control must count at least one of its op."""
+    cost on a v5e chip) and none of the three in the probe; each control
+    must count at least one of its op."""
     at_least, op, lower = _pin_inputs()[case]
     n = count_ops(lower(), op)
     if at_least:
